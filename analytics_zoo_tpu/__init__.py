@@ -25,6 +25,10 @@ Top-level layout (mirrors the reference's layer map, SURVEY.md §1):
 - ``utils``     : summaries (TensorBoard-style), file IO, logging
 """
 
+import os
+
+import jax
+
 from analytics_zoo_tpu.version import __version__
 from analytics_zoo_tpu.common.zoo_context import (
     init_zoo_context,
@@ -38,3 +42,20 @@ __all__ = [
     "get_zoo_context",
     "ZooContext",
 ]
+
+
+def _place_compile_cache() -> None:
+    """ONE persistent XLA compilation cache for every entry point, placed
+    from outside: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    and nothing is set here; otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (the directory is part of the cache key, so
+    it is derived from this package's location — never a temp dir, a pid
+    or the time)."""
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+
+
+_place_compile_cache()

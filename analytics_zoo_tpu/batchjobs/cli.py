@@ -48,10 +48,8 @@ def cmd_demo(args) -> int:
     job = demo_job(args.output_dir, num_rows=args.rows,
                    rows_per_shard=args.rows_per_shard,
                    batch_size=args.batch_size, keras=args.keras)
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     report = run_job(job, args.run_dir, num_workers=args.workers,
-                     env=env, timeout_s=args.timeout)
+                     timeout_s=args.timeout)
     return _finish(report, args.run_dir, args.report_out)
 
 

@@ -172,6 +172,28 @@ class _StaleEntry(RuntimeError):
     pass
 
 
+def _device_ids(compiled) -> Optional[List[int]]:
+    """The executable's device assignment, in order, as device ids
+    (None for a program with no array in or out)."""
+    import jax
+    shardings = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings))
+    if not shardings:
+        return None
+    return [d.id for d in shardings[0]._device_assignment]
+
+
+def _devices_by_id(ids: Optional[List[int]]):
+    """``execution_devices`` for ``deserialize_and_load``: since jaxlib
+    0.9 it defaults to ALL of the backend's devices, which a program
+    compiled for fewer refuses at its first call."""
+    if ids is None:
+        return None
+    import jax
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in ids]
+
+
 class ExecutableCache:
     """On-disk executable store with atomic writes, corruption-safe
     loads, and an LRU size cap.  One instance per directory per
@@ -224,7 +246,9 @@ class ExecutableCache:
                     f"entry built by {meta.get('versions')}, running "
                     f"{current}")
             from jax.experimental import serialize_executable as se
-            exe = se.deserialize_and_load(*doc["payload"])
+            exe = se.deserialize_and_load(
+                *doc["payload"],
+                execution_devices=_devices_by_id(meta.get("device_ids")))
         except _StaleEntry as e:
             # read-only processes (farm workers, cache_write=false)
             # must never mutate the shared directory: a worker on a
@@ -276,6 +300,7 @@ class ExecutableCache:
                     "versions": runtime_versions(),
                     "key_hint": key_hint,
                     "created_unix": round(time.time(), 1),
+                    "device_ids": _device_ids(compiled),
                 },
                 "payload": payload,
             })

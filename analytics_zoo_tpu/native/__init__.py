@@ -1,8 +1,11 @@
 """ctypes bindings for the native data-path library (zoodata.cpp).
 
-Compiled lazily with g++ on first use and cached next to the source;
-all callers fall back to numpy when the toolchain or binary is
-unavailable, so the native path is an accelerator, never a dependency.
+Built with g++ from the tracked source on first use and kept next to
+it; all callers fall back to numpy when the toolchain is unavailable,
+so the native path is an accelerator, never a dependency.  The binary
+is never trusted across machines: it is built for the baseline ISA (no
+``-march=native``), and one that fails to load is rebuilt once from
+source before the numpy path is taken.
 """
 
 from __future__ import annotations
@@ -27,14 +30,34 @@ _tried = False
 
 
 def _build() -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", _SRC,
+    cmd = ["g++", "-O3", "-shared", "-fPIC", _SRC,
            "-o", _LIB_PATH, "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return True
-    except Exception as e:      # noqa: BLE001
-        log.info("native build skipped (%s); using numpy fallback", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        log.warning("native build failed (%s); using the numpy path", e)
         return False
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+        lib.gather_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int]
+        lib.shuffle_indices.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
+        lib.u8_to_f32_scaled.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_float, ctypes.c_float, ctypes.c_int]
+        lib.crc32c_update.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64, ctypes.c_uint32]
+        lib.crc32c_update.restype = ctypes.c_uint32
+        return lib
+    except (OSError, AttributeError) as e:
+        log.warning("native lib failed to load (%s)", e)
+        return None
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -43,31 +66,23 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
+        # deliberate: the one-shot native build MUST be serialized (two
+        # concurrent cc invocations would corrupt the artifact);
+        # waiters need the lib anyway, and _tried caps this to one
+        # attempt ever
+        built = False
         if not os.path.exists(_LIB_PATH) or \
                 os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC):
-            # deliberate: the one-shot native build MUST be
-            # serialized (two concurrent cc invocations would corrupt
-            # the artifact); waiters need the lib anyway, and _tried
-            # caps this to one build ever
             # zoolint: disable=LOCK010 — serialized one-shot build
             if not _build():
                 return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.gather_rows.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int]
-            lib.shuffle_indices.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
-            lib.u8_to_f32_scaled.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_float, ctypes.c_float, ctypes.c_int]
-            lib.crc32c_update.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64, ctypes.c_uint32]
-            lib.crc32c_update.restype = ctypes.c_uint32
-            _lib = lib
-        except OSError as e:
-            log.info("native lib load failed (%s)", e)
+            built = True
+        _lib = _load()
+        # a binary copied from another machine or build: rebuild it
+        # here once instead of quietly pinning the numpy path
+        # zoolint: disable=LOCK010 — serialized one-shot build
+        if _lib is None and not built and _build():
+            _lib = _load()
         return _lib
 
 

@@ -56,7 +56,7 @@ def _jit_cache_size() -> Optional[int]:
 # devices that have successfully reported memory_stats at least once
 # in this process — a later failed poll on one of these marks its
 # gauges STALE instead of silently freezing them (some backends drop
-# memory_stats mid-run, e.g. across a tunneled-runtime reconnect).
+# memory_stats mid-run).
 # Guarded: the TelemetrySampler thread and direct callers (estimator
 # per-epoch sampling, tests) may run a pass concurrently.
 _reported_devices: set = set()

@@ -1,7 +1,7 @@
 """Fused Pallas kernel suite — single-HBM-pass hot-path kernels.
 
-Three kernel families, each with a lax fallback behind ONE capability
-probe (the ``_int8_conv_supported`` pattern from ``ops/quant.py``):
+Three kernel families, each with a lax form beside it; ONE capability
+probe (``pallas_supported``) decides which runs:
 
 * **Fused optimizer update** (``build_fused_update``): global-norm
   grad clip + SGD/Adam moment update + parameter apply in ONE pass over
@@ -20,7 +20,8 @@ probe (the ``_int8_conv_supported`` pattern from ``ops/quant.py``):
 * **Epilogue kernels** (``bias_gelu``, ``layernorm_act``): the
   bias-add→GeLU and LayerNorm→activation tails of the dense/attention
   stacks, computed without a round trip of the intermediate activation
-  through HBM.
+  through HBM.  Differentiable: a ``custom_vjp`` runs the Pallas
+  forward and takes the backward from the lax form's own derivative.
 
 * The flash-attention kernels live in ``ops/pallas_attention.py`` and
   the cross-chip ring schedule in ``parallel/ring_attention.py`` — this
@@ -28,8 +29,9 @@ probe (the ``_int8_conv_supported`` pattern from ``ops/quant.py``):
 
 Mode selection (``ops.fused`` config key):
 
-* ``auto`` (default) — Pallas kernels when the backend compiles them
-  (TPU; decided by one eager probe), lax otherwise.
+* ``auto`` (default) — Pallas kernels on a TPU backend (one eager
+  probe; a kernel the TPU compiler refuses is an error, never a quiet
+  switch to lax), lax on every other backend.
 * ``lax``  — always the lax form (same math, XLA fusion does the work).
 * ``off``  — disable the suite; call sites fall back to their
   pre-suite code paths (the trainer runs the optax triple pass).
@@ -49,12 +51,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:           # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from analytics_zoo_tpu.ops import activations as acts
 
 
 # ------------------------------------------------------------------ mode
@@ -72,52 +72,47 @@ def fused_enabled() -> bool:
 _PALLAS_OK: Optional[bool] = None
 
 
-def pallas_supported() -> bool:
-    """Probe ONCE, eagerly, whether the backend compiles a
-    REPRESENTATIVE suite kernel — SMEM scalar operand + grid +
+def _probe():
+    """ONE representative suite kernel — SMEM scalar operand + grid +
     ``input_output_aliases``, the exact features the optimizer kernels
-    use — outside any trace (backend rejection surfaces at compile
-    time; a try/except around a traced call would miss it), mirroring
-    ``quant._int8_conv_supported``.  The suite's kernels are
-    TPU-Pallas (pltpu memory spaces, TPU tiling), so any other
-    backend answers False even where a generic Pallas kernel would
-    compile (e.g. the GPU Triton lowering)."""
-    global _PALLAS_OK
-    if not _HAS_PALLAS:
-        return False
-    if _PALLAS_OK is None:
-        if jax.default_backend() != "tpu":
-            _PALLAS_OK = False
-            return _PALLAS_OK
-        try:
-            def k(s_ref, x_ref, o_ref):
-                o_ref[:] = x_ref[:] * s_ref[0]
+    use — as ``(jitted function, argument shapes)``."""
+    def k(s_ref, x_ref, o_ref):
+        o_ref[:] = x_ref[:] * s_ref[0]
 
-            # ensure_compile_time_eval: the first call may come from a
-            # layer/trainer body already under jit tracing — without
-            # escaping the trace, the probe jit would be INLINED into
-            # the outer program and its backend rejection deferred past
-            # the except (observed: probe "succeeds" on CPU, outer
-            # lowering then fails)
-            with jax.ensure_compile_time_eval():
-                x = jnp.zeros((16, 128), jnp.float32)
-                s = jnp.ones((4,), jnp.float32)
-                blk = pl.BlockSpec((8, 128), lambda i: (i, 0))
-                # one-shot backend capability probe, not an engine
-                # program: caching its throwaway executable would
-                # pollute the store
-                # zoolint: disable=COMPILE011 — capability probe, not an engine program
-                out = jax.jit(lambda s, a: pl.pallas_call(
-                    k,
-                    out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    grid=(2,),
-                    in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                              blk],
-                    out_specs=blk,
-                    input_output_aliases={1: 0})(s, a))(s, x)
-                jax.block_until_ready(out)
+    blk = pl.BlockSpec((8, 128), lambda i: (i, 0))
+    # zoolint: disable=COMPILE011 — capability probe, not an engine program
+    fn = jax.jit(lambda s, a: pl.pallas_call(
+        k,
+        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        grid=(2,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk],
+        out_specs=blk,
+        input_output_aliases={1: 0})(s, a))
+    return fn, (jax.ShapeDtypeStruct((4,), jnp.float32),
+                jax.ShapeDtypeStruct((16, 128), jnp.float32))
+
+
+def pallas_supported() -> bool:
+    """Whether the suite's kernels run on this backend, decided ONCE.
+    They are TPU-Pallas (pltpu memory spaces, TPU tiling), so every
+    other backend answers False even where a generic Pallas kernel
+    would compile (e.g. the GPU Triton lowering).  On a TPU the answer
+    is True or an exception: a probe kernel the compiler refuses
+    propagates with the compiler's message — a broken Pallas install
+    must not turn the suite (flash attention included) into its lax
+    and dense forms without a word.
+
+    The probe is lowered from shapes and compiled, not called: the
+    first ask may come from a layer body already under jit tracing,
+    where a call would be inlined into the outer program and its
+    refusal deferred to the outer compile."""
+    global _PALLAS_OK
+    if _PALLAS_OK is None:
+        if jax.default_backend() == "tpu":
+            fn, shapes = _probe()
+            fn.lower(*shapes).compile()
             _PALLAS_OK = True
-        except Exception:
+        else:
             _PALLAS_OK = False
     return _PALLAS_OK
 
@@ -129,31 +124,25 @@ def _use_pallas() -> bool:
     if m == "pallas":
         # expert override: trust the caller (e.g. inside a shard_map
         # body, where the per-shard program is single-device again)
-        return _HAS_PALLAS
+        return True
     # auto: pallas_call is not GSPMD-partitionable (the same
     # constraint that keeps flash attention off sharded meshes) — only
     # route to Pallas on a single-device topology; multi-device
     # programs get the lax forms, which XLA fuses and partitions.
-    try:
-        if len(jax.devices()) != 1:
-            return False
-    except Exception:
+    if len(jax.devices()) != 1:
         return False
     return pallas_supported()
 
 
-def _count_build(kernel: str, path: str) -> None:
+def count_build(kernel: str, path: str) -> None:
     """Trace-time accounting: which kernels were built into the live
     programs, on which path (pallas|lax) — obs_report's kernel-suite
     row reads these."""
-    try:
-        from analytics_zoo_tpu.observability import get_registry
-        get_registry().counter(
-            "fused_kernel_builds_total",
-            "fused-suite kernels built into traced programs",
-            labels=("kernel", "path")).labels(kernel, path).inc()
-    except Exception:
-        pass
+    from analytics_zoo_tpu.observability import get_registry
+    get_registry().counter(
+        "fused_kernel_builds_total",
+        "fused-suite kernels built into traced programs",
+        labels=("kernel", "path")).labels(kernel, path).inc()
 
 
 def _leaf_rows(a, min_size: int = 1024) -> Optional[int]:
@@ -167,11 +156,29 @@ def _leaf_rows(a, min_size: int = 1024) -> Optional[int]:
     return n // 128
 
 
-def _row_block(rows: int) -> int:
+# Half of the 16 MiB scoped-VMEM limit the v5e compiler enforces on one
+# kernel: the rest is headroom for what the model below does not count
+# (the (1, d) operands, compiler-internal scratch).
+_VMEM_BUDGET = 8 << 20
+# Block-sized temporaries a kernel body keeps live besides its operand
+# buffers — what the v5e compiler's allocation showed for the GeLU and
+# LayerNorm bodies (a 3 MiB block asked for 17.9 MiB: 4 operand buffers
+# + 2 temporaries).
+_BODY_TEMPS = 2
+
+
+def _row_block(rows: int, d: int, itemsize: int,
+               n_blocked: int) -> Optional[int]:
+    """Largest row block (a power of two ≥ 8 that divides ``rows``)
+    whose VMEM footprint fits ``_VMEM_BUDGET``: each of the
+    ``n_blocked`` (block, d) operands is double-buffered by the
+    pipeline, plus ``_BODY_TEMPS`` block-sized temporaries.  None when
+    even 8 rows do not fit (the caller takes the lax form)."""
+    row_bytes = d * itemsize * (2 * n_blocked + _BODY_TEMPS)
     for br in (1024, 512, 256, 128, 64, 32, 16, 8):
-        if rows % br == 0:
+        if rows % br == 0 and br * row_bytes <= _VMEM_BUDGET:
             return br
-    return rows
+    return None
 
 
 # ===================================================== optimizer kernels
@@ -219,7 +226,9 @@ def _pallas_moment_call(kernel, scal, arrays, n_out: int,
     re-layout, params/moments aliased in place."""
     rows = _leaf_rows(arrays[0])
     shaped = [a.reshape(rows, 128) for a in arrays]
-    br = _row_block(rows)
+    # _leaf_rows guarantees rows % 8 == 0, and 8 rows of 128 lanes fit
+    # the budget for any operand count the suite has
+    br = _row_block(rows, 128, 4, len(arrays) + n_out)
     grid = (rows // br,)
     blk = pl.BlockSpec((br, 128), lambda i: (i, 0))
     # inputs: scal, p, g, (moments...); outputs alias p + moments —
@@ -256,7 +265,7 @@ def adam_leaf_update(p, g, mu, nu, *, b1: float, b2: float, eps: float,
     lo, hi = clip_const if clip_const else (None, None)
     if ((interpret or _use_pallas()) and _leaf_rows(p) is not None
             and g.dtype == jnp.float32 and mu.dtype == jnp.float32):
-        _count_build("fused_adam", "pallas")
+        count_build("fused_adam", "pallas")
         scal = jnp.stack([
             jnp.asarray(clip_scale if clip_scale is not None else 1.0,
                         jnp.float32),
@@ -269,7 +278,7 @@ def adam_leaf_update(p, g, mu, nu, *, b1: float, b2: float, eps: float,
             use_clip_scale=clip_scale is not None)
         return _pallas_moment_call(kern, scal, [p, g, mu, nu], 3,
                                    interpret)
-    _count_build("fused_adam", "lax")
+    count_build("fused_adam", "lax")
     if clip_scale is not None:
         g = g * clip_scale
     if lo is not None:
@@ -300,7 +309,7 @@ def sgd_leaf_update(p, g, trace, *, momentum: float, nesterov: bool,
     if (trace is not None and (interpret or _use_pallas())
             and _leaf_rows(p) is not None
             and g.dtype == jnp.float32):
-        _count_build("fused_sgd", "pallas")
+        count_build("fused_sgd", "pallas")
         scal = jnp.stack([
             jnp.asarray(clip_scale if clip_scale is not None else 1.0,
                         jnp.float32),
@@ -313,7 +322,7 @@ def sgd_leaf_update(p, g, trace, *, momentum: float, nesterov: bool,
         p_n, t_n = _pallas_moment_call(kern, scal, [p, g, trace], 2,
                                        interpret)
         return p_n, t_n
-    _count_build("fused_sgd", "lax")
+    count_build("fused_sgd", "lax")
     if clip_scale is not None:
         g = g * clip_scale
     if lo is not None:
@@ -503,48 +512,89 @@ def build_fused_update(optim, clip=None) -> Optional[Callable]:
 
 
 # ====================================================== epilogue kernels
-def _epilogue_rows(x, d: int) -> Optional[int]:
-    """(rows, d) layout for an epilogue-eligible activation; None = lax.
-    The last dim must be a 128-lane multiple and the collapsed leading
-    dims an 8-sublane multiple (f32 tile)."""
-    if x.dtype not in (jnp.float32,) or x.ndim < 2 or d % 128:
+# Activations the LayerNorm epilogue runs in-kernel: the ones whose
+# primitives Mosaic lowers on the installed jaxlib.  erf/erfc
+# (``gelu_erf``) and expm1 (``elu``, ``selu``) are not, so those take
+# the lax form.  tests/test_tpu_aot_compile.py compiles every member
+# for the v5e.
+_PALLAS_ACTIVATIONS = frozenset((
+    acts.relu, acts.relu6, acts.tanh, acts.sigmoid, acts.hard_sigmoid,
+    acts.hard_sigmoid_torch, acts.hard_swish, acts.softmax,
+    acts.log_softmax, acts.softplus, acts.softsign, acts.gelu,
+    acts.swish, acts.exp))
+
+
+def _epilogue_row_block(x, d: int) -> Optional[int]:
+    """Row block of the (rows, d) layout for an epilogue-eligible
+    activation; None = lax.  The last dim must be a 128-lane multiple,
+    the collapsed leading dims an 8-sublane multiple (f32 tile), and a
+    row block must fit the VMEM budget: x in and y out are the two
+    blocked operands."""
+    if x.dtype != jnp.float32 or x.ndim < 2 or d % 128:
         return None
     rows = int(np.prod(x.shape[:-1]))
     if rows % 8:
         return None
-    return rows
+    return _row_block(rows, d, x.dtype.itemsize, n_blocked=2)
 
 
-def _bias_gelu_kernel(x_ref, b_ref, o_ref, *, approximate: bool):
-    o_ref[:] = jax.nn.gelu(x_ref[:] + b_ref[:],
-                           approximate=approximate)
+def _row_spec(br: int, d: int):
+    return pl.BlockSpec((br, d), lambda i: (i, 0))
+
+
+def _vec_spec(d: int):
+    return pl.BlockSpec((1, d), lambda i: (0, 0))
+
+
+def _bias_gelu_kernel(x_ref, b_ref, o_ref):
+    o_ref[:] = jax.nn.gelu(x_ref[:] + b_ref[:], approximate=True)
+
+
+def _bias_gelu_lax(x, bias, approximate: bool = True):
+    return jax.nn.gelu(x + bias, approximate=approximate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _bias_gelu_pallas(x, bias, br: int, interpret: bool):
+    d = x.shape[-1]
+    xr = x.reshape(-1, d)
+    out = pl.pallas_call(
+        _bias_gelu_kernel,
+        out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
+        grid=(xr.shape[0] // br,),
+        in_specs=[_row_spec(br, d), _vec_spec(d)],
+        out_specs=_row_spec(br, d),
+        interpret=interpret,
+    )(xr, bias.reshape(1, d))
+    return out.reshape(x.shape)
+
+
+def _bias_gelu_fwd(x, bias, br, interpret):
+    return _bias_gelu_pallas(x, bias, br, interpret), (x, bias)
+
+
+def _bias_gelu_bwd(br, interpret, res, g):
+    # the lax form's own derivative: XLA fuses it into one pass
+    return jax.vjp(_bias_gelu_lax, *res)[1](g)
+
+
+_bias_gelu_pallas.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)
 
 
 def bias_gelu(x, bias, approximate: bool = True,
               interpret: bool = False):
     """Fused bias-add→GeLU epilogue (the dense/FFN tail).  Lax path is
     literally ``gelu(x + bias)`` — identical numerics to the unfused
-    call sites it replaces."""
+    call sites it replaces.  The kernel is the tanh form only: erf has
+    no Mosaic lowering, so ``approximate=False`` is always lax."""
     d = x.shape[-1]
-    rows = _epilogue_rows(x, d)
-    if (interpret or _use_pallas()) and rows is not None \
+    br = _epilogue_row_block(x, d) if approximate else None
+    if (interpret or _use_pallas()) and br is not None \
             and bias.shape == (d,) and bias.dtype == x.dtype:
-        _count_build("bias_gelu", "pallas")
-        xr = x.reshape(rows, d)
-        br = _row_block(rows)
-        out = pl.pallas_call(
-            functools.partial(_bias_gelu_kernel,
-                              approximate=approximate),
-            out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-            grid=(rows // br,),
-            in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
-                      pl.BlockSpec((1, d), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-            interpret=interpret,
-        )(xr, bias.reshape(1, d))
-        return out.reshape(x.shape)
-    _count_build("bias_gelu", "lax")
-    return jax.nn.gelu(x + bias, approximate=approximate)
+        count_build("bias_gelu", "pallas")
+        return _bias_gelu_pallas(x, bias, br, interpret)
+    count_build("bias_gelu", "lax")
+    return _bias_gelu_lax(x, bias, approximate)
 
 
 def _layernorm_act_kernel(x_ref, g_ref, b_ref, o_ref, *, eps: float,
@@ -559,32 +609,7 @@ def _layernorm_act_kernel(x_ref, g_ref, b_ref, o_ref, *, eps: float,
     o_ref[:] = y.astype(o_ref.dtype)
 
 
-def layernorm_act(x, gamma, beta, eps: float = 1e-5,
-                  activation: Optional[Callable] = None,
-                  interpret: bool = False):
-    """Fused LayerNorm→activation.  Lax path mirrors
-    ``layers.normalization.LayerNorm.call`` exactly (biased variance,
-    same op order) followed by the activation."""
-    d = x.shape[-1]
-    rows = _epilogue_rows(x, d)
-    if (interpret or _use_pallas()) and rows is not None \
-            and gamma.shape == (d,) and gamma.dtype == x.dtype:
-        _count_build("layernorm_act", "pallas")
-        xr = x.reshape(rows, d)
-        br = _row_block(rows)
-        out = pl.pallas_call(
-            functools.partial(_layernorm_act_kernel, eps=eps,
-                              activation=activation),
-            out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-            grid=(rows // br,),
-            in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
-                      pl.BlockSpec((1, d), lambda i: (0, 0)),
-                      pl.BlockSpec((1, d), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-            interpret=interpret,
-        )(xr, gamma.reshape(1, d), beta.reshape(1, d))
-        return out.reshape(x.shape)
-    _count_build("layernorm_act", "lax")
+def _layernorm_act_lax(x, gamma, beta, eps: float, activation):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     y = (x - mean) / jnp.sqrt(var + eps)
@@ -592,3 +617,53 @@ def layernorm_act(x, gamma, beta, eps: float = 1e-5,
     if activation is not None:
         y = activation(y)
     return y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _layernorm_act_pallas(x, gamma, beta, eps: float, activation,
+                          br: int, interpret: bool):
+    d = x.shape[-1]
+    xr = x.reshape(-1, d)
+    out = pl.pallas_call(
+        functools.partial(_layernorm_act_kernel, eps=eps,
+                          activation=activation),
+        out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
+        grid=(xr.shape[0] // br,),
+        in_specs=[_row_spec(br, d), _vec_spec(d), _vec_spec(d)],
+        out_specs=_row_spec(br, d),
+        interpret=interpret,
+    )(xr, gamma.reshape(1, d), beta.reshape(1, d))
+    return out.reshape(x.shape)
+
+
+def _layernorm_act_fwd(x, gamma, beta, eps, activation, br, interpret):
+    out = _layernorm_act_pallas(x, gamma, beta, eps, activation, br,
+                                interpret)
+    return out, (x, gamma, beta)
+
+
+def _layernorm_act_bwd(eps, activation, br, interpret, res, g):
+    lax_form = functools.partial(_layernorm_act_lax, eps=eps,
+                                 activation=activation)
+    return jax.vjp(lax_form, *res)[1](g)
+
+
+_layernorm_act_pallas.defvjp(_layernorm_act_fwd, _layernorm_act_bwd)
+
+
+def layernorm_act(x, gamma, beta, eps: float = 1e-5,
+                  activation: Optional[Callable] = None,
+                  interpret: bool = False):
+    """Fused LayerNorm→activation.  Lax path mirrors
+    ``layers.normalization.LayerNorm.call`` exactly (biased variance,
+    same op order) followed by the activation."""
+    d = x.shape[-1]
+    br = _epilogue_row_block(x, d) if (
+        activation is None or activation in _PALLAS_ACTIVATIONS) else None
+    if (interpret or _use_pallas()) and br is not None \
+            and gamma.shape == (d,) and gamma.dtype == x.dtype:
+        count_build("layernorm_act", "pallas")
+        return _layernorm_act_pallas(x, gamma, beta, eps, activation,
+                                     br, interpret)
+    count_build("layernorm_act", "lax")
+    return _layernorm_act_lax(x, gamma, beta, eps, activation)

@@ -29,11 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    _HAS_PALLAS = True
-except Exception:           # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _apply_causal_mask(s, q_start, k_start, block_q: int,
@@ -309,15 +305,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 256,
                     block_k: int = 256, interpret: bool = False):
     """q,k,v: (B, H, T, D) -> (B, H, T, D).  Differentiable (flash
-    backward kernels); falls back to dense XLA attention without
-    Pallas."""
+    backward kernels)."""
     b, h, t, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    if not _HAS_PALLAS:
-        from analytics_zoo_tpu.ops.attention import (
-            scaled_dot_product_attention)
-        return scaled_dot_product_attention(q, k, v, causal=causal,
-                                            scale=scale)
     block_q, block_k = _resolve_blocks(t, block_q, block_k)
     return _flash(q, k, v, (causal, scale, block_q, block_k, interpret))

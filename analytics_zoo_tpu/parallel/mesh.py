@@ -74,22 +74,16 @@ def create_mesh(shape: Optional[Dict[str, int]] = None,
         raise ValueError(
             f"mesh shape {sizes} needs {total} devices, have {n}")
     dims = [sizes[ax] for ax in ALL_AXES]
-    arr = None
-    if devices and getattr(devices[0], "platform", "") == "tpu" \
-            and n > 1:
-        # On real TPU pods, let mesh_utils lay devices out so inner
-        # mesh axes ride ICI and the outermost (data) axis spans
-        # DCN/slices — a plain reshape can put a model axis across
-        # slice boundaries and turn every tensor-parallel collective
-        # into a DCN hop.  Falls back to row-major on any failure
-        # (virtual CPU meshes, exotic topologies).
-        try:
-            from jax.experimental import mesh_utils
-            arr = mesh_utils.create_device_mesh(
-                dims, devices=devices, allow_split_physical_axes=True)
-        except Exception:
-            arr = None
-    if arr is None:
+    if devices and devices[0].platform == "tpu" and n > 1:
+        # On real TPUs mesh_utils lays devices out so inner mesh axes
+        # ride ICI and the outermost (data) axis spans DCN/slices — a
+        # plain reshape can put a model axis across slice boundaries
+        # and turn every tensor-parallel collective into a DCN hop.  A
+        # layout mesh_utils refuses raises: never a quiet row-major.
+        from jax.experimental import mesh_utils
+        arr = mesh_utils.create_device_mesh(
+            dims, devices=devices, allow_split_physical_axes=True)
+    else:
         arr = np.array(devices).reshape(dims)
     return Mesh(arr, ALL_AXES)
 

@@ -81,19 +81,7 @@ def ring_attention(q, k, v, mesh: Mesh, causal: bool = False,
     body = functools.partial(_ring_body, axis_name=axis_name,
                              causal=causal, scale=scale,
                              axis_size=axis_size)
-    fn = _shard_map(body, mesh, (spec, spec, spec), spec)
+    # replication checking off: the ring body is explicitly collective
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
-
-
-def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map(check_vma=)``
-    (new) vs ``jax.experimental.shard_map.shard_map(check_rep=)``
-    (jax<=0.4.x) — replication checking is off either way (the ring
-    body is explicitly collective)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs,
-              out_specs=out_specs, check_rep=False)

@@ -117,16 +117,16 @@ class MultiHeadSelfAttention(Layer):
         # Availability comes from the kernel suite's ONE capability
         # probe (ops/fused.pallas_supported — does this backend compile
         # Pallas?) instead of a backend-name string match.
-        from analytics_zoo_tpu.ops.fused import pallas_supported
+        from analytics_zoo_tpu.ops import fused
         mesh_trivial = math.prod(_mesh().shape.values()) == 1
         use_flash = (not use_sp and mask is None and
-                     pallas_supported() and mesh_trivial and
+                     fused.pallas_supported() and mesh_trivial and
                      t % 256 == 0 and self.head_dim % 64 == 0 and
                      t * self.head_dim <= 4096 * 128)
         if use_flash:
             from analytics_zoo_tpu.ops.pallas_attention import (
                 flash_attention)
-            # 29x over dense XLA attention at T=8k on v5e (O(T·Tb) VMEM)
+            fused.count_build("flash_attention", "pallas")
             ctx = flash_attention(q, k, v, causal=self.causal)
         elif use_sp:
             from analytics_zoo_tpu.parallel.ring_attention import (
@@ -142,6 +142,7 @@ class MultiHeadSelfAttention(Layer):
             attn_mask = None
             if mask is not None:
                 attn_mask = mask[:, None, None, :]   # (B,1,1,Tk)
+            fused.count_build("flash_attention", "lax")
             ctx = scaled_dot_product_attention(
                 q, k, v, mask=attn_mask, causal=self.causal)
 

@@ -43,10 +43,8 @@ def main(argv=None):
     broker = None
     worker = serving = None
     if args.redis_url is None:
-        # self-contained: embedded broker + background worker
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-
+        # self-contained: embedded broker + background worker, on
+        # whatever platform JAX finds (JAX_PLATFORMS=cpu for a host run)
         from analytics_zoo_tpu.pipeline.api.keras import Sequential
         from analytics_zoo_tpu.pipeline.api.keras.layers import (
             Conv2D, Dense, Flatten)

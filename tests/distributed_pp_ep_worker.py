@@ -32,7 +32,6 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
 try:
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 except Exception:

@@ -5,6 +5,8 @@ best number per workload on a shared chip)."""
 import json
 import sys
 
+import pytest
+
 sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
 
 import bench  # noqa: E402  (repo-root module)
@@ -31,7 +33,13 @@ def test_mfu_estimate_known_and_unknown_kind():
 
     # 98.5 TFLOP/s of work on a 197-peak chip -> 0.5
     assert mfu_estimate(98.5e12, 1.0, Dev("TPU v5 lite")) == 0.5
-    assert mfu_estimate(98.5e12, 1.0, Dev("warp9 accelerator")) is None
+    # an accelerator missing from the table is an error, not a default
+    with pytest.raises(KeyError, match="warp9"):
+        mfu_estimate(98.5e12, 1.0, Dev("warp9 accelerator"))
+    # the host platform measures no device utilisation
+    cpu = Dev("cpu")
+    cpu.platform = "cpu"
+    assert mfu_estimate(98.5e12, 1.0, cpu) is None
     assert mfu_estimate(None, 1.0, Dev("TPU v5 lite")) is None
     assert mfu_estimate(1e12, 0.0, Dev("TPU v5 lite")) is None
 

@@ -1,0 +1,138 @@
+"""The kernel suite, compiled for a described v5e at the widths the
+models run — no chip attached, nothing executed.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+device that is described, not attached (the on-chip-measurement guide,
+section 2).  It refuses what interpret mode cannot see: a block that
+overflows scoped VMEM, a primitive Mosaic does not lower, a misaligned
+slice.  These cases guard every later PR at no chip time; they are
+skipped only where the topology cannot be described.  JAX's persistent
+compilation cache is off for the whole suite (tests/conftest.py): such
+a compile is written to it but cannot be read back without a chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from analytics_zoo_tpu.ops import activations as acts
+from analytics_zoo_tpu.ops import fused
+from analytics_zoo_tpu.ops.pallas_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """What one TPU device answers: the Pallas branch.  The CPU backend
+    this test runs on would answer lax."""
+    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
+
+
+def _bias_gelu(x, b):
+    return fused.bias_gelu(x, b)
+
+
+def _layernorm_gelu(x, g, b):
+    return fused.layernorm_act(x, g, b, activation=acts.gelu)
+
+
+def _adam(p, g, m, v):
+    return fused.adam_leaf_update(
+        p, g, m, v, b1=0.9, b2=0.999, eps=1e-8, step_size=-1e-3,
+        bias_corr1=0.1, bias_corr2=0.001, clip_scale=0.5)
+
+
+def _sgd(p, g, t):
+    return fused.sgd_leaf_update(
+        p, g, t, momentum=0.9, nesterov=False, step_size=-0.1,
+        weight_decay=1e-4)
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v)
+
+
+def _grad(fn, n_args):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=tuple(range(n_args)))
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (id, function, argument shapes, dtype, Pallas kernels in the program)
+CASES = [
+    # the two epilogues at rows 16384 (b32 x T512): the FFN width of
+    # BERT-base and its residual width
+    ("bias_gelu-16384x3072", _bias_gelu, [(16384, 3072), (3072,)], F32, 1),
+    ("bias_gelu-16384x768", _bias_gelu, [(16384, 768), (768,)], F32, 1),
+    ("layernorm_act-16384x3072", _layernorm_gelu,
+     [(16384, 3072), (3072,), (3072,)], F32, 1),
+    ("layernorm_act-16384x768", _layernorm_gelu,
+     [(16384, 768), (768,), (768,)], F32, 1),
+    # their backward is the lax derivative: it must compile beside the
+    # kernel, and the forward kernel is dead code in a grad-only program
+    ("bias_gelu-grad-16384x3072", _grad(_bias_gelu, 2),
+     [(16384, 3072), (3072,)], F32, 0),
+    ("layernorm_act-grad-16384x3072", _grad(_layernorm_gelu, 3),
+     [(16384, 3072), (3072,), (3072,)], F32, 0),
+    # ResNet-50's largest conv leaf through the optimizer kernels
+    ("adam-3x3x512x512", _adam, [(3, 3, 512, 512)] * 4, F32, 1),
+    ("sgd-3x3x512x512", _sgd, [(3, 3, 512, 512)] * 3, F32, 1),
+    # flash attention forward and its two backward kernels
+    ("flash-b2h12t512d64", _flash, [(2, 12, 512, 64)] * 3, BF16, 1),
+    ("flash-grad-b2h12t512d64", _grad(_flash, 3),
+     [(2, 12, 512, 64)] * 3, BF16, 3),
+    ("flash-b4h8t4096d128", _flash, [(4, 8, 4096, 128)] * 3, BF16, 1),
+    ("flash-grad-b4h8t4096d128", _grad(_flash, 3),
+     [(4, 8, 4096, 128)] * 3, BF16, 3),
+]
+# every activation the LayerNorm epilogue claims to run in-kernel
+CASES += [
+    (f"layernorm_act-{a.__name__}",
+     lambda x, g, b, a=a: fused.layernorm_act(x, g, b, activation=a),
+     [(64, 768), (768,), (768,)], F32, 1)
+    for a in sorted(fused._PALLAS_ACTIVATIONS, key=lambda f: f.__name__)]
+
+
+@pytest.mark.parametrize("fn,shapes,dtype,kernels",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(v5e, on_tpu, fn, shapes, dtype, kernels):
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=v5e) for s in shapes]
+    # raises what the chip's compiler would raise
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
+
+
+def test_capability_probe_compiles_for_v5e(v5e):
+    """On a TPU a probe the compiler refuses is an error, so the probe
+    itself must be a kernel the v5e accepts."""
+    fn, shapes = fused._probe()
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e)
+            for s in shapes]
+    assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("activation", [acts.gelu_erf, acts.elu, acts.selu],
+                         ids=lambda a: a.__name__)
+def test_activation_mosaic_cannot_lower_takes_lax(v5e, on_tpu, activation):
+    """erf and expm1 have no Mosaic lowering in this jaxlib: those
+    activations must take the lax form, or the program would be refused
+    on the chip and nowhere else."""
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=v5e)
+            for s in [(64, 768), (768,), (768,)]]
+    text = jax.jit(lambda x, g, b: fused.layernorm_act(
+        x, g, b, activation=activation)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
