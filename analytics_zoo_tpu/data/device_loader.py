@@ -20,7 +20,8 @@ from typing import Any, Callable, Iterator, Optional
 import jax
 
 from analytics_zoo_tpu.data.pipeline import DataPipeline
-from analytics_zoo_tpu.data.stages import PrefetchIterator
+from analytics_zoo_tpu.data.stages import (
+    PrefetchIterator, pull_with_wait_spans)
 from analytics_zoo_tpu.observability import get_registry
 from analytics_zoo_tpu.observability.diagnostics import (
     step_attribution_histogram)
@@ -77,11 +78,13 @@ class DeviceLoader:
         # data_wait producer on the DataPipeline path
         self._m_wait = step_attribution_histogram().labels("data_wait")
 
-    def epoch(self) -> Iterator[Any]:
+    def epoch(self, iteration: Optional[int] = None) -> Iterator[Any]:
         """Yield device batches for the pipeline's current epoch from
         its current step; the pipeline position commits per yielded
         batch (exact-resume contract) and rolls to the next epoch at
-        the end."""
+        the end.  ``iteration`` is the training step the first batch
+        feeds: the builders', the placer's and this consumer's spans
+        for one batch then carry the same ``iteration``."""
         pipe = self.pipeline
         epoch, start = pipe.epoch, pipe.step
 
@@ -89,17 +92,17 @@ class DeviceLoader:
             step, batch = pair
             return step, self.put_fn(batch)
 
+        host_batches = pipe.iter_epoch(epoch, start, iteration)
         if self.depth <= 0:   # synchronous fallback
-            placed: Iterator = map(place, pipe.iter_epoch(epoch, start))
+            placed: Iterator = map(place, host_batches)
         else:
             placed = PrefetchIterator(
-                pipe.iter_epoch(epoch, start), self.depth, fn=place,
-                on_depth=self._m_depth.set)
-        import time
-        t0 = time.perf_counter()
+                host_batches, self.depth, fn=place,
+                on_depth=self._m_depth.set, iteration=iteration)
         chaos = active_chaos()
         try:
-            for step, batch in placed:
+            for (step, batch), wait in pull_with_wait_spans(
+                    placed, iteration):
                 if chaos is not None:
                     # fault-injection site, keyed on the pipeline's
                     # epoch step index, tripped BEFORE the position
@@ -110,13 +113,11 @@ class DeviceLoader:
                 # histogram — device-fed consumption is still pipeline
                 # consumption — plus the step-attribution data_wait
                 # component the diagnostics report reads
-                wait = time.perf_counter() - t0
                 pipe._m["wait"].observe(wait)
                 self._m_wait.observe(wait)
                 pipe._m["batches"].inc()
                 pipe.commit(epoch, step + 1)
                 yield batch
-                t0 = time.perf_counter()
         finally:
             # a consumer stopping mid-epoch (end trigger, retry
             # restore, exception) must release the prefetch thread and

@@ -36,7 +36,8 @@ from analytics_zoo_tpu.data.sampler import IndexSampler
 from analytics_zoo_tpu.data.source import Source, as_source
 from analytics_zoo_tpu.data.stages import (
     MapStage, Stage, TransformStage, WorkerPool, run_stages)
-from analytics_zoo_tpu.observability import get_registry
+from analytics_zoo_tpu.observability import get_registry, get_tracer
+from analytics_zoo_tpu.observability.tracing import iteration_args
 
 STATE_VERSION = 1
 
@@ -150,22 +151,33 @@ class DataPipeline:
         return self
 
     # ------------------------------------------------------- batch assembly
-    def _build_batch(self, sel_mask: Tuple[np.ndarray, np.ndarray]):
+    def _build_batch(self, sel_mask: Tuple[np.ndarray, np.ndarray],
+                     iteration: Optional[int] = None):
+        """Gather and run the stages: one ``data_build`` span a batch,
+        on whichever thread builds it."""
         sel, mask = sel_mask
-        batch = run_stages(self.source.gather(sel), self.stages)
+        with get_tracer().span("data_build", jax_annotation=True,
+                               **iteration_args(iteration)):
+            batch = run_stages(self.source.gather(sel), self.stages)
         if self.sampler.remainder == "pad":
             if isinstance(batch, tuple):
                 return batch + (mask,)
             return (batch, mask)
         return batch
 
-    def iter_epoch(self, epoch: int, start_step: int = 0
+    def iter_epoch(self, epoch: int, start_step: int = 0,
+                   iteration: Optional[int] = None
                    ) -> Iterator[Tuple[int, Any]]:
         """Pure read of ``(step, batch)`` pairs for one epoch — does
         NOT move the pipeline position (``commit`` does).  Resumable
         from any step; with ``num_workers`` the batches are assembled
-        in the pool, ordered."""
+        in the pool, ordered.  ``iteration`` is the training step that
+        ``start_step``'s batch feeds, for the builders' spans."""
         steps = self.sampler.iter_epoch(epoch, start_step)
+
+        def at(step):
+            return None if iteration is None \
+                else iteration + step - start_step
         if self.num_workers > 0:
             if self._pool is None:
                 self._pool = WorkerPool(self.num_workers,
@@ -174,13 +186,13 @@ class DataPipeline:
 
             def build(pair):
                 step, sel_mask = pair
-                return step, self._build_batch(sel_mask)
+                return step, self._build_batch(sel_mask, at(step))
 
             yield from self._pool.imap(
                 build, pairs, on_depth=self._m["qdepth"].set)
         else:
             for step, sel, mask in steps:
-                yield step, self._build_batch((sel, mask))
+                yield step, self._build_batch((sel, mask), at(step))
 
     # ------------------------------------------------------------ position
     @property

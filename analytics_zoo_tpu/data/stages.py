@@ -13,13 +13,18 @@ reuses them: ``ClusterServing`` runs its JPEG decode through the same
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 import jax
+
+from analytics_zoo_tpu.observability import get_registry, get_tracer
+from analytics_zoo_tpu.observability.tracing import iteration_args
 
 
 class Stage:
@@ -165,6 +170,26 @@ class WorkerPool:
         self.shutdown()
 
 
+def pull_with_wait_spans(placed: Iterator, iteration: Optional[int] = None,
+                         stride: int = 1) -> Iterator:
+    """The consumer's side of a prefetch: ``(item, seconds waited)``
+    for every item of ``placed``, each pull inside a ``data_wait`` span
+    (the consumer blocked on the next placed batch).  ``iteration`` is
+    the training step the first item feeds, ``stride`` the steps an
+    item covers.  The wait runs from the consumer's request to the
+    item's arrival."""
+    tracer = get_tracer()
+    for k in itertools.count():
+        t0 = time.perf_counter()
+        with tracer.span("data_wait", jax_annotation=True,
+                         **iteration_args(iteration, k * stride)):
+            try:
+                item = next(placed)
+            except StopIteration:
+                return
+        yield item, time.perf_counter() - t0
+
+
 class PrefetchIterator:
     """Background-thread prefetch over any iterator with queue-depth
     and wait-time instrumentation fed by the caller.
@@ -176,14 +201,23 @@ class PrefetchIterator:
     the consumer; the consumer stops early by just abandoning the
     iterator (daemon thread + bounded queue => no leak beyond ``depth``
     buffered items).
+
+    On the training timeline the thread's pull is a ``data_assemble``
+    span and ``fn`` a ``data_place`` span whose ``bytes`` are the host
+    arrays handed to it (also counted in ``data_h2d_bytes_total``).
+    ``iteration`` is the training step the first item feeds, ``stride``
+    the steps an item covers.
     """
 
     _END = object()
 
     def __init__(self, source_iter: Iterable, depth: int,
                  fn: Optional[Callable] = None,
-                 on_depth: Optional[Callable[[int], None]] = None):
+                 on_depth: Optional[Callable[[int], None]] = None,
+                 iteration: Optional[int] = None, stride: int = 1):
         self.depth = max(int(depth), 1)
+        self._iteration = iteration
+        self._stride = int(stride)
         self._q: "queue.Queue" = queue.Queue(maxsize=self.depth)
         self._on_depth = on_depth
         self._fn = fn
@@ -207,10 +241,28 @@ class PrefetchIterator:
         return False
 
     def _worker(self):
+        tracer = get_tracer()
+        h2d_bytes = get_registry().counter(
+            "data_h2d_bytes_total",
+            "bytes of host batches handed to the device placement")
         try:
-            for item in self._src:
+            src = iter(self._src)
+            for k in itertools.count():
+                at = iteration_args(self._iteration, k * self._stride)
+                with tracer.span("data_assemble", jax_annotation=True,
+                                 **at):
+                    try:
+                        item = next(src)
+                    except StopIteration:
+                        break
                 if self._fn is not None:
-                    item = self._fn(item)
+                    nbytes = sum(
+                        getattr(leaf, "nbytes", 0)
+                        for leaf in jax.tree_util.tree_leaves(item))
+                    with tracer.span("data_place", jax_annotation=True,
+                                     bytes=nbytes, **at):
+                        item = self._fn(item)
+                    h2d_bytes.inc(nbytes)
                 if not self._put(item):
                     return
             self._put(self._END)

@@ -5,16 +5,24 @@ The reference's time visibility was coarse driver-side ``Utils.timeIt``
 log lines; ``jax.profiler`` covers the device side but not host
 orchestration (batch assembly, checkpoint IO, Redis round trips).  Spans
 fill that gap: a bounded in-memory ring of complete ("ph":"X") events,
-cheap enough to leave on in production (two perf_counter reads and a
-deque append per span).
+cheap enough to leave on in production (two perf_counter reads, a
+deque append and three counter increments per span).
+
+Each event keeps its ``parent`` (the enclosing span's name on that
+thread).  At a span's end the tracer also counts, by span name, into
+the shared registry: ``span_seconds_total``, ``span_self_seconds_total``
+(the duration less what the spans nested in it on the same thread
+cover) and ``spans_total`` — the reading a benchmark bounds with two
+registry snapshots.
 
 Interval math uses ``time.perf_counter`` (monotonic); the wall-clock
 epoch is recorded once so exported timestamps still line up with log
 timestamps.
 
 ``span(..., jax_annotation=True)`` additionally brackets the block with
-``jax.profiler.TraceAnnotation`` so the same name shows up inside a
-captured device profile.
+``jax.profiler.TraceAnnotation`` carrying the span's ``args``, so the
+same name lands on the host planes of a captured device profile, on the
+profiler's clock.  Without a profiler session that is a flag test.
 """
 
 from __future__ import annotations
@@ -26,6 +34,67 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from analytics_zoo_tpu.observability.metrics import get_registry
+
+try:    # once, at module load: a span must not pay an import
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except Exception:   # noqa: BLE001 — profiler unavailable: spans still record
+    _TraceAnnotation = None
+
+
+# The training timeline's spans, by the thread that records them: the
+# names a captured profile is read by (dev/trace-summary) and the span
+# counters are labelled with.  docs/observability.md says what each is
+# around.
+TRAIN_TIMELINE_SPANS = {
+    "main": ("train_epoch_scan", "train_dispatch", "train_step",
+             "train_permute", "train_loss_sync", "train_device_sync",
+             "train_boundary", "data_wait", "checkpoint_save",
+             "checkpoint_restore", "eval", "aot_warm_start"),
+    "prefetch": ("data_assemble", "data_place"),
+    "worker": ("data_build",),
+    "callback": ("callback_finite_check", "callback_grad_norm"),
+}
+
+
+class _Span:
+    """One open span: a frame on its thread's stack, which collects
+    the seconds of the spans nested in it."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_annotation", "_frame",
+                 "_parent", "_start")
+
+    def __init__(self, tracer, name, annotate, args):
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        self._annotation = _TraceAnnotation(name, **args) \
+            if annotate and _TraceAnnotation is not None else None
+
+    def __enter__(self):
+        stack = self._tracer._stack()
+        self._parent = stack[-1][0] if stack else None
+        self._frame = [self._name, 0.0]   # name, children's seconds
+        stack.append(self._frame)
+        self._start = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self._tracer
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        dur = time.perf_counter() - self._start
+        tracer = self._tracer
+        stack = tracer._stack()
+        stack.pop()
+        if stack:
+            stack[-1][1] += dur
+        tracer._record(self._name, self._start, dur, self._parent,
+                       self._args)
+        tracer._count(self._name, dur, max(dur - self._frame[1], 0.0))
+        return False
 
 
 class Tracer:
@@ -46,66 +115,83 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._wall0 = time.time()
         self.enabled = True
+        self._disabled_span = contextlib.nullcontext(self)
+        # the three span counters' children by span name, for the
+        # registry they were made in (tests swap the registry)
+        self._registry = None
+        self._series: Dict[str, tuple] = {}
 
     # ------------------------------------------------------------- spans
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[list]:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
 
-    @contextlib.contextmanager
     def span(self, name: str, jax_annotation: bool = False, **args):
         """Time a block as one trace event.  ``args`` become the
         event's Chrome-trace ``args`` dict (values must be
-        JSON-serializable)."""
+        JSON-serializable) and, with ``jax_annotation``, the
+        annotation's.  The context manager yields the tracer."""
         if not self.enabled:
-            yield self
-            return
-        ctx = contextlib.nullcontext()
-        if jax_annotation:
-            try:
-                import jax.profiler
-                ctx = jax.profiler.TraceAnnotation(name)
-            except Exception:  # profiler unavailable — span still records
-                pass
-        stack = self._stack()
-        stack.append(name)
-        start = time.perf_counter()
+            return self._disabled_span
+        return _Span(self, name, jax_annotation, args)
+
+    def _record(self, name: str, start_perf: float, duration_s: float,
+                parent: Optional[str], args: Dict) -> None:
+        event = {
+            "name": name, "ph": "X",
+            "ts": (start_perf - self._t0) * 1e6,
+            "dur": duration_s * 1e6,
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "parent": parent,
+        }
+        if args:
+            event["args"] = args
+        # the ring lock pairs with events()/clear(): appends must not
+        # rely on the GIL for exclusion (free-threaded builds)
+        with self._lock:
+            self._events.append(event)
+
+    def _count(self, name: str, duration_s: float, self_s: float) -> None:
+        """The window-bounded reading: seconds, self seconds and count
+        by span name.  Never raises (spans run on the runtime's
+        callback thread too)."""
         try:
-            with ctx:
-                yield self
-        finally:
-            dur = time.perf_counter() - start
-            stack.pop()
-            # the ring lock pairs with events()/clear(): appends must
-            # not rely on the GIL for exclusion (free-threaded builds)
-            with self._lock:
-                self._events.append({
-                    "name": name,
-                    "ph": "X",
-                    "ts": (start - self._t0) * 1e6,
-                    "dur": dur * 1e6,
-                    "pid": os.getpid(),
-                    "tid": threading.get_ident(),
-                    **({"args": args} if args else {}),
-                })
+            reg = get_registry()
+            if reg is not self._registry:
+                self._registry, self._series = reg, {}
+            series = self._series.get(name)
+            if series is None:
+                series = self._series[name] = (
+                    reg.counter(
+                        "span_seconds_total",
+                        "seconds inside spans, by span name",
+                        labels=("name",)).labels(name),
+                    reg.counter(
+                        "span_self_seconds_total",
+                        "span seconds less what the spans nested in "
+                        "them on the same thread cover",
+                        labels=("name",)).labels(name),
+                    reg.counter(
+                        "spans_total", "spans ended, by span name",
+                        labels=("name",)).labels(name))
+            series[0].inc(duration_s)
+            series[1].inc(self_s)
+            series[2].inc()
+        except Exception:   # noqa: BLE001 — never breaks the traced code
+            pass
 
     def complete(self, name: str, start_perf: float, duration_s: float,
                  **args) -> None:
         """Record a complete span from explicit timing (non-lexical
         scopes — e.g. an epoch whose end is reached via several code
-        paths).  ``start_perf`` is a ``time.perf_counter()`` reading."""
+        paths).  ``start_perf`` is a ``time.perf_counter()`` reading.
+        Not on the stack: it has no parent and no self time, and is
+        not counted."""
         if not self.enabled:
             return
-        with self._lock:
-            self._events.append({
-                "name": name, "ph": "X",
-                "ts": (start_perf - self._t0) * 1e6,
-                "dur": duration_s * 1e6,
-                "pid": os.getpid(), "tid": threading.get_ident(),
-                **({"args": args} if args else {}),
-            })
+        self._record(name, start_perf, duration_s, None, args)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker event (``ph: "i"``)."""
@@ -152,7 +238,7 @@ class Tracer:
 
     def current_span(self) -> Optional[str]:
         stack = self._stack()
-        return stack[-1] if stack else None
+        return stack[-1][0] if stack else None
 
     def depth(self) -> int:
         return len(self._stack())
@@ -229,6 +315,13 @@ def reset_tracer() -> None:
     global _global_tracer
     with _tracer_lock:
         _global_tracer = None
+
+
+def iteration_args(first: Optional[int], offset: int = 0) -> Dict:
+    """The ``iteration`` argument of a hot-path span: the training
+    step the work belongs to, ``first + offset``; nothing where the
+    caller knows no step (an eval pass, a bare loader)."""
+    return {} if first is None else {"iteration": int(first) + offset}
 
 
 def span(name: str, **kwargs):

@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional
 
 from analytics_zoo_tpu.observability.metrics import (
     MetricsRegistry, get_registry)
+from analytics_zoo_tpu.observability.tracing import get_tracer
 
 log = logging.getLogger("analytics_zoo_tpu.observability")
 
@@ -347,15 +348,17 @@ def record_step_finiteness(finite) -> None:
     ``isfinite(loss + Σ grads)`` flag lands here on host.  Must never
     raise (it runs on the callback thread inside the runtime)."""
     try:
-        if bool(finite):
-            return
-        wd = get_active_watchdog()
-        if wd is not None:
-            wd.record_nonfinite("step")
-        else:
-            get_registry().counter(
-                "train_nonfinite_total",
-                "steps whose loss or gradients were non-finite",
-                labels=("source",)).labels("step").inc()
+        with get_tracer().span("callback_finite_check",
+                               jax_annotation=True):
+            if bool(finite):
+                return
+            wd = get_active_watchdog()
+            if wd is not None:
+                wd.record_nonfinite("step")
+            else:
+                get_registry().counter(
+                    "train_nonfinite_total",
+                    "steps whose loss or gradients were non-finite",
+                    labels=("source",)).labels("step").inc()
     except Exception:
         pass

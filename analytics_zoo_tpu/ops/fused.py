@@ -87,7 +87,8 @@ def _probe():
         grid=(2,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk],
         out_specs=blk,
-        input_output_aliases={1: 0})(s, a))
+        input_output_aliases={1: 0},
+        name="pallas_probe")(s, a))
     return fn, (jax.ShapeDtypeStruct((4,), jnp.float32),
                 jax.ShapeDtypeStruct((16, 128), jnp.float32))
 
@@ -221,9 +222,10 @@ def _sgd_kernel(scal_ref, p_ref, g_ref, t_ref, po_ref, to_ref, *,
 
 
 def _pallas_moment_call(kernel, scal, arrays, n_out: int,
-                        interpret: bool):
+                        interpret: bool, name: str):
     """Dispatch a per-leaf optimizer kernel over the (rows, 128)
-    re-layout, params/moments aliased in place."""
+    re-layout, params/moments aliased in place.  ``name`` is the
+    kernel's name in a compiled program and in a profile."""
     rows = _leaf_rows(arrays[0])
     shaped = [a.reshape(rows, 128) for a in arrays]
     # _leaf_rows guarantees rows % 8 == 0, and 8 rows of 128 lanes fit
@@ -246,6 +248,7 @@ def _pallas_moment_call(kernel, scal, arrays, n_out: int,
         out_specs=tuple(blk for _ in range(n_out)),
         input_output_aliases=aliases,
         interpret=interpret,
+        name=name,
     )(scal, *shaped)
     shape = arrays[0].shape
     return tuple(o.reshape(shape) for o in outs)
@@ -277,7 +280,7 @@ def adam_leaf_update(p, g, mu, nu, *, b1: float, b2: float, eps: float,
             weight_decay=weight_decay, clip_lo=lo, clip_hi=hi,
             use_clip_scale=clip_scale is not None)
         return _pallas_moment_call(kern, scal, [p, g, mu, nu], 3,
-                                   interpret)
+                                   interpret, "fused_adam")
     count_build("fused_adam", "lax")
     if clip_scale is not None:
         g = g * clip_scale
@@ -320,7 +323,7 @@ def sgd_leaf_update(p, g, trace, *, momentum: float, nesterov: bool,
             weight_decay=weight_decay, clip_lo=lo, clip_hi=hi,
             use_clip_scale=clip_scale is not None)
         p_n, t_n = _pallas_moment_call(kern, scal, [p, g, trace], 2,
-                                       interpret)
+                                       interpret, "fused_sgd")
         return p_n, t_n
     count_build("fused_sgd", "lax")
     if clip_scale is not None:
@@ -565,6 +568,7 @@ def _bias_gelu_pallas(x, bias, br: int, interpret: bool):
         in_specs=[_row_spec(br, d), _vec_spec(d)],
         out_specs=_row_spec(br, d),
         interpret=interpret,
+        name="bias_gelu",
     )(xr, bias.reshape(1, d))
     return out.reshape(x.shape)
 
@@ -632,6 +636,7 @@ def _layernorm_act_pallas(x, gamma, beta, eps: float, activation,
         in_specs=[_row_spec(br, d), _vec_spec(d), _vec_spec(d)],
         out_specs=_row_spec(br, d),
         interpret=interpret,
+        name="layernorm_act",
     )(xr, gamma.reshape(1, d), beta.reshape(1, d))
     return out.reshape(x.shape)
 

@@ -212,6 +212,7 @@ def _flash_fwd_impl(q, k, v, cfg):
                    pl.BlockSpec((None, block_q, 1),
                                 lambda i, j: (i, j, 0))),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, t, d), lse
 
@@ -258,6 +259,7 @@ def _flash_vjp_bwd(cfg, res, dout):
         out_specs=pl.BlockSpec((None, block_q, d),
                                lambda i, j: (i, j, 0)),
         interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, dof, lse, delta)
 
     dkv_kernel = functools.partial(_flash_dkv_kernel, block_k=block_k,
@@ -290,6 +292,7 @@ def _flash_vjp_bwd(cfg, res, dout):
                    pl.BlockSpec((None, block_k, d),
                                 lambda i, jk, jq: (i, jk, 0))),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qf, kf, vf, dof, lse, delta)
     dk = dk.astype(k.dtype)
     dv = dv.astype(v.dtype)

@@ -46,12 +46,15 @@ from analytics_zoo_tpu.resilience.chaos import (
 
 def _record_grad_norm(gnorm) -> None:
     """Host callback target: surface the in-jit global grad norm as a
-    gauge (debug.callback delivers a host copy after the step runs)."""
+    gauge (debug.callback delivers a host copy after the step runs).
+    Runs on the runtime's callback thread: never raises."""
     try:
-        get_registry().gauge(
-            "train_grad_norm",
-            "global L2 gradient norm (observability.grad_norm=true)"
-        ).set(float(gnorm))
+        with get_tracer().span("callback_grad_norm",
+                               jax_annotation=True):
+            get_registry().gauge(
+                "train_grad_norm",
+                "global L2 gradient norm (observability.grad_norm=true)"
+            ).set(float(gnorm))
     except Exception:
         pass
 
@@ -329,8 +332,11 @@ class DistributedTrainer:
             # recompute the forward during the backward instead of
             # storing activations (train.remat) — see config.py
             objective = jax.checkpoint(objective)
-        grads, (new_state, loss) = jax.grad(
-            objective, has_aux=True)(params)
+        # the three scopes are names only (op_name metadata): a trace's
+        # reduction finds a phase whatever implements it
+        with jax.named_scope("forward_loss"):
+            grads, (new_state, loss) = jax.grad(
+                objective, has_aux=True)(params)
         if self._obs_grad_norm:
             # surfaces the norm on host after each step without
             # changing the step's signature; opt-in because the
@@ -342,21 +348,23 @@ class DistributedTrainer:
             # program; the flag surfaces asynchronously through the
             # same callback path as the grad norm — the driver's
             # watchdog polls it between steps
-            fold_finiteness_check(loss, grads)
+            with jax.named_scope("finite_check"):
+                fold_finiteness_check(loss, grads)
         if self.grad_sync_dtype == "bfloat16":
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.bfloat16).astype(jnp.float32),
                 grads)
-        if self._fused_update is not None:
-            # single-pass clip+moments+apply (ops/fused.py), numerically
-            # the optax triple pass below — proven by
-            # tests/test_fused_kernels.py
-            new_params, new_opt_state = self._fused_update(
-                grads, opt_state, params)
-        else:
-            grads = _apply_clipping(grads, clip)
-            new_params, new_opt_state = self._optimizer_update(
-                grads, opt_state, params)
+        with jax.named_scope("optimizer_update"):
+            if self._fused_update is not None:
+                # single-pass clip+moments+apply (ops/fused.py),
+                # numerically the optax triple pass below — proven by
+                # tests/test_fused_kernels.py
+                new_params, new_opt_state = self._fused_update(
+                    grads, opt_state, params)
+            else:
+                grads = _apply_clipping(grads, clip)
+                new_params, new_opt_state = self._optimizer_update(
+                    grads, opt_state, params)
         new_params = mask_frozen_params(model, params, new_params)
         return new_params, new_opt_state, new_state, loss
 
@@ -380,9 +388,11 @@ class DistributedTrainer:
         # live MFU gauge (diagnostics.CompileMonitor)
         return self._monitor.wrap("train_step", jitted)
 
-    def _dispatch_instrumented(self, fn, *args):
+    def _dispatch_instrumented(self, fn, *args, iteration=None):
         """One step dispatch wrapped in a train_step span + the
-        per-step latency histogram and step counter.
+        per-step latency histogram and step counter.  ``iteration`` is
+        the training step this dispatch runs, for the timeline; a
+        caller that keeps no count gets this trainer's dispatch index.
 
         Step-time attribution: every dispatch observes its host wall
         (``host_dispatch``); every N-th dispatch additionally brackets
@@ -396,13 +406,17 @@ class DistributedTrainer:
             # at step k leaves exactly k committed steps and donates
             # no buffer to a doomed dispatch (resilience/chaos.py)
             chaos.trip(SITE_TRAINER_DISPATCH, self._dispatch_count)
+        if iteration is None:
+            iteration = self._dispatch_count
         self._dispatch_count += 1
         sample_device = (self._obs_device_every > 0 and
                          self._dispatch_count % self._obs_device_every
                          == 0)
         if self._collective_bytes is None and args:
             self._collective_bytes = self._estimate_collectives(args[0])
-        with get_tracer().span("train_step"):
+        tracer = get_tracer()
+        with tracer.span("train_step", jax_annotation=True,
+                         iteration=iteration, steps=1, path="per_step"):
             t0 = time.perf_counter()
             out = fn(*args)
             dispatch_s = time.perf_counter() - t0
@@ -410,16 +424,21 @@ class DistributedTrainer:
             self._m_step_time.labels("host_dispatch").observe(
                 dispatch_s)
             if sample_device:
-                try:
-                    jax.block_until_ready(out)
-                    device_s = time.perf_counter() - t0
-                except Exception:
-                    device_s = None
-                if device_s is not None:
-                    self._m_step_time.labels("device").observe(device_s)
-                    self._m_device_step.set(device_s)
-                    publish_mfu("train_step", device_s)
-                self._probe_barrier_wait()
+                # the host blocks here: a child span, so that
+                # train_step's self time stays the dispatch
+                with tracer.span("train_device_sync", jax_annotation=True,
+                                 iteration=iteration):
+                    try:
+                        jax.block_until_ready(out)
+                        device_s = time.perf_counter() - t0
+                    except Exception:
+                        device_s = None
+                    if device_s is not None:
+                        self._m_step_time.labels("device").observe(
+                            device_s)
+                        self._m_device_step.set(device_s)
+                        publish_mfu("train_step", device_s)
+                    self._probe_barrier_wait()
         if self._collective_bytes:
             from analytics_zoo_tpu.observability.collectives import (
                 record_step_collectives)
@@ -513,7 +532,7 @@ class DistributedTrainer:
             self._train_step_at = self._build_train_step(fold_rng=True)
         return self._dispatch_instrumented(
             self._train_step_at, params, opt_state, state, batch, rng,
-            step)
+            step, iteration=int(step))
 
     # ----------------------------------------------------- AOT warm-start
     def warm_start(self, params, opt_state, state, host_batch,
@@ -538,7 +557,8 @@ class DistributedTrainer:
                 self._train_step_at = self._build_train_step(
                     fold_rng=True)
             batch = self.put_batch(host_batch)
-            with get_tracer().span("aot_warm_start"):
+            with get_tracer().span("aot_warm_start",
+                                   jax_annotation=True):
                 # _MonitoredJit forwards .warm to the EngineJit
                 return bool(self._train_step_at.warm(
                     params, opt_state, state, batch, rng, np.int32(0)))
@@ -824,57 +844,41 @@ class DistributedTrainer:
             lambda a: jax.device_put(jnp.array(a, copy=True), self._rep),
             tree)
 
-    def prefetch(self, batches, depth: Optional[int] = None):
+    def prefetch(self, batches, depth: Optional[int] = None,
+                 iteration: Optional[int] = None, stride: int = 1):
         """Overlap host batch assembly + H2D transfer with device compute.
 
         A background thread pulls host batches, places them on the mesh
         (``put_batch``) and queues them ``depth`` deep — the analogue of
         the reference's MTSampleToMiniBatch worker threads feeding the
-        training tasks (MTSampleToMiniBatch.scala:28).
+        training tasks (MTSampleToMiniBatch.scala:28).  ``iteration``
+        is the training step the first batch feeds and ``stride`` the
+        steps a batch covers: the producer's spans and the consumer's
+        ``data_wait`` for one batch then carry the same ``iteration``.
         """
-        import queue
-        import threading
+        from analytics_zoo_tpu.data.stages import (
+            PrefetchIterator, pull_with_wait_spans)
         if depth is None:
             depth = int(get_config().get("data.prefetch"))
         wait_hist = self._m_step_time.labels("data_wait")
         if depth <= 0:
-            it = iter(batches)
-            while True:
-                # data_wait here covers host batch assembly + H2D —
-                # the whole input-side cost the device waits on
-                t0 = time.perf_counter()
-                try:
-                    b = next(it)
-                except StopIteration:
-                    return
-                placed = self.put_batch(b)
-                wait_hist.observe(time.perf_counter() - t0)
-                yield placed
-        q: "queue.Queue" = queue.Queue(maxsize=depth)
-        _END = object()
-
-        def worker():
-            try:
-                for b in batches:
-                    q.put(self.put_batch(b))
-                q.put(_END)
-            except BaseException as e:   # propagate into consumer
-                q.put(e)
-
-        t = threading.Thread(target=worker, daemon=True)
-        t.start()
-        while True:
-            # sampled before the dequeue so a full steady-state
-            # pipeline reads `depth`, not depth-1
-            self._m_prefetch_depth.set(q.qsize())
-            t0 = time.perf_counter()
-            item = q.get()
-            if item is _END:
-                self._m_prefetch_depth.set(0)
-                break
-            if isinstance(item, BaseException):
-                raise item
-            # attribution: how long the consumer stalled waiting for
-            # the next device-placed batch (0 ≈ input keeps up)
-            wait_hist.observe(time.perf_counter() - t0)
-            yield item
+            # data_wait here covers host batch assembly + H2D — the
+            # whole input-side cost the device waits on
+            placed = map(self.put_batch, batches)
+        else:
+            placed = PrefetchIterator(
+                batches, depth, fn=self.put_batch,
+                on_depth=self._m_prefetch_depth.set,
+                iteration=iteration, stride=stride)
+        try:
+            for item, wait in pull_with_wait_spans(placed, iteration,
+                                                   stride):
+                # attribution: how long the consumer stalled waiting
+                # for the next device-placed batch (0 ≈ input keeps up)
+                wait_hist.observe(wait)
+                yield item
+        finally:
+            # a consumer that stops early releases the thread and the
+            # device batches it buffered
+            if isinstance(placed, PrefetchIterator):
+                placed.close()

@@ -290,7 +290,7 @@ class Estimator:
             replay-the-epoch semantics."""
             if ckpt is None:
                 return None
-            with tracer.span("checkpoint_restore"):
+            with tracer.span("checkpoint_restore", jax_annotation=True):
                 try:
                     restored = ckpt.restore_latest(like)
                 except (ValueError, KeyError):
@@ -375,7 +375,8 @@ class Estimator:
             # model_dir is on a filesystem all hosts can read.
             # ``target`` overrides the destination Checkpoint (the
             # watchdog's halt snapshot goes to model_dir/halt/).
-            with tracer.span("checkpoint_save", iteration=ts.iteration):
+            with tracer.span("checkpoint_save", jax_annotation=True,
+                             iteration=ts.iteration):
                 payload = {"params": mesh_lib.fetch_global(params),
                            "state": mesh_lib.fetch_global(state),
                            "opt_state": mesh_lib.fetch_global(opt_state),
@@ -486,7 +487,8 @@ class Estimator:
             release the cache and retry streaming from host."""
             t0 = time.perf_counter()
             try:
-                with tracer.span("eval"):
+                with tracer.span("eval", jax_annotation=True,
+                                 iteration=ts.iteration):
                     if eval_cache_holder[0] is not None:
                         try:
                             return eval_runner(params, state,
@@ -503,12 +505,19 @@ class Estimator:
             finally:
                 met["eval_seconds"].observe(time.perf_counter() - t0)
 
+        def sync_loss(loss, it0) -> float:
+            """Every host read of a loss: the host blocks here until
+            the dispatch that produced it has run."""
+            with tracer.span("train_loss_sync", jax_annotation=True,
+                             iteration=it0):
+                return float(loss)
+
         def log_loss_crossing(loss, k):
             """Sync + log when the iteration counter crosses a
             20-multiple (same cadence as the per-step path, without a
             device sync per dispatch)."""
             if (ts.iteration // 20) != ((ts.iteration - k) // 20):
-                ts.last_loss = float(loss)
+                ts.last_loss = sync_loss(loss, ts.iteration - k)
                 met["loss"].set(ts.last_loss)
                 # already-synced loss → watchdog divergence/plateau/
                 # NaN detection at zero extra device cost
@@ -516,6 +525,28 @@ class Estimator:
                 if self._train_summary is not None:
                     self._train_summary.add_scalar(
                         "Loss", ts.last_loss, ts.iteration)
+
+        def boundary(it0, loss, k, fused=False, epoch_loss=False) -> bool:
+            """The host's work between two dispatches, as ONE span
+            (``it0``: the first step of the dispatch just made, ``k``
+            its steps; ``fused``: a scan dispatch, whose collectives
+            are accounted here; ``epoch_loss``: ``ts.last_loss`` was
+            read at this dispatch's end).  Returns whether the end
+            trigger fired."""
+            with tracer.span("train_boundary", jax_annotation=True,
+                             iteration=it0):
+                if fused:
+                    trainer.account_collectives(params, k)
+                log_loss_crossing(loss, k)
+                beat()
+                if epoch_loss:
+                    observe_loss_once(ts.last_loss)
+                health_check()
+                # iteration-level triggers fire mid-epoch; EveryEpoch
+                # (all the scan engines admit) answers False here
+                if ckpt is not None and checkpoint_trigger(ts):
+                    save_snapshot()
+                return bool(end_trigger(ts))
 
         # AOT warm-start (docs/aot-compile.md): pre-lower-and-compile
         # the per-step train program — deserialized from the
@@ -562,6 +593,7 @@ class Estimator:
                 # monotonic clock for the epoch interval: wall-clock
                 # adjustments must not produce negative/garbage durations
                 epoch_start = time.perf_counter()
+                epoch_it0 = ts.iteration
                 seen = 0
                 loss = None
                 num_slices = getattr(train_set, "num_slices", 1)
@@ -572,35 +604,37 @@ class Estimator:
                         # and commits the pipeline position per batch
                         # consumed, so any checkpoint below captures
                         # the exact next batch
-                        for batch in device_loader.epoch():
+                        for batch in device_loader.epoch(
+                                iteration=ts.iteration):
                             params, opt_state, state, loss = \
                                 trainer.train_step_at(
                                     params, opt_state, state, batch,
                                     rng, np.int32(ts.iteration))
                             ts.iteration += 1
                             seen += batch_size
-                            log_loss_crossing(loss, 1)
-                            beat()
-                            health_check()
-                            if ckpt is not None and \
-                                    checkpoint_trigger(ts):
-                                save_snapshot()
-                            if end_trigger(ts):
+                            if boundary(ts.iteration - 1, loss, 1):
                                 stop = True
                                 break
                     elif hbm_src is not None:
                         try:
                             xs, ys = hbm_src
                             if train_set.shuffle:
-                                perm = train_set._epoch_perm(
-                                    ts.epoch)[:epoch_rows].astype(np.int32)
-                                xe, ye = hbm_permute(xs, ys, perm)
+                                with tracer.span("train_permute",
+                                                 jax_annotation=True,
+                                                 iteration=ts.iteration):
+                                    perm = train_set._epoch_perm(
+                                        ts.epoch)[:epoch_rows].astype(
+                                            np.int32)
+                                    xe, ye = hbm_permute(xs, ys, perm)
                             else:
                                 # unshuffled: the scan slices the source
                                 # in order; no gather, no second copy
                                 xe, ye = xs, ys
                             with tracer.span("train_epoch_scan",
-                                             steps=nb_epoch):
+                                             jax_annotation=True,
+                                             iteration=ts.iteration,
+                                             steps=nb_epoch,
+                                             path="epoch_scan"):
                                 params, opt_state, state, loss = hbm_scan(
                                     params, opt_state, state, xe, ye, rng,
                                     np.int32(ts.iteration))
@@ -615,7 +649,7 @@ class Estimator:
                             # return before the program completes). One
                             # scalar read per epoch on a
                             # one-dispatch-per-epoch path.
-                            ts.last_loss = float(loss)
+                            ts.last_loss = sync_loss(loss, ts.iteration)
                             # drop the permuted copy eagerly: holding it
                             # across epochs would put THREE epoch-sized
                             # buffers live at the next permute (source +
@@ -678,19 +712,18 @@ class Estimator:
                         ts.iteration += nb_epoch
                         seen += epoch_rows
                         met["steps"].labels("epoch_scan").inc(nb_epoch)
-                        trainer.account_collectives(params, nb_epoch)
-                        log_loss_crossing(loss, nb_epoch)
-                        beat()
-                        observe_loss_once(ts.last_loss)
-                        health_check()
-                        if end_trigger(ts):
+                        if boundary(ts.iteration - nb_epoch, loss,
+                                    nb_epoch, fused=True,
+                                    epoch_loss=True):
                             stop = True
                     elif use_chunks:
                         global_rows = mesh_lib.global_batch_rows(
                             trainer.mesh, batch_size)
                         gen = ((x, y) for x, y, _ in train_set.epoch_chunks(
                             ts.epoch, batch_size, chunk_steps))
-                        for placed in trainer.prefetch(gen):
+                        for placed in trainer.prefetch(
+                                gen, iteration=ts.iteration,
+                                stride=chunk_steps):
                             xc, yc = placed
                             # chunk length from the placed arrays (single
                             # source of truth is epoch_chunks' row count)
@@ -702,20 +735,18 @@ class Estimator:
                                 chunk_fns[k] = fn
                             # same rng stream as per-step dispatch: the fn
                             # folds rng by (start_step + i) internally
-                            with tracer.span("train_dispatch", steps=k):
+                            with tracer.span("train_dispatch",
+                                             jax_annotation=True,
+                                             iteration=ts.iteration,
+                                             steps=k, path="chunked"):
                                 params, opt_state, state, loss = fn(
                                     params, opt_state, state, xc, yc, rng,
                                     np.int32(ts.iteration))
                             ts.iteration += k
                             seen += k * batch_size
                             met["steps"].labels("chunked").inc(k)
-                            trainer.account_collectives(params, k)
-                            log_loss_crossing(loss, k)
-                            beat()
-                            health_check()
-                            if ckpt is not None and checkpoint_trigger(ts):
-                                save_snapshot()
-                            if end_trigger(ts):
+                            if boundary(ts.iteration - k, loss, k,
+                                        fused=True):
                                 stop = True
                                 break
                     else:
@@ -727,7 +758,8 @@ class Estimator:
                             else:
                                 batches = train_set.epoch_batches(
                                     ts.epoch, batch_size, train=True)
-                            for batch in trainer.prefetch(batches):
+                            for batch in trainer.prefetch(
+                                    batches, iteration=ts.iteration):
                                 # rng folded IN-JIT by the step index: no
                                 # extra fold_in dispatch per step
                                 params, opt_state, state, loss = \
@@ -737,16 +769,10 @@ class Estimator:
                                 ts.iteration += 1
                                 seen += batch_size
                                 # avoid a device sync per step: loss is
-                                # fetched only at logging points
-                                log_loss_crossing(loss, 1)
-                                beat()
-                                health_check()
+                                # fetched only at logging points;
                                 # iteration-level triggers (MaxIteration,
                                 # SeveralIteration) fire mid-epoch
-                                if ckpt is not None and \
-                                        checkpoint_trigger(ts):
-                                    save_snapshot()
-                                if end_trigger(ts):
+                                if boundary(ts.iteration - 1, loss, 1):
                                     stop = True
                                     break
                             if stop:
@@ -884,51 +910,58 @@ class Estimator:
                             train_set.load_state_dict(entry_data_state)
                     continue
 
-                if loss is not None:
-                    ts.last_loss = float(loss)
-                    observe_loss_once(ts.last_loss)
-                    health_check()
-                if stop:
-                    break
-                ts.epoch += 1
-                ts.slice_index = 0
-                ts.epoch_finished = True
-                wall = time.perf_counter() - epoch_start
-                throughput = seen / max(wall, 1e-9)
-                tracer.complete("epoch", epoch_start, wall, epoch=ts.epoch,
-                                samples=seen)
-                met["epoch_seconds"].labels("distributed").observe(wall)
-                met["samples"].inc(seen)
-                met["throughput"].set(throughput)
-                met["loss"].set(ts.last_loss)
-                sample_device_telemetry()
-                # multi-host runs: land this epoch's snapshot in the
-                # worker's run-dir slot, so offline cluster aggregation
-                # (obs_report --merge-hosts) sees fresh numbers even if
-                # the worker later dies without its atexit flush
-                flush_worker_observability()
-                record = {"epoch": ts.epoch, "loss": ts.last_loss,
-                          "throughput": throughput, "wall_s": wall}
-                if self._train_summary is not None:
-                    self._train_summary.add_scalar(
-                        "Throughput", throughput, ts.iteration)
+                # the epoch's end is boundary work too: one span, so
+                # that telemetry, flush, validation and snapshot show
+                # on the timeline (the nested eval and checkpoint_save
+                # spans keep their own time)
+                with tracer.span("train_boundary", jax_annotation=True,
+                                 iteration=epoch_it0):
+                    if loss is not None:
+                        ts.last_loss = sync_loss(loss, epoch_it0)
+                        observe_loss_once(ts.last_loss)
+                        health_check()
+                    if stop:
+                        break
+                    ts.epoch += 1
+                    ts.slice_index = 0
+                    ts.epoch_finished = True
+                    wall = time.perf_counter() - epoch_start
+                    throughput = seen / max(wall, 1e-9)
+                    tracer.complete("epoch", epoch_start, wall, epoch=ts.epoch,
+                                    samples=seen)
+                    met["epoch_seconds"].labels("distributed").observe(wall)
+                    met["samples"].inc(seen)
+                    met["throughput"].set(throughput)
+                    met["loss"].set(ts.last_loss)
+                    sample_device_telemetry()
+                    # multi-host runs: land this epoch's snapshot in the
+                    # worker's run-dir slot, so offline cluster aggregation
+                    # (obs_report --merge-hosts) sees fresh numbers even if
+                    # the worker later dies without its atexit flush
+                    flush_worker_observability()
+                    record = {"epoch": ts.epoch, "loss": ts.last_loss,
+                              "throughput": throughput, "wall_s": wall}
+                    if self._train_summary is not None:
+                        self._train_summary.add_scalar(
+                            "Throughput", throughput, ts.iteration)
 
-                if eval_runner is not None:
-                    scores = run_eval(params, state)
-                    record["val"] = scores
-                    ts.last_score = next(iter(scores.values()), None)
-                    if self._val_summary is not None:
-                        for k, v in scores.items():
-                            self._val_summary.add_scalar(k, v, ts.iteration)
-                    log.info("epoch %d loss %.4f val %s (%.1f samples/s)",
-                             ts.epoch, ts.last_loss, scores, throughput)
-                else:
-                    log.info("epoch %d loss %.4f (%.1f samples/s)",
-                             ts.epoch, ts.last_loss, throughput)
-                self.history.append(record)
+                    if eval_runner is not None:
+                        scores = run_eval(params, state)
+                        record["val"] = scores
+                        ts.last_score = next(iter(scores.values()), None)
+                        if self._val_summary is not None:
+                            for k, v in scores.items():
+                                self._val_summary.add_scalar(
+                                    k, v, ts.iteration)
+                        log.info("epoch %d loss %.4f val %s (%.1f samples/s)",
+                                 ts.epoch, ts.last_loss, scores, throughput)
+                    else:
+                        log.info("epoch %d loss %.4f (%.1f samples/s)",
+                                 ts.epoch, ts.last_loss, throughput)
+                    self.history.append(record)
 
-                if ckpt is not None and checkpoint_trigger(ts):
-                    save_snapshot()
+                    if ckpt is not None and checkpoint_trigger(ts):
+                        save_snapshot()
                 ts.epoch_finished = False
         finally:
             watchdog.stop()

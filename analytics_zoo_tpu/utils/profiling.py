@@ -7,10 +7,9 @@ TPU version: the same cheap step timers, plus first-class
 
 All interval math uses ``time.perf_counter`` (monotonic): wall-clock
 (NTP) adjustments must never yield negative or garbage durations.
-These helpers are kept API-compatible but are now BACKED by the
-observability registry/tracer (observability/): ``time_it`` records a
-span, ``StepTimer`` feeds per-phase histograms — so existing callers
-show up in ``/metrics`` and Chrome traces for free.
+``time_it`` is kept API-compatible and is BACKED by the observability
+tracer (observability/): it records a span, so existing callers show up
+on the timeline and in the span counters of ``/metrics`` for free.
 """
 
 from __future__ import annotations
@@ -18,12 +17,10 @@ from __future__ import annotations
 import contextlib
 import logging
 import time
-from collections import defaultdict
-from typing import Dict, Optional
 
 import jax
 
-from analytics_zoo_tpu.observability import get_registry, get_tracer
+from analytics_zoo_tpu.observability import get_tracer
 
 log = logging.getLogger("analytics_zoo_tpu.profiling")
 
@@ -68,49 +65,3 @@ def trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class StepTimer:
-    """Aggregate per-phase step timings (the BigDL Metrics table role:
-    driver-side phase breakdown printed per interval).  Each ``stop``
-    also feeds the shared ``step_phase_seconds{phase=...}`` histogram,
-    so phase breakdowns appear in ``/metrics`` without new wiring."""
-
-    def __init__(self, report_every: int = 100):
-        self.report_every = report_every
-        self._acc: Dict[str, float] = defaultdict(float)
-        self._count = 0
-        self._open: Dict[str, float] = {}
-        self._hist = get_registry().histogram(
-            "step_phase_seconds",
-            "per-phase step timing from StepTimer", labels=("phase",))
-
-    def start(self, phase: str) -> None:
-        self._open[phase] = time.perf_counter()
-
-    def stop(self, phase: str) -> None:
-        t0 = self._open.pop(phase, None)
-        if t0 is not None:
-            dt = time.perf_counter() - t0
-            self._acc[phase] += dt
-            self._hist.labels(phase).observe(dt)
-
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        self.start(name)
-        try:
-            yield
-        finally:
-            self.stop(name)
-
-    def step(self) -> Optional[Dict[str, float]]:
-        """Mark one step done; returns (and logs) the averaged phase
-        table every ``report_every`` steps."""
-        self._count += 1
-        if self._count % self.report_every:
-            return None
-        avg = {k: v / self.report_every for k, v in self._acc.items()}
-        self._acc.clear()
-        log.info("step %d phase avg: %s", self._count,
-                 {k: f"{v * 1e3:.2f}ms" for k, v in avg.items()})
-        return avg

@@ -371,22 +371,6 @@ class TestTrainingInstrumentation:
         g = get_registry().gauge("train_grad_norm")
         assert g.value > 0.0
 
-    def test_step_timer_feeds_registry(self):
-        from analytics_zoo_tpu.utils.profiling import StepTimer
-        reg = get_registry()
-        h = reg.histogram("step_phase_seconds", "",
-                          labels=("phase",)).labels("fwd")
-        before = h.count
-        st = StepTimer(report_every=2)
-        with st.phase("fwd"):
-            pass
-        with st.phase("fwd"):
-            pass
-        st.step()
-        avg = st.step()
-        assert "fwd" in avg
-        assert h.count - before == 2
-
 
 # -------------------------------------------- serving /metrics endpoint
 class TestServingMetrics:

@@ -116,6 +116,33 @@ def test_kernel_compiles_for_v5e(v5e, on_tpu, fn, shapes, dtype, kernels):
     assert text.count("tpu_custom_call") == kernels
 
 
+NAMED = {
+    "bias_gelu-16384x768": ["bias_gelu"],
+    "layernorm_act-16384x768": ["layernorm_act"],
+    "adam-3x3x512x512": ["fused_adam"],
+    "sgd-3x3x512x512": ["fused_sgd"],
+    "flash-grad-b2h12t512d64": ["flash_attention_fwd", "flash_attention_dq",
+                                "flash_attention_dkv"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(NAMED))
+def test_kernel_name_is_the_compiled_instruction_name(v5e, on_tpu, case):
+    """What a chip profile names an ``XLA Ops`` event by: the kernel's
+    ``name=`` must be in its custom call's instruction name and
+    ``op_name``, whatever transform (jvp, transpose) wraps it."""
+    _, fn, shapes, dtype, _ = next(c for c in CASES if c[0] == case)
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=v5e) for s in shapes]
+    calls = [line for line in
+             jax.jit(fn).lower(*args).compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == len(NAMED[case])
+    for name in NAMED[case]:
+        hits = [c for c in calls if name in c.split(" = ")[0]]
+        assert len(hits) == 1, (name, [c.split(" = ")[0] for c in calls])
+        assert f"{name}" in hits[0].split("op_name=")[1]
+
+
 def test_capability_probe_compiles_for_v5e(v5e):
     """On a TPU a probe the compiler refuses is an error, so the probe
     itself must be a kernel the v5e accepts."""
