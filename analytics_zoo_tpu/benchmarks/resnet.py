@@ -102,7 +102,9 @@ def run_resnet_bench(device, batch_size: int = 128, image_size: int = 224,
 
     # first execution (donates params/opt_state/state); the first
     # run after a compile is not timed
-    params, opt_state, state, mloss = compiled(
+    # (the raw Compiled hands back the program's five outputs; the
+    # fifth, its count of non-finite steps, is not this bench's)
+    params, opt_state, state, mloss, _ = compiled(
         params, opt_state, state, x_dev, y_dev, rng)
     float(mloss)                       # D2H sync — see module docstring
     compile_s = time.time() - t_compile
@@ -121,7 +123,7 @@ def run_resnet_bench(device, batch_size: int = 128, image_size: int = 224,
     for r in range(repeats + 1):
         jax.block_until_ready((params, opt_state, state))
         t0 = time.time()
-        params, opt_state, state, mloss = compiled(
+        params, opt_state, state, mloss, _ = compiled(
             params, opt_state, state, x_dev, y_dev,
             jax.random.fold_in(rng, r))
         loss_val = float(mloss)        # D2H sync

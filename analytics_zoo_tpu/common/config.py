@@ -121,14 +121,17 @@ _DEFAULTS: Dict[str, Any] = {
     # /trace and export_chrome_trace).
     "observability.trace_events": 200000,
     # Record the global L2 grad norm as a gauge each step (adds an
-    # in-jit norm + a host callback per step — opt-in).
+    # in-jit norm + a host callback per step — opt-in: a program with
+    # a host callback leaves pjit's C++ dispatch path and is never
+    # written to the persistent compile caches).
     "observability.grad_norm": False,
     # Background device-telemetry sampling period for long-running
     # services (serving); one-shot samples are free-form.
     "observability.telemetry_interval_s": 10.0,
     # Fold a jnp.isfinite(loss + sum(grads)) reduction into the jitted
-    # train step and surface non-finite steps through a host callback
-    # (the grad-norm callback path) — the watchdog's NaN detector.
+    # train step — the watchdog's NaN detector.  The step returns the
+    # flag beside its loss and the driver reads it where it already
+    # blocks on the device (no host callback).  Off: no reduction.
     "observability.check_finite": True,
     # Training-health watchdog: what to do when an unhealthy signal
     # (non-finite loss/grad, loss divergence) fires.

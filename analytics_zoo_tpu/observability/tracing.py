@@ -54,7 +54,7 @@ TRAIN_TIMELINE_SPANS = {
              "checkpoint_restore", "eval", "aot_warm_start"),
     "prefetch": ("data_assemble", "data_place"),
     "worker": ("data_build",),
-    "callback": ("callback_finite_check", "callback_grad_norm"),
+    "callback": ("callback_grad_norm",),
 }
 
 

@@ -6,8 +6,9 @@ The reference surfaced run health through driver logs and validation
 summaries; a silently-NaN'd run was only visible when someone read the
 loss curve.  Here the health signals are *first-class*: the jitted
 train step folds a ``jnp.isfinite`` reduction over loss+grads into its
-program and surfaces the flag through a host callback (the grad-norm
-callback path); the driver loop feeds observed losses and heartbeats;
+program and returns the flag beside the loss, which the driver loop
+reads where it already blocks on the device; the driver loop also
+feeds observed losses and heartbeats;
 a background thread flags stalls when no step completes within a
 deadline.  The policy decides what an unhealthy signal does:
 
@@ -33,11 +34,11 @@ import logging
 import math
 import threading
 import time
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from analytics_zoo_tpu.observability.metrics import (
     MetricsRegistry, get_registry)
-from analytics_zoo_tpu.observability.tracing import get_tracer
 
 log = logging.getLogger("analytics_zoo_tpu.observability")
 
@@ -57,7 +58,7 @@ class TrainingHalted(RuntimeError):
 
 class TrainingWatchdog:
     """Aggregates health signals from three producers — the in-jit
-    finite-check callback (any thread), the driver loop
+    finite check's drained flags, the driver loop
     (``beat``/``observe_loss``), and the stall monitor thread — into a
     queue of *issues* the driver polls between steps.
 
@@ -154,7 +155,7 @@ class TrainingWatchdog:
             self._stall_fired = False
 
     def record_nonfinite(self, source: str = "step") -> None:
-        """A non-finite loss/grad was detected (host-callback thread
+        """A non-finite loss/grad was detected (a drained in-jit flag
         or a driver-side isfinite check).  The counter counts every
         occurrence; the ISSUE (and its warning log) is throttled —
         under the warn policy a permanently-NaN run would otherwise
@@ -315,8 +316,8 @@ _active_lock = threading.Lock()
 
 def set_active_watchdog(wd: Optional[TrainingWatchdog]
                         ) -> Optional[TrainingWatchdog]:
-    """Install the watchdog the in-jit finite-check callback reports
-    to; returns the previous one (restore it in a ``finally``)."""
+    """Install the watchdog the drained finite flags report to;
+    returns the previous one (restore it in a ``finally``)."""
     global _active_watchdog
     with _active_lock:
         prev = _active_watchdog
@@ -328,37 +329,101 @@ def get_active_watchdog() -> Optional[TrainingWatchdog]:
     return _active_watchdog
 
 
-def fold_finiteness_check(loss, grads) -> None:
+def fold_finiteness_check(loss, grads):
     """IN-JIT: fold an ``isfinite(loss + Σ grads)`` reduction into the
     traced step (NaN/Inf propagate through the sums — one add-reduce
-    per grad leaf) and surface the flag through
-    :func:`record_step_finiteness`.  The single implementation both
-    engines' step builders call, so the detection logic cannot
-    diverge between them."""
+    per grad leaf) and return the flag, a bool scalar the step hands
+    back beside its loss.  The single implementation both engines'
+    step builders call, so the detection logic cannot diverge between
+    them; the host reads the flag through :class:`PendingFiniteFlags`."""
     import jax
     import jax.numpy as jnp
     total = loss.astype(jnp.float32)
     for g in jax.tree_util.tree_leaves(grads):
         total = total + jnp.sum(g).astype(jnp.float32)
-    jax.debug.callback(record_step_finiteness, jnp.isfinite(total))
+    return jnp.isfinite(total)
 
 
-def record_step_finiteness(finite) -> None:
-    """``jax.debug.callback`` target: the jitted step's folded
-    ``isfinite(loss + Σ grads)`` flag lands here on host.  Must never
-    raise (it runs on the callback thread inside the runtime)."""
+def record_finite_checks(steps: int, nonfinite: int) -> None:
+    """The ONE host function every read flag goes through: ``steps``
+    train steps were checked, ``nonfinite`` of them had a non-finite
+    loss or gradient.  Each of those is reported once, to the active
+    watchdog or the bare counter.  A registry that is down never
+    stops training."""
     try:
-        with get_tracer().span("callback_finite_check",
-                               jax_annotation=True):
-            if bool(finite):
-                return
-            wd = get_active_watchdog()
-            if wd is not None:
-                wd.record_nonfinite("step")
-            else:
+        get_registry().counter(
+            "train_finite_checked_steps_total",
+            "train steps whose in-jit finite flag the host has read"
+        ).inc(steps)
+    except Exception:
+        pass
+    wd = get_active_watchdog()
+    for _ in range(nonfinite):
+        if wd is not None:
+            wd.record_nonfinite("step")
+        else:
+            try:
                 get_registry().counter(
                     "train_nonfinite_total",
                     "steps whose loss or gradients were non-finite",
                     labels=("source",)).labels("step").inc()
-    except Exception:
-        pass
+            except Exception:
+                pass
+
+
+class PendingFiniteFlags:
+    """The finite checks of dispatched train steps, kept as device
+    values until the host reads them.  A step program hands back its
+    flag (bool: was the step finite), a scan program the count of its
+    non-finite steps; neither is read at dispatch.  ``drain`` reads
+    all that is pending — free right after the caller has blocked on
+    the newest dispatch, since older flags are ready by program
+    order — and reports through :func:`record_finite_checks`.  A
+    caller that never drains is bounded: past ``MAX_PENDING`` the
+    oldest flag, long since computed, is read at ``keep``."""
+
+    MAX_PENDING = 64
+
+    def __init__(self):
+        self._pending: deque = deque()
+
+    def keep(self, value, steps: int = 1) -> None:
+        """Hold one dispatch's check (``None``: the check is off)."""
+        if value is None:
+            return
+        self._pending.append((value, steps))
+        if len(self._pending) > self.MAX_PENDING:
+            self._read([self._pending.popleft()])
+
+    def drain(self) -> None:
+        if self._pending:
+            pending, self._pending = self._pending, deque()
+            self._read(pending)
+
+    @staticmethod
+    def _read(pending) -> None:
+        import jax
+        # plain host reads: nothing compiles, nothing is dispatched.
+        # All copies are started before the first is waited for (a
+        # scalar's round trip is 0.45 ms on the v5e: sixteen one by
+        # one read 7.2 ms, PERF.md PR 26)
+        for value, _ in pending:
+            try:
+                value.copy_to_host_async()
+            except Exception:   # noqa: BLE001 — numpy value, or below
+                pass
+        checked = nonfinite = 0
+        for value, steps in pending:
+            try:
+                value = jax.device_get(value)
+            except Exception:
+                # the dispatch itself failed (it surfaced at the
+                # caller's own sync): no step ran, nothing to check
+                log.debug("finite flag unreadable; its dispatch "
+                          "failed", exc_info=True)
+                continue
+            checked += steps
+            nonfinite += int(value) if value.dtype != bool \
+                else int(not value)
+        if checked:
+            record_finite_checks(checked, nonfinite)

@@ -38,7 +38,7 @@ from analytics_zoo_tpu.observability import get_registry, get_tracer
 from analytics_zoo_tpu.observability.diagnostics import (
     get_compile_monitor, publish_mfu, step_attribution_histogram)
 from analytics_zoo_tpu.observability.watchdog import (
-    fold_finiteness_check)
+    PendingFiniteFlags, fold_finiteness_check)
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 from analytics_zoo_tpu.resilience.chaos import (
     SITE_TRAINER_DISPATCH, active_chaos)
@@ -57,6 +57,28 @@ def _record_grad_norm(gnorm) -> None:
             ).set(float(gnorm))
     except Exception:
         pass
+
+
+class _ScanDispatch:
+    """What ``epoch_scan_fn`` hands out: the compiled scan, called as
+    before for ``(params, opt_state, state, mean_loss)``.  The
+    program's fifth output (its count of non-finite steps) stays
+    behind as a pending device value.  Other attributes (``lower``,
+    ``warm``, ...) are the jitted function's, whose program has all
+    five outputs."""
+
+    def __init__(self, jitted, finite_flags, steps: int):
+        self._jitted = jitted
+        self._finite_flags = finite_flags
+        self._steps = steps
+
+    def __call__(self, *args):
+        *out, nonfinite = self._jitted(*args)
+        self._finite_flags.keep(nonfinite, self._steps)
+        return tuple(out)
+
+    def __getattr__(self, item):
+        return getattr(self._jitted, item)
 
 
 @dataclasses.dataclass
@@ -170,6 +192,10 @@ class DistributedTrainer:
         # NaN detector), sampled device-step bracket, compile monitor
         self._obs_check_finite = bool(
             cfg.get("observability.check_finite"))
+        # the fifth output of every compiled train program, stripped
+        # where the jitted call is wrapped and held until drain_finite
+        self._finite_flags = PendingFiniteFlags()
+        self._traced_finite = None   # see _step_core
         self._obs_device_every = int(
             cfg.get("observability.device_time_every") or 0)
         self._monitor = get_compile_monitor()
@@ -312,7 +338,13 @@ class DistributedTrainer:
     # ---------------------------------------------------------- train step
     def _step_core(self, params, opt_state, state, batch, rng):
         """One forward+backward+update — traced into both the per-step
-        jit and the whole-epoch scan.
+        jit and the whole-epoch scan, both through ``_step_checked``.
+        Returns ``(params, opt_state, state, loss)``: the benchmark's
+        tests replace this method and hold it to these four, so the
+        step's finite flag leaves by ``self._traced_finite`` instead
+        (a bool scalar of the trace in progress; ``None`` with
+        ``observability.check_finite`` off), which ``_step_checked``
+        takes right after the call.
 
         Mixed precision is OP-LEVEL: the matmul/conv kernels cast their
         operands per ``dtype.compute`` (ops/dtypes.py policy), so bf16
@@ -345,11 +377,13 @@ class DistributedTrainer:
                                optax.global_norm(grads))
         if self._obs_check_finite:
             # watchdog NaN/Inf detector, folded into the step's
-            # program; the flag surfaces asynchronously through the
-            # same callback path as the grad norm — the driver's
-            # watchdog polls it between steps
+            # program.  The flag is a VALUE the program returns, not a
+            # host callback: the program keeps pjit's C++ dispatch
+            # path and can be stored in the persistent caches, and the
+            # device never waits inside the step for the host.  The
+            # driver reads it where it already blocks (drain_finite).
             with jax.named_scope("finite_check"):
-                fold_finiteness_check(loss, grads)
+                self._traced_finite = fold_finiteness_check(loss, grads)
         if self.grad_sync_dtype == "bfloat16":
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.bfloat16).astype(jnp.float32),
@@ -368,20 +402,29 @@ class DistributedTrainer:
         new_params = mask_frozen_params(model, params, new_params)
         return new_params, new_opt_state, new_state, loss
 
+    def _step_checked(self, params, opt_state, state, batch, rng):
+        """``_step_core`` with the finite flag it folded as a fifth
+        value beside ``loss``: what every compiled train program
+        traces."""
+        self._traced_finite = None
+        out = self._step_core(params, opt_state, state, batch, rng)
+        finite, self._traced_finite = self._traced_finite, None
+        return (*out, finite)
+
     def _build_train_step(self, fold_rng: bool = False):
         """One source of truth for the train-step jit spec; with
         ``fold_rng`` the program takes (.., rng, step) and derives the
         per-step rng in-jit."""
         donate = (0, 1, 2) if self.donate else ()
         if fold_rng:
-            fn = lambda p, o, s, b, r, i: self._step_core(  # noqa: E731
+            fn = lambda p, o, s, b, r, i: self._step_checked(  # noqa: E731
                 p, o, s, b, jax.random.fold_in(r, i))
         else:
-            fn = self._step_core
+            fn = self._step_checked
         jitted = engine_jit(
             fn,
             out_shardings=(self._param_shardings, None, self._rep,
-                           self._rep),
+                           self._rep, self._rep),
             donate_argnums=donate,
             key_hint="train_step_at" if fold_rng else "train_step")
         # compile/recompile accounting + cost-analysis FLOPs for the
@@ -418,7 +461,8 @@ class DistributedTrainer:
         with tracer.span("train_step", jax_annotation=True,
                          iteration=iteration, steps=1, path="per_step"):
             t0 = time.perf_counter()
-            out = fn(*args)
+            *out, finite = fn(*args)
+            self._finite_flags.keep(finite)
             dispatch_s = time.perf_counter() - t0
             self._m_step_latency.labels("per_step").observe(dispatch_s)
             self._m_step_time.labels("host_dispatch").observe(
@@ -439,12 +483,24 @@ class DistributedTrainer:
                         self._m_device_step.set(device_s)
                         publish_mfu("train_step", device_s)
                     self._probe_barrier_wait()
+                    # the host has just blocked on this step: every
+                    # pending flag is ready
+                    self.drain_finite()
         if self._collective_bytes:
             from analytics_zoo_tpu.observability.collectives import (
                 record_step_collectives)
             record_step_collectives(self._collective_bytes)
         self._m_steps.labels("per_step").inc()
-        return out
+        return tuple(out)
+
+    def drain_finite(self) -> None:
+        """Read the finite flags of the steps dispatched since the last
+        drain and report them (``train_nonfinite_total{source="step"}``
+        through the active watchdog, ``train_finite_checked_steps_total``).
+        Call it where the host has already blocked on the newest
+        dispatch: the flags are then ready and the read costs no wait.
+        A caller that never does is bounded by ``PendingFiniteFlags``."""
+        self._finite_flags.drain()
 
     def _estimate_collectives(self, params) -> Dict[str, float]:
         """One-time {op: bytes/step} estimate from the sharding
@@ -580,7 +636,9 @@ class DistributedTrainer:
         involvement: no per-step dispatch, no H2D transfers.  Batches
         are contiguous slices of the (host-preshuffled) epoch arrays.
         Returns ``f(params, opt_state, state, x, y, rng) ->
-        (params, opt_state, state, mean_loss)``.
+        (params, opt_state, state, mean_loss)``; the compiled program's
+        fifth output, the count of the dispatch's non-finite steps, is
+        kept for ``drain_finite``.
 
         ``batch_size`` is PER-HOST, matching the per-step
         ``put_batch`` convention: when the data axes divide across
@@ -613,7 +671,7 @@ class DistributedTrainer:
             # so chunked dispatch is a pure performance knob — same
             # rng stream, same batches, same updates
             def body(carry, i):
-                params, opt_state, state = carry
+                params, opt_state, state, nonfinite = carry
 
                 def take(a):
                     if nproc > 1:
@@ -633,25 +691,35 @@ class DistributedTrainer:
                         out, mesh_lib.data_sharding(self.mesh, out.ndim))
                 batch = (jax.tree_util.tree_map(take, x),
                          jax.tree_util.tree_map(take, y))
-                params, opt_state, state, loss = self._step_core(
-                    params, opt_state, state, batch,
-                    jax.random.fold_in(rng, start_step + i))
-                return (params, opt_state, state), loss
+                params, opt_state, state, loss, finite = \
+                    self._step_checked(
+                        params, opt_state, state, batch,
+                        jax.random.fold_in(rng, start_step + i))
+                if finite is not None:
+                    nonfinite = nonfinite + (~finite).astype(jnp.int32)
+                return (params, opt_state, state, nonfinite), loss
 
-            (params, opt_state, state), losses = jax.lax.scan(
-                body, (params, opt_state, state),
+            # the dispatch's count of non-finite steps rides the carry
+            # (None with the check off): stacking the flags beside the
+            # losses read 1.0 ms a step slower on the v5e in the GPT
+            # cell (PERF.md, PR 26)
+            nonfinite = jnp.int32(0) if self._obs_check_finite else None
+            (params, opt_state, state, nonfinite), losses = jax.lax.scan(
+                body, (params, opt_state, state, nonfinite),
                 jnp.arange(num_batches), unroll=unroll)
-            return params, opt_state, state, losses.mean()
+            return params, opt_state, state, losses.mean(), nonfinite
 
         donate = (0, 1, 2) if self.donate else ()
         jitted = engine_jit(
             epoch,
             out_shardings=(self._param_shardings, None, self._rep,
-                           self._rep),
+                           self._rep, self._rep),
             donate_argnums=donate, key_hint="train_epoch_scan")
         # cost analysis counts the scan BODY once (~ one step), so the
         # monitor's flops gauge stays per-step-comparable
-        return self._monitor.wrap("train_epoch_scan", jitted)
+        return _ScanDispatch(
+            self._monitor.wrap("train_epoch_scan", jitted),
+            self._finite_flags, num_batches)
 
     def put_epoch(self, x, y, epoch: int, feature_set=None):
         """Device-place a whole epoch, sharded on the data axis.

@@ -225,10 +225,11 @@ class Estimator:
         met = _train_metrics()
         tracer = get_tracer()
 
-        # training-health watchdog: collects the in-jit finite-check
-        # callbacks (trainer._step_core), the losses observed at sync
-        # points, and the stall heartbeat; health_check() runs between
-        # steps and applies the policy.  (Installed as the ACTIVE
+        # training-health watchdog: collects the in-jit finite check's
+        # flags (trainer._step_core; drained where this loop already
+        # blocks on the device), the losses observed at sync points,
+        # and the stall heartbeat; health_check() runs between steps
+        # and applies the policy.  (Installed as the ACTIVE
         # watchdog just before the training loop — see below — so a
         # failure in restore/cache setup can't leak the thread.)
         watchdog = TrainingWatchdog()
@@ -507,10 +508,14 @@ class Estimator:
 
         def sync_loss(loss, it0) -> float:
             """Every host read of a loss: the host blocks here until
-            the dispatch that produced it has run."""
+            the dispatch that produced it has run.  ``loss`` is always
+            the newest dispatch's, so every pending finite flag is
+            ready by program order: they are read here, at no wait."""
             with tracer.span("train_loss_sync", jax_annotation=True,
                              iteration=it0):
-                return float(loss)
+                value = float(loss)
+            trainer.drain_finite()
+            return value
 
         def log_loss_crossing(loss, k):
             """Sync + log when the iteration counter crosses a
@@ -541,10 +546,17 @@ class Estimator:
                 beat()
                 if epoch_loss:
                     observe_loss_once(ts.last_loss)
-                health_check()
                 # iteration-level triggers fire mid-epoch; EveryEpoch
                 # (all the scan engines admit) answers False here
-                if ckpt is not None and checkpoint_trigger(ts):
+                save = ckpt is not None and checkpoint_trigger(ts)
+                if save:
+                    # the snapshot blocks on every dispatched step
+                    # anyway: read their flags first, so that a
+                    # non-finite step halts instead of being saved as
+                    # the newest good snapshot
+                    trainer.drain_finite()
+                health_check()
+                if save:
                     save_snapshot()
                 return bool(end_trigger(ts))
 
@@ -963,6 +975,11 @@ class Estimator:
                     if ckpt is not None and checkpoint_trigger(ts):
                         save_snapshot()
                 ts.epoch_finished = False
+            # every step's flag has been read before train returns
+            # (an epoch's end reads them; a recovery that ran into
+            # the end trigger has not)
+            trainer.drain_finite()
+            health_check()
         finally:
             watchdog.stop()
             set_active_watchdog(prev_watchdog)

@@ -62,19 +62,21 @@ class LocalEstimator:
                 objective = jax.checkpoint(objective)
             grads, (new_state, loss) = jax.grad(
                 objective, has_aux=True)(params)
+            finite = None
             if check_finite:
                 # watchdog NaN/Inf detector — the same fold the
-                # distributed engine traces (one shared helper)
+                # distributed engine traces (one shared helper); the
+                # flag is the step's fifth output
                 from analytics_zoo_tpu.observability.watchdog import (
                     fold_finiteness_check)
-                fold_finiteness_check(loss, grads)
+                finite = fold_finiteness_check(loss, grads)
             import optax
             from analytics_zoo_tpu.parallel.trainer import (
                 mask_frozen_params)
             updates, new_opt_state = optim.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
             new_params = mask_frozen_params(model, params, new_params)
-            return new_params, new_opt_state, new_state, loss
+            return new_params, new_opt_state, new_state, loss, finite
 
         from analytics_zoo_tpu.compile import engine_jit
         from analytics_zoo_tpu.observability import get_compile_monitor
@@ -139,7 +141,8 @@ class LocalEstimator:
         from analytics_zoo_tpu.observability.diagnostics import (
             publish_mfu, step_attribution_histogram)
         from analytics_zoo_tpu.observability.watchdog import (
-            TrainingHalted, TrainingWatchdog, set_active_watchdog)
+            PendingFiniteFlags, TrainingHalted, TrainingWatchdog,
+            set_active_watchdog)
         reg = get_registry()
         m_epoch = reg.histogram(
             "train_epoch_seconds", "wall time per completed epoch",
@@ -158,6 +161,9 @@ class LocalEstimator:
         watchdog = TrainingWatchdog()
         prev_watchdog = set_active_watchdog(watchdog)
         watchdog.start_stall_monitor()
+        # the steps' finite flags, read where this loop already blocks
+        # on the device: the sampled device bracket and the epoch's end
+        finite_flags = PendingFiniteFlags()
 
         def health_check():
             # poll() returns an issue only under checkpoint_and_halt;
@@ -190,9 +196,10 @@ class LocalEstimator:
                     with tracer.span("train_step"):
                         # t_step, NOT t0: the epoch wall below reads t0
                         t_step = time.perf_counter()
-                        params, opt_state, state, loss = self._step(
-                            params, opt_state, state, bx, by,
-                            jax.random.fold_in(rng, it))
+                        params, opt_state, state, loss, finite = \
+                            self._step(params, opt_state, state, bx, by,
+                                       jax.random.fold_in(rng, it))
+                        finite_flags.keep(finite)
                         m_step_time.labels("host_dispatch").observe(
                             time.perf_counter() - t_step)
                         if device_every > 0 and \
@@ -208,6 +215,7 @@ class LocalEstimator:
                                     device_s)
                                 publish_mfu("local_train_step",
                                             device_s, reg)
+                            finite_flags.drain()
                     it += 1
                     seen += batch_size
                     watchdog.beat()
@@ -217,6 +225,7 @@ class LocalEstimator:
                 m_samples.inc(seen)
                 record = {"epoch": epoch + 1, "loss": float(loss),
                           "throughput": seen / max(wall, 1e-9)}
+                finite_flags.drain()
                 watchdog.observe_loss(record["loss"])
                 health_check()
                 if validate:   # evaluate() reads the host-side variables

@@ -2006,15 +2006,6 @@ def main(argv=None):
         # process if a workload ever runs in-process)
         os.environ["ZOO_TPU_COMPILE_CACHE"] = \
             os.path.abspath(args.compile_cache)
-        # the watchdog's in-jit finite fold embeds a host-callback
-        # PyCapsule the backend cannot serialize — with it on, the
-        # train-step executable would degrade (loudly) to in-memory
-        # AOT and never persist.  A bench workload is a fixed program
-        # measuring throughput, not a run needing NaN rescue, so the
-        # cached rounds trade the fold for persistable executables
-        # (docs/aot-compile.md "what cannot be cached").
-        os.environ.setdefault("ZOO_TPU_OBSERVABILITY_CHECK_FINITE",
-                              "false")
     if args.fresh_artifact:
         try:
             os.remove(ARTIFACT_PATH)

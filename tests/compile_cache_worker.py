@@ -48,13 +48,9 @@ def main() -> int:
     cfg = get_config()
     cfg.set("train.steps_per_dispatch", 1)
     cfg.set("train.hbm_cache_mb", 0)
-    # a host debug-callback (the watchdog's in-jit finite fold) embeds
-    # a PyCapsule the backend cannot serialize — that program would
-    # degrade (loudly) to in-memory AOT only.  The acceptance claim
-    # here is that the TRAIN STEP itself round-trips through the
-    # persistent cache, so run it callback-free (docs/aot-compile.md
-    # documents the interaction).
-    cfg.set("observability.check_finite", False)
+    # the DEFAULT train step (the watchdog's in-jit finite fold on: its
+    # flag is a value the step returns, not a host callback) is the
+    # program that must round-trip through the persistent cache
 
     rs = np.random.RandomState(0)
     x = rs.randn(256, 8).astype(np.float32)
@@ -90,6 +86,8 @@ def main() -> int:
         "pred_digest": pred_digest,
         "final_loss": est.train_state.last_loss,
         "cache_hits": total("compile_cache_hits_total"),
+        "train_step_hits": total(
+            'compile_cache_hits_total{fn="train_step_at"}'),
         "cache_misses": total("compile_cache_misses_total"),
         "cache_load_seconds": total("compile_cache_load_seconds"),
         "cache_writes": total("compile_cache_writes_total"),

@@ -486,6 +486,10 @@ class TestSecondProcessWarmStart:
         # the acceptance gate (ISSUE 8): >=1 hit, zero post-warm
         # recompiles, train/predict bit-identical to the cold run
         assert warm["cache_hits"] >= 1
+        # the DEFAULT train step (finite check on) is among them: its
+        # program holds no host callback, so it serializes
+        assert cold["train_step_hits"] == 0
+        assert warm["train_step_hits"] >= 1
         assert warm["recompiles_after_warmup"] == 0
         assert warm["cache_errors"] == 0
         assert warm["params_digest"] == cold["params_digest"]

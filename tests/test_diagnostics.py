@@ -20,8 +20,8 @@ from analytics_zoo_tpu.observability import get_registry
 from analytics_zoo_tpu.observability.diagnostics import CompileMonitor
 from analytics_zoo_tpu.observability.metrics import MetricsRegistry
 from analytics_zoo_tpu.observability.watchdog import (
-    TrainingHalted, TrainingWatchdog, record_step_finiteness,
-    set_active_watchdog)
+    PendingFiniteFlags, TrainingHalted, TrainingWatchdog,
+    record_finite_checks, set_active_watchdog)
 
 
 # -------------------------------------------------------- CompileMonitor
@@ -147,18 +147,60 @@ class TestWatchdog:
         assert reg.snapshot()["counters"][
             'watchdog_events_total{kind="stall"}'] == 2.0
 
-    def test_nonfinite_callback_routes_to_active_watchdog(self):
+    def test_read_flags_route_to_active_watchdog(self):
         reg = MetricsRegistry()
         wd = TrainingWatchdog(policy="checkpoint_and_halt", registry=reg)
         prev = set_active_watchdog(wd)
         try:
-            record_step_finiteness(np.bool_(True))    # finite: no-op
+            checked = get_registry().snapshot()["counters"].get(
+                "train_finite_checked_steps_total", 0.0)
+            record_finite_checks(4, 0)        # four finite steps: no-op
             assert wd.poll() is None
-            record_step_finiteness(np.bool_(False))   # NaN/Inf step
+            record_finite_checks(3, 2)        # two NaN/Inf steps of three
             issue = wd.poll()
             assert issue is not None and issue["kind"] == "nonfinite"
             assert reg.snapshot()["counters"][
-                'train_nonfinite_total{source="step"}'] == 1.0
+                'train_nonfinite_total{source="step"}'] == 2.0
+            # the checked steps are counted in the shared registry
+            assert get_registry().snapshot()["counters"][
+                "train_finite_checked_steps_total"] == checked + 7.0
+        finally:
+            set_active_watchdog(prev)
+
+    def test_pending_flags_are_read_at_drain_and_bounded(self):
+        """A step's flag (bool: finite?) and a scan's count of
+        non-finite steps are both held unread; drain reports each step
+        once, without a watchdog through the bare counter; a caller
+        that never drains reads its oldest flag past MAX_PENDING; a
+        check that is off (None) holds nothing."""
+        def read():
+            snap = get_registry().snapshot()["counters"]
+            return (snap.get("train_finite_checked_steps_total", 0.0),
+                    snap.get('train_nonfinite_total{source="step"}', 0.0))
+        start = read()
+
+        def counters():
+            return tuple(now - was for now, was in zip(read(), start))
+        prev = set_active_watchdog(None)
+        try:
+            flags = PendingFiniteFlags()
+            flags.keep(None)
+            flags.keep(jnp.bool_(True))
+            flags.keep(jnp.bool_(False))
+            flags.keep(jnp.int32(3), steps=5)      # a scan of 5, 3 bad
+            assert counters() == (0.0, 0.0)        # nothing read yet
+            flags.drain()
+            assert counters() == (7.0, 4.0)
+            flags.drain()                          # nothing pending
+            assert counters() == (7.0, 4.0)
+            for _ in range(PendingFiniteFlags.MAX_PENDING):
+                flags.keep(np.bool_(False))
+            assert counters() == (7.0, 4.0)
+            flags.keep(np.bool_(True))             # one too many
+            assert counters() == (8.0, 5.0)        # the OLDEST was read
+            flags.drain()
+            assert counters() == (8.0 + PendingFiniteFlags.MAX_PENDING,
+                                  4.0 + PendingFiniteFlags.MAX_PENDING)
         finally:
             set_active_watchdog(prev)
 

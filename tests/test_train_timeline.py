@@ -1,12 +1,15 @@
 """The training timeline (PR 25): what the tracer records about a span
 (parent, iteration, self time, the three span counters), the spans each
-dispatch engine leaves behind, the callbacks' spans, the names on device
-work, and ``dev/trace-summary``'s attribution of idle gaps."""
+dispatch engine leaves behind, the finite flag's way to the host (a
+value the step returns: no host callback in a default train program),
+the names on device work, and ``dev/trace-summary``'s attribution of
+idle gaps."""
 
 import ast
 import importlib.machinery
 import importlib.util
 import os
+import re
 import threading
 import time
 
@@ -181,14 +184,12 @@ def _train(engine):
 
 ENGINE_SPANS = {
     "epoch_scan": {"train_permute", "train_epoch_scan", "train_loss_sync",
-                   "train_boundary", "callback_finite_check"},
+                   "train_boundary"},
     "chunked": {"data_wait", "data_assemble", "data_place",
-                "train_dispatch", "train_loss_sync", "train_boundary",
-                "callback_finite_check"},
+                "train_dispatch", "train_loss_sync", "train_boundary"},
     "per_step": {"data_build", "data_assemble", "data_place", "data_wait",
                  "train_step", "train_device_sync", "train_loss_sync",
-                 "train_boundary", "callback_finite_check",
-                 "aot_warm_start"},
+                 "train_boundary", "aot_warm_start"},
 }
 DISPATCH_SPAN = {"epoch_scan": "train_epoch_scan",
                  "chunked": "train_dispatch", "per_step": "train_step"}
@@ -227,8 +228,8 @@ def test_engine_leaves_its_spans_with_sane_nesting(engine):
     assert all(e["args"]["steps"] == stride
                and e["args"]["path"] == engine for e in dispatch)
     shared = ENGINE_SPANS[engine] - {
-        "callback_finite_check", "aot_warm_start", "train_loss_sync",
-        "train_device_sync", "train_permute"}
+        "aot_warm_start", "train_loss_sync", "train_device_sync",
+        "train_permute"}
     for name in shared:
         seen = {e["args"]["iteration"] for e in events
                 if e["name"] == name and "args" in e}
@@ -239,22 +240,29 @@ def test_engine_leaves_its_spans_with_sane_nesting(engine):
         'train_steps_total{path="%s"}' % engine]
     assert steps == 8
     assert counters[DISPATCH_SPAN[engine]]["spans_total"] == 8 // stride
-    assert counters["callback_finite_check"]["spans_total"] == 8
+    # the default config runs no callback, so none leaves a span
+    assert not [n for n in names if n.startswith("callback_")]
     if engine == "per_step":
         assert counters["train_step"]["spans_total"] == steps
+        # draining the finite flags adds no sync: the spans in which the
+        # host blocks are the parent's for these 8 steps (every 4th
+        # dispatch; the loss at each of the two epochs' ends)
         assert counters["train_device_sync"]["spans_total"] == 2
+        assert counters["train_loss_sync"]["spans_total"] == 2
         placed = [e for e in events if e["name"] == "data_place"]
         assert all(e["args"]["bytes"] == 64 * (8 + 4) * 4 for e in placed)
         assert get_registry().snapshot()["counters"][
             "data_h2d_bytes_total"] == 8 * 64 * (8 + 4) * 4
 
 
-# ------------------------------------------------------------ the callbacks
+# ------------------------- the flag's host function, the callback left
 def _finite_check():
+    """The host function every drained flag goes through (main thread,
+    no span of its own): two checked steps, one of them non-finite."""
     from analytics_zoo_tpu.observability.watchdog import (
-        record_step_finiteness)
-    record_step_finiteness(np.bool_(False))
-    return "callback_finite_check"
+        record_finite_checks)
+    record_finite_checks(2, 1)
+    return None
 
 
 def _grad_norm():
@@ -266,8 +274,8 @@ def _grad_norm():
 @pytest.mark.parametrize("callback", [_finite_check, _grad_norm],
                          ids=["finite_check", "grad_norm"])
 @pytest.mark.parametrize("registry", ["sound", "raising"])
-def test_callback_records_a_span_and_never_raises(callback, registry,
-                                                  monkeypatch):
+def test_host_function_never_raises_and_a_callback_records_a_span(
+        callback, registry, monkeypatch):
     if registry == "raising":
         from analytics_zoo_tpu.observability import (
             metrics, tracing, watchdog)
@@ -277,10 +285,135 @@ def test_callback_records_a_span_and_never_raises(callback, registry,
             raise RuntimeError("registry down")
         for module in (metrics, tracing, watchdog, trainer):
             monkeypatch.setattr(module, "get_registry", boom)
-    name = callback()        # on the runtime's thread: must not raise
-    assert name in [e["name"] for e in get_tracer().events()]
+    name = callback()        # must not raise, wherever it runs
+    if name is not None:
+        assert name in [e["name"] for e in get_tracer().events()]
     if registry == "sound":
-        assert span_counters()[name]["spans_total"] == 1
+        if name is not None:
+            assert span_counters()[name]["spans_total"] == 1
+        else:
+            counters = get_registry().snapshot()["counters"]
+            assert counters["train_finite_checked_steps_total"] == 2
+            assert counters['train_nonfinite_total{source="step"}'] == 1
+
+
+# ------------------------------------- finiteness as a value the step returns
+def _toy_trainer():
+    from analytics_zoo_tpu.parallel.trainer import DistributedTrainer
+    from analytics_zoo_tpu.pipeline.api.keras import objectives
+    m = _toy_model()
+    trainer = DistributedTrainer(m, objectives.get("mse"),
+                                 optim_method=m.optim_method)
+    variables = m.get_variables()
+    params = trainer.place_params(variables["params"])
+    state = trainer.replicate(variables["state"])
+    return trainer, params, trainer.init_opt_state(params), state
+
+
+def _compile_step_at(trainer, params, opt_state, state, rng):
+    batch = trainer.put_batch((np.ones((64, 8), "float32"),
+                               np.ones((64, 4), "float32")))
+    return trainer._build_train_step(fold_rng=True).lower(
+        params, opt_state, state, batch, rng, np.int32(0)).compile()
+
+
+def _compile_epoch_scan(trainer, params, opt_state, state, rng):
+    x, y = trainer.put_epoch_source(np.ones((256, 8), "float32"),
+                                    np.ones((256, 4), "float32"))
+    return trainer.epoch_scan_fn(4, 64).lower(
+        params, opt_state, state, x, y, rng, np.int32(0)).compile()
+
+
+@pytest.mark.parametrize("compile_program", [
+    _compile_step_at, _compile_epoch_scan],
+    ids=["train_step_at", "train_epoch_scan"])
+@pytest.mark.parametrize("grad_norm", [False, True],
+                         ids=["default", "grad_norm_on"])
+def test_default_train_program_holds_no_host_callback(compile_program,
+                                                      grad_norm):
+    """With the default config (finite check on) the compiled program
+    holds no host callback, so it keeps pjit's C++ dispatch path and
+    the persistent caches take it; ``observability.grad_norm``, the
+    opt-in that still rides a callback, brings one back (the control:
+    this test can see a callback)."""
+    import jax
+    from analytics_zoo_tpu.common.config import get_config
+    assert get_config().get("observability.check_finite") is True
+    get_config().set("observability.grad_norm", grad_norm)
+    trainer, params, opt_state, state = _toy_trainer()
+    compiled = compile_program(trainer, params, opt_state, state,
+                               jax.random.PRNGKey(0))
+    assert compiled._executable.unsafe_call.has_host_callbacks is grad_norm
+    # (a bare "callback" would also match this test's name in the
+    # HLO's source metadata)
+    calls = re.findall(r'custom_call_target="[^"]*callback[^"]*"',
+                       compiled.as_text())
+    assert bool(calls) is grad_norm, calls
+    # the flag is the program's fifth output, beside the loss
+    assert len(compiled.out_tree.children()) == 5
+
+
+POISONED_FROM, POISONED_OF = 3, 8
+
+
+def _poisoned_rows():
+    """Eight unshuffled batches of 64; every row from batch 3 on has a
+    NaN label."""
+    rs = np.random.RandomState(0)
+    x = rs.randn(64 * POISONED_OF, 8).astype("float32")
+    y = rs.randn(64 * POISONED_OF, 4).astype("float32")
+    y[64 * POISONED_FROM:] = np.nan
+    return x, y
+
+
+def _train_poisoned(engine):
+    from analytics_zoo_tpu.common.config import get_config
+    from analytics_zoo_tpu.common.triggers import MaxEpoch, MaxIteration
+    from analytics_zoo_tpu.feature.feature_set import FeatureSet
+    from analytics_zoo_tpu.pipeline.estimator import Estimator
+    from analytics_zoo_tpu.pipeline.estimator.local_estimator import (
+        LocalEstimator)
+    x, y = _poisoned_rows()
+    m = _toy_model()
+    cfg = get_config()
+    if engine == "local":
+        LocalEstimator(m, "mse", m.optim_method).fit(
+            FeatureSet.from_ndarrays(x, y, shuffle=False), None,
+            batch_size=64, epochs=1)
+        return
+    est = Estimator(m, optim_method=m.optim_method)
+    if engine == "chunked":
+        cfg.set("train.hbm_cache_mb", 0)
+        cfg.set("train.steps_per_dispatch", 2)
+    end = MaxIteration(POISONED_OF) if engine == "per_step" \
+        else MaxEpoch(1)
+    est.train(FeatureSet.from_ndarrays(x, y, shuffle=False), "mse",
+              end_trigger=end, batch_size=64)
+
+
+@pytest.mark.parametrize("engine", ["per_step", "chunked", "epoch_scan",
+                                    "local"])
+def test_every_nonfinite_step_is_counted_once_before_train_returns(engine):
+    """Rows poisoned from step k of n: exactly n - k non-finite steps,
+    on every engine, counted by the time ``train`` / ``fit`` returns
+    (the benchmark reads the counter for ``failed`` right after)."""
+    _train_poisoned(engine)
+    counters = get_registry().snapshot()["counters"]
+    assert counters['train_nonfinite_total{source="step"}'] == \
+        POISONED_OF - POISONED_FROM
+    assert counters["train_finite_checked_steps_total"] == POISONED_OF
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINE_SPANS))
+def test_every_dispatched_step_has_its_flag_read(engine):
+    """``train_finite_checked_steps_total`` grows by what
+    ``train_steps_total`` grows by: a flag that is computed and never
+    read cannot go unnoticed."""
+    _train(engine)
+    counters = get_registry().snapshot()["counters"]
+    assert counters["train_finite_checked_steps_total"] == \
+        counters['train_steps_total{path="%s"}' % engine] == 8
+    assert 'train_nonfinite_total{source="step"}' not in counters
 
 
 # ------------------------------------------------------ names on device work
@@ -397,8 +530,8 @@ def hand_trace():
                 ("$trainer.py:400 _dispatch_instrumented", 0, 60 * ms, {}),
                 ("train_boundary", 55 * ms, 1 * ms, {"iteration": 7})]},
             {"name": "callback", "events": [
-                ("callback_finite_check", 12 * ms, 1 * ms, {}),
-                ("callback_finite_check", 50 * ms + ms // 2, ms, {})]},
+                ("callback_grad_norm", 12 * ms, 1 * ms, {}),
+                ("callback_grad_norm", 50 * ms + ms // 2, ms, {})]},
             {"name": "idle", "events": []}]},
     ]
 
@@ -417,7 +550,7 @@ def test_trace_summary_names_the_span_behind_each_gap():
         "span": "train_device_sync", "iteration": 7,
         "share": pytest.approx(2 / 3)}
     # the callback covers a thirtieth of it: the best there is
-    assert long["threads"]["callback"]["span"] == "callback_finite_check"
+    assert long["threads"]["callback"]["span"] == "callback_grad_norm"
     assert long["threads"]["callback"]["share"] == pytest.approx(1 / 30)
     assert long["threads"]["callback"]["iteration"] is None
     assert short["threads"]["main"]["span"] == "train_device_sync"
