@@ -51,7 +51,8 @@ TRAIN_TIMELINE_SPANS = {
     "main": ("train_epoch_scan", "train_dispatch", "train_step",
              "train_permute", "train_loss_sync", "train_device_sync",
              "train_boundary", "data_wait", "checkpoint_save",
-             "checkpoint_restore", "eval", "aot_warm_start"),
+             "checkpoint_restore", "eval", "aot_warm_start",
+             "moe_stats_read"),
     "prefetch": ("data_assemble", "data_place"),
     "worker": ("data_build",),
     "callback": ("callback_grad_norm",),

@@ -57,3 +57,18 @@ def blockwise_attention_step(q, k_blk, v_blk, acc, m, l, scale,
     acc_new = acc * correction[..., None] + jnp.einsum(
         "bhqk,bhkd->bhqd", p.astype(v_blk.dtype), v_blk).astype(jnp.float32)
     return acc_new, m_new, l_new
+
+
+def rotary_embedding(x, positions, base: float):
+    """Rotary position embedding, rotate-half form.  x: (B, T, H, D)
+    with D even; positions: (B, T) integer position ids.  The angles are
+    taken in float32 and the result comes back in ``x.dtype``."""
+    half = x.shape[-1] // 2
+    inv_freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)

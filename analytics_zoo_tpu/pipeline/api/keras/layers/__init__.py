@@ -6,9 +6,10 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.embedding import (
     Embedding, SparseEmbedding, WordEmbedding,
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.merge import Merge, merge
-from analytics_zoo_tpu.pipeline.api.keras.layers.moe import MoE
+from analytics_zoo_tpu.pipeline.api.keras.layers.moe import DroplessMoE, MoE
 from analytics_zoo_tpu.pipeline.api.keras.layers.normalization import (
     BatchNormalization, L2Normalization, LayerNorm, NormalizeScale,
+    RMSNorm,
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.recurrent import (
     GRU, LSTM, Bidirectional, SimpleRNN,
@@ -54,8 +55,11 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.local import (
     LocallyConnected1D, LocallyConnected2D,
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
-    BERT, MultiHeadSelfAttention, PositionwiseFeedForward,
-    TransformerLayer, transformer_block,
+    BERT, GroupedQueryAttention, MultiHeadSelfAttention,
+    PositionwiseFeedForward, TransformerLayer, transformer_block,
+)
+from analytics_zoo_tpu.pipeline.api.keras.layers.diffusion import (
+    BlockDiffusionLoss, BlockDiffusionNoise,
 )
 
 # Keras-2 style aliases
@@ -67,7 +71,7 @@ __all__ = [
     "Activation", "Dense", "Dropout", "Flatten", "Highway", "Lambda",
     "Masking", "MaxoutDense", "Permute", "RepeatVector", "Reshape",
     "SparseDense", "Embedding", "WordEmbedding", "Merge", "merge",
-    "BatchNormalization", "L2Normalization", "LayerNorm",
+    "BatchNormalization", "L2Normalization", "LayerNorm", "RMSNorm",
     "NormalizeScale",
     "GRU", "LSTM", "Bidirectional", "SimpleRNN",
     "AtrousConvolution2D", "Convolution1D", "Convolution2D",
@@ -85,10 +89,11 @@ __all__ = [
     "KerasLayerWrapper", "TimeDistributed",
     "ConvLSTM2D", "ConvLSTM3D", "LocallyConnected1D",
     "LocallyConnected2D",
-    "BERT", "MultiHeadSelfAttention", "PositionwiseFeedForward",
+    "BERT", "GroupedQueryAttention", "MultiHeadSelfAttention",
+    "PositionwiseFeedForward", "BlockDiffusionLoss", "BlockDiffusionNoise",
     "TransformerLayer", "transformer_block",
     "SparseEmbedding", "AtrousConvolution1D", "ShareConvolution2D",
-    "SpaceToDepth2D", "MoE",
+    "SpaceToDepth2D", "MoE", "DroplessMoE",
     "AddConstant", "BinaryThreshold", "CAdd", "CMul", "Exp",
     "GaussianSampler", "HardShrink", "HardTanh", "Identity", "Log",
     "LRN2D", "Mul", "MulConstant", "Negative", "Power",

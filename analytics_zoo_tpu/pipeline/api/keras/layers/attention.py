@@ -30,7 +30,7 @@ from analytics_zoo_tpu.pipeline.api.keras.engine import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.core import Dense, Dropout
 from analytics_zoo_tpu.pipeline.api.keras.layers.embedding import Embedding
 from analytics_zoo_tpu.pipeline.api.keras.layers.normalization import (
-    LayerNorm,
+    LayerNorm, rms_norm,
 )
 from analytics_zoo_tpu.pipeline.api.keras.topology import Model
 from analytics_zoo_tpu.parallel.mesh import (
@@ -49,6 +49,20 @@ def _mm(x, w):
 def _mesh():
     from analytics_zoo_tpu.common.zoo_context import get_zoo_context
     return get_zoo_context().mesh
+
+
+def _flash_route(t: int, head_dim: int) -> bool:
+    """Whether self-attention over ``t`` positions goes to the flash
+    kernels: ``pallas_call`` is not GSPMD-partitionable, so only on a
+    trivial (single-device) mesh; the tiles are 256 wide and a head is
+    a lane multiple.  Every operand enters the kernels tile by tile, so
+    no length is too long.  Availability comes from the kernel suite's
+    ONE capability probe (ops/fused.pallas_supported — does this
+    backend compile Pallas?), not from a backend-name string match."""
+    from analytics_zoo_tpu.ops import fused
+    return (fused.pallas_supported()
+            and math.prod(_mesh().shape.values()) == 1
+            and t % 256 == 0 and head_dim % 64 == 0)
 
 
 class MultiHeadSelfAttention(Layer):
@@ -110,19 +124,9 @@ class MultiHeadSelfAttention(Layer):
         q, k, v = (jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3))
 
         use_sp = self._use_sp() and mask is None
-        # flash kernel constraints: pallas_call is not GSPMD-partitionable,
-        # so only auto-route on a trivial (single-device) mesh; K/V for one
-        # (batch, head) must fit VMEM (~4k·128 floats, see pallas_attention)
-        # — training included now that the flash backward kernels exist.
-        # Availability comes from the kernel suite's ONE capability
-        # probe (ops/fused.pallas_supported — does this backend compile
-        # Pallas?) instead of a backend-name string match.
+        use_flash = (not use_sp and mask is None
+                     and _flash_route(t, self.head_dim))
         from analytics_zoo_tpu.ops import fused
-        mesh_trivial = math.prod(_mesh().shape.values()) == 1
-        use_flash = (not use_sp and mask is None and
-                     fused.pallas_supported() and mesh_trivial and
-                     t % 256 == 0 and self.head_dim % 64 == 0 and
-                     t * self.head_dim <= 4096 * 128)
         if use_flash:
             from analytics_zoo_tpu.ops.pallas_attention import (
                 flash_attention)
@@ -161,6 +165,104 @@ class MultiHeadSelfAttention(Layer):
         if isinstance(input_shape, list):
             return tuple(input_shape[0])
         return tuple(input_shape)
+
+
+class GroupedQueryAttention(Layer):
+    """Self-attention with fewer key/value heads than query heads, as
+    the current open decoders have it: separate q/k/v/o projections
+    without bias, an optional RMS norm over each head of q and k (own
+    gains), rotary positions (rotate-half form), and query head ``i``
+    reading K/V head ``i // (n_head / n_kv_head)``.
+
+    Inputs ``[x, positions]``: ``x`` (B, T, D) and integer position ids
+    (B, T).  ``mask``: ``None`` (every query reads every key),
+    ``"causal"``, or ``ops.pallas_attention.block_diffusion(L, B)``
+    over ``T = 2 L`` positions (the noisy copy, then the clean one).
+
+    Routing is ``MultiHeadSelfAttention``'s: the flash kernels on one
+    device (K/V heads are indexed, never repeated, and tiles the mask
+    rules out are skipped), dense attention under an explicit mask
+    elsewhere."""
+
+    def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
+                 rope_theta: float = 10000.0, qk_norm: bool = True,
+                 norm_epsilon: float = 1e-6, mask=None, **kwargs):
+        super().__init__(**kwargs)
+        if n_head % n_kv_head:
+            raise ValueError(
+                f"{n_kv_head} K/V heads do not divide {n_head} heads")
+        self.n_head, self.n_kv_head = int(n_head), int(n_kv_head)
+        self.head_dim = int(head_dim)
+        self.rope_theta = float(rope_theta)
+        self.qk_norm = bool(qk_norm)
+        self.norm_epsilon = float(norm_epsilon)
+        self.mask = mask
+
+    def build(self, rng, input_shape) -> Params:
+        d = input_shape[0][-1]
+        params: Params = {}
+        self.add_weight(params, rng, "q_kernel",
+                        (d, self.n_head * self.head_dim))
+        self.add_weight(params, rng, "k_kernel",
+                        (d, self.n_kv_head * self.head_dim))
+        self.add_weight(params, rng, "v_kernel",
+                        (d, self.n_kv_head * self.head_dim))
+        self.add_weight(params, rng, "o_kernel",
+                        (self.n_head * self.head_dim, d))
+        if self.qk_norm:
+            self.add_weight(params, rng, "q_norm", (self.head_dim,),
+                            init="one")
+            self.add_weight(params, rng, "k_norm", (self.head_dim,),
+                            init="one")
+        return params
+
+    def call(self, params, inputs, training=False, rng=None):
+        from analytics_zoo_tpu.ops import fused
+        from analytics_zoo_tpu.ops.attention import rotary_embedding
+        from analytics_zoo_tpu.ops.pallas_attention import (
+            allowed_pairs, flash_attention)
+        x, positions = inputs
+        b, t, _ = x.shape
+        compute = get_policy().compute_dtype
+
+        def heads(kernel, n, gain):
+            # the MXU accumulates in float32; the activation is kept in
+            # the compute dtype (a float32 q at 8,192 positions is
+            # 128 MB a layer, held for the backward pass)
+            y = _mm(x, params[kernel]).astype(compute)
+            y = y.reshape(b, t, n, self.head_dim)
+            if self.qk_norm and gain is not None:
+                y = rms_norm(y, params[gain], self.norm_epsilon)
+            return y
+
+        q = rotary_embedding(heads("q_kernel", self.n_head, "q_norm"),
+                             positions, self.rope_theta)
+        k = rotary_embedding(heads("k_kernel", self.n_kv_head, "k_norm"),
+                             positions, self.rope_theta)
+        v = heads("v_kernel", self.n_kv_head, None)
+        q, k, v = (jnp.moveaxis(a, 1, 2) for a in (q, k, v))
+
+        causal = self.mask == "causal"
+        if _flash_route(t, self.head_dim):
+            fused.count_build("flash_attention", "pallas")
+            block = 512 if t % 1024 == 0 else 256
+            ctx = flash_attention(
+                q, k, v, causal=causal, block_q=block, block_k=block,
+                mask=None if causal else self.mask)
+        else:
+            fused.count_build("flash_attention", "lax")
+            group = self.n_head // self.n_kv_head
+            ctx = scaled_dot_product_attention(
+                q, jnp.repeat(k, group, axis=1),
+                jnp.repeat(v, group, axis=1),
+                mask=None if self.mask is None else jnp.asarray(
+                    allowed_pairs(self.mask, t)))
+        ctx = jnp.moveaxis(ctx, 1, 2).reshape(
+            b, t, self.n_head * self.head_dim)
+        return _mm(ctx, params["o_kernel"]).astype(x.dtype)
+
+    def compute_output_shape(self, input_shape):
+        return tuple(input_shape[0])
 
 
 class PositionwiseFeedForward(Layer):

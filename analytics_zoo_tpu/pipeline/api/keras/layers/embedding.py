@@ -20,11 +20,25 @@ class Embedding(Layer):
 
     def __init__(self, input_dim: int, output_dim: int, init="uniform",
                  W_regularizer=None, mask_zero: bool = False,
-                 parallel_mode: str = None, **kwargs):
+                 parallel_mode: str = None, vocab_held=None, **kwargs):
         """parallel_mode: None | "dim" — "dim" shards the embedding dim
         over the ``model`` axis (the gather stays local; downstream TP
-        layers consume the sharded activations directly)."""
+        layers consume the sharded activations directly).
+
+        vocab_held: ``(first, count)`` — this chip's slice of a
+        vocabulary-parallel table of ``input_dim`` rows: the layer
+        holds rows ``first .. first + count - 1`` only and gives zeros
+        for every other id (what the other ranks would add is theirs to
+        compute; no exchange is built here).  Default: the whole
+        table."""
         super().__init__(**kwargs)
+        self.vocab_first, self.vocab_count = (
+            (0, int(input_dim)) if vocab_held is None
+            else (int(vocab_held[0]), int(vocab_held[1])))
+        if not 0 <= self.vocab_first \
+                <= self.vocab_first + self.vocab_count <= int(input_dim):
+            raise ValueError(
+                f"vocab_held {vocab_held} outside 0..{input_dim}")
         self.input_dim = int(input_dim)
         self.output_dim = int(output_dim)
         self.kernel_init = init
@@ -39,7 +53,8 @@ class Embedding(Layer):
         from analytics_zoo_tpu.parallel.mesh import MODEL_AXIS
         params: Params = {}
         self.add_weight(params, rng, "embeddings",
-                        (self.input_dim, self.output_dim), init=self.kernel_init,
+                        (self.vocab_count, self.output_dim),
+                        init=self.kernel_init,
                         regularizer=self.W_regularizer)
         if self.parallel_mode == "dim":
             self.param_pspecs["embeddings"] = P(None, MODEL_AXIS)
@@ -47,7 +62,14 @@ class Embedding(Layer):
 
     def call(self, params, x, training=False, rng=None):
         ids = x.astype(jnp.int32)
-        out = jnp.take(params["embeddings"], ids, axis=0)
+        if self.vocab_count != self.input_dim:
+            local = ids - self.vocab_first
+            held = (local >= 0) & (local < self.vocab_count)
+            out = jnp.take(params["embeddings"],
+                           jnp.where(held, local, 0), axis=0)
+            out = jnp.where(held[..., None], out, 0)
+        else:
+            out = jnp.take(params["embeddings"], ids, axis=0)
         if self.mask_zero:
             out = out * (ids != 0)[..., None].astype(out.dtype)
         return out

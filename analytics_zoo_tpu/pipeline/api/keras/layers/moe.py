@@ -16,11 +16,19 @@ einsums and inserts the token all_to_all automatically.
 The router's load-balancing auxiliary loss (Switch eq. 4) is returned
 by ``aux_loss()`` after a forward — add it to the objective via
 ``CustomLoss`` / a lambda criterion.
+
+``DroplessMoE`` is the layer of the current open decoders: any
+``top_k`` of any number of experts with renormalised gates, gated
+(SiLU) experts, no capacity and no dropped token, rows sorted by expert
+and multiplied by ``ops.grouped_matmul``; told which experts it holds
+(``experts_held``), it computes their part of the sum only — what one
+rank of an expert-parallel group runs.
 """
 
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +37,9 @@ from jax.sharding import PartitionSpec as P
 from analytics_zoo_tpu.ops import activations as acts
 from analytics_zoo_tpu.ops.dtypes import get_policy
 from analytics_zoo_tpu.parallel.mesh import EXPERT_AXIS
-from analytics_zoo_tpu.pipeline.api.keras.engine import Layer, Params
+from analytics_zoo_tpu.pipeline.api.keras.engine import (
+    Layer, Params, State,
+)
 
 
 class MoE(Layer):
@@ -157,3 +167,222 @@ class MoE(Layer):
 
     def compute_output_shape(self, input_shape):
         return input_shape
+
+
+# ------------------------------------------------------------- dropless
+class _Route(NamedTuple):
+    """One step's routing as int32 index arrays, both ways, so that
+    dispatch, combine and their backward passes are all GATHERS (a
+    scatter of 65,536 rows serialises on the TPU).  ``N`` tokens with
+    ``k = top_k`` picks each, ``R`` buffer rows.  The assignment side
+    is kept ``(k, N)``, tokens along the lanes: the sum over a token's
+    picks is then over a leading axis (a ``(N, k, d)`` view would
+    re-tile every row), and no index vector changes rank on its way (a
+    1-D to 2-D reshape of an index vector is a relayout that took the
+    v5e 0.5 ms apiece, forty a step: my chip run, PR 27)."""
+    dest: jax.Array        # (k, N) buffer row of an assignment
+    held: jax.Array        # (k, N) bool: its expert is held here
+    row_pick: jax.Array    # (R,) the pick a buffer row holds
+    row_token: jax.Array   # (R,) its token; N where the row holds none
+
+
+def _with_zero_row(a):
+    """``a`` with one more row, of zeros: what an index of ``N`` reads,
+    so that a row that holds no assignment needs no mask."""
+    return jnp.concatenate([a, jnp.zeros((1,) + a.shape[1:], a.dtype)])
+
+
+@jax.custom_vjp
+def _dispatch(xt, route: _Route):
+    """Token rows (N, d) into the expert-sorted buffer (R, d); rows that
+    hold no assignment are zero."""
+    return _with_zero_row(xt)[route.row_token]
+
+
+def _dispatch_fwd(xt, route):
+    return _dispatch(xt, route), route
+
+
+def _dispatch_bwd(route, d_buf):
+    rows = jnp.where(route.held[..., None], d_buf[route.dest], 0)
+    return jnp.sum(rows, axis=0, dtype=jnp.float32).astype(d_buf.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y_buf, gates, route: _Route):
+    """Each token's gated sum (N, d) float32 of the buffer rows its held
+    assignments produced; ``gates`` (k, N) float32."""
+    return jnp.einsum("kn,knd->nd", gates, _assigned_rows(y_buf, route))
+
+
+def _assigned_rows(y_buf, route):
+    return jnp.where(route.held[..., None], y_buf[route.dest],
+                     0).astype(jnp.float32)
+
+
+def _combine_fwd(y_buf, gates, route):
+    return _combine(y_buf, gates, route), (y_buf, gates, route)
+
+
+def _combine_bwd(res, dy):
+    y_buf, gates, route = res
+    gate_row = _with_zero_row(gates.T)[route.row_token, route.row_pick]
+    # the rows are gathered in the buffer's dtype: gathered in float32
+    # they are 570 MB a layer, 3.3 ms on the v5e (my chip run, PR 27)
+    d_buf = gate_row[:, None] * _with_zero_row(
+        dy.astype(y_buf.dtype))[route.row_token]
+    d_gates = jnp.einsum("nd,knd->kn", dy, _assigned_rows(y_buf, route))
+    return d_buf.astype(y_buf.dtype), d_gates, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class DroplessMoE(Layer):
+    """Router over all ``num_experts`` → the ``top_k`` largest
+    probabilities (renormalised to sum to one under ``norm_topk_prob``)
+    → gated experts ``(silu(x Wg) * (x Wu)) Wd`` → gated sum.  Input
+    ``(B, T, d)``; outputs ``[y, aux]``: ``y`` of the input's shape and
+    the load-balance term of each sequence, ``(B,)``:
+    ``num_experts * sum_e f_e * p_e`` with ``f_e`` the share of the
+    sequence's assignments that went to expert ``e`` and ``p_e`` its
+    mean router probability.
+
+    Dropless: the ``B T top_k`` assignments are sorted by expert into a
+    buffer of static length (``ops.grouped_matmul.buffer_rows``), so no
+    capacity exists and no token is dropped at any imbalance.
+
+    ``experts_held=(first, count)`` (default: all): the layer holds the
+    weights of experts ``first .. first + count - 1`` only.  The router
+    keeps its full width; assignments to the other experts fall in the
+    buffer's tail, which no kernel touches, and ``y`` is the held
+    experts' part of the sum.  Nothing stands in for the absent chips
+    or their exchange: under an ``expert`` mesh axis this is what each
+    rank runs between the two all-to-alls, which are not built here.
+
+    State (not trained, carried like BatchNorm's moving statistics):
+    ``rows_routed`` (count + 1,) int32 — assignments so far to each
+    held expert and, last, to all the others; wraps at 2**32.
+    ``observability.moe_stats`` publishes it at the host's syncs."""
+
+    def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
+                 norm_topk_prob: bool = True, experts_held=None,
+                 block_rows: int = 256, init="glorot_uniform", **kwargs):
+        super().__init__(**kwargs)
+        self.num_experts = int(num_experts)
+        self.hidden_dim = int(hidden_dim)
+        self.top_k = int(top_k)
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"top_k {top_k} of {num_experts} experts")
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.first, self.count = (0, self.num_experts) \
+            if experts_held is None else map(int, experts_held)
+        if not 0 <= self.first < self.first + self.count \
+                <= self.num_experts:
+            raise ValueError(
+                f"experts_held {experts_held} outside "
+                f"0..{self.num_experts}")
+        self.block_rows = int(block_rows)
+        self.kernel_init = init
+
+    def build(self, rng, input_shape) -> Params:
+        d, e, h = input_shape[-1], self.count, self.hidden_dim
+        params: Params = {}
+        self.add_weight(params, rng, "router", (d, self.num_experts),
+                        init=self.kernel_init)
+        self.add_weight(params, rng, "gate", (e, d, h),
+                        init=self.kernel_init)
+        self.add_weight(params, rng, "up", (e, d, h),
+                        init=self.kernel_init)
+        self.add_weight(params, rng, "down", (e, h, d),
+                        init=self.kernel_init)
+        return params
+
+    def init_state(self, input_shape) -> State:
+        return {"rows_routed": jnp.zeros((self.count + 1,), jnp.int32)}
+
+    def compute_output_shape(self, input_shape):
+        return [tuple(input_shape), (input_shape[0],)]
+
+    def route(self, router, x):
+        """``(gates (N, k) float32, experts (N, k) int32, aux (B,))``
+        for ``x`` (B, T, d): the router in float32 over all experts."""
+        policy = get_policy()
+        b, t, d = x.shape
+        logits = jax.lax.dot_general(
+            policy.cast_compute(x.reshape(b * t, d)),
+            policy.cast_compute(router), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, self.top_k)
+        if self.norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        picked = jnp.sum(jax.nn.one_hot(experts, self.num_experts,
+                                        dtype=jnp.float32), axis=1)
+        share = jnp.mean(picked.reshape(b, t, -1), axis=1) / self.top_k
+        mean_prob = jnp.mean(probs.reshape(b, t, -1), axis=1)
+        aux = self.num_experts * jnp.sum(share * mean_prob, axis=-1)
+        return gates, experts, aux
+
+    def layout(self, experts):
+        """The step's ``(_Route, GroupLayout, rows (count + 1,))`` from
+        the experts picked, (N, k) int32."""
+        from analytics_zoo_tpu.ops import grouped_matmul as gmm
+        count = self.count
+        picked = experts.T                                   # (k, N)
+        k, n = picked.shape
+        held = (picked >= self.first) & (picked < self.first + count)
+        local = jnp.where(held, picked - self.first, count)
+        # an assignment's rank among those to the same expert (pick by
+        # pick, tokens in order): a running count per group, no sort
+        onehot = (local[None] == jnp.arange(count + 1)[:, None, None]
+                  ).astype(jnp.int32)                        # (G + 1, k, N)
+        running = jnp.cumsum(onehot, axis=2)
+        per_pick = running[:, :, -1]                         # (G + 1, k)
+        before = jnp.cumsum(per_pick, axis=1) - per_pick
+        rank = jnp.sum(onehot * (running - 1 + before[:, :, None]), axis=0)
+        rows = jnp.sum(per_pick, axis=1)
+        buf = gmm.buffer_rows(k * n, count, self.block_rows)
+        layout = gmm.group_layout(rows[:count], buf, self.block_rows)
+        start = jnp.sum(onehot[:count] * layout.starts[:, None, None],
+                        axis=0)
+        dest = jnp.where(held, start + rank, buf)
+        pick = jax.lax.broadcasted_iota(jnp.int32, (k, n), 0)
+        token = jax.lax.broadcasted_iota(jnp.int32, (k, n), 1)
+        row_pick = jnp.zeros((buf,), jnp.int32).at[dest].set(
+            pick, mode="drop")
+        row_token = jnp.full((buf,), n, jnp.int32).at[dest].set(
+            token, mode="drop")
+        route = _Route(jnp.minimum(dest, buf - 1), held, row_pick,
+                       row_token)
+        return route, layout, rows
+
+    def apply(self, params, x, state=None, training=False, rng=None):
+        from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
+        compute = get_policy().compute_dtype
+        b, t, d = x.shape
+        gates, experts, aux = self.route(params["router"], x)
+        route, layout, rows = self.layout(experts)
+
+        def held_experts(xt, gates, gate, up, down):
+            # recomputed in the backward pass: the buffers are sized for
+            # the worst split (every assignment held here), 0.8 GB a
+            # layer if kept
+            x_buf = _dispatch(xt, route)
+            g = grouped_matmul(x_buf, gate, layout)
+            u = grouped_matmul(x_buf, up, layout)
+            h = (jax.nn.silu(g.astype(jnp.float32))
+                 * u.astype(jnp.float32)).astype(compute)
+            return _combine(grouped_matmul(h, down, layout), gates, route)
+
+        y = jax.checkpoint(held_experts)(
+            x.reshape(b * t, d).astype(compute), gates.T, params["gate"],
+            params["up"], params["down"])
+        new_state = state
+        if state is not None:
+            new_state = {"rows_routed": state["rows_routed"] + rows}
+        return [y.reshape(x.shape).astype(x.dtype), aux], new_state

@@ -127,6 +127,36 @@ class LayerNorm(Layer):
         return y
 
 
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the last dim with a learned
+    gain and no bias: ``x / sqrt(mean(x^2) + epsilon) * gamma``.  The
+    statistics are taken in float32 whatever ``x`` is, and the result
+    comes back in ``x.dtype``.  Applied to a ``(..., heads, head_dim)``
+    tensor it is the per-head q/k norm (one gain of ``head_dim``)."""
+
+    def __init__(self, epsilon: float = 1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self.epsilon = float(epsilon)
+
+    def build(self, rng, input_shape) -> Params:
+        params: Params = {}
+        self.add_weight(params, rng, "gamma", (input_shape[-1],),
+                        init="one")
+        return params
+
+    def call(self, params, x, training=False, rng=None):
+        return rms_norm(x, params["gamma"], self.epsilon)
+
+
+def rms_norm(x, gamma, epsilon: float):
+    """The ``RMSNorm`` arithmetic (``GroupedQueryAttention`` applies it
+    to q and k per head)."""
+    xf = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                          + epsilon)
+    return (xf * scale * gamma).astype(x.dtype)
+
+
 class L2Normalization(Layer):
     """Unit-L2 normalize along an axis (objectdetection Normalize
     analogue)."""

@@ -29,6 +29,7 @@ from analytics_zoo_tpu.observability import (
     EPOCH_BUCKETS, flush_worker_observability, get_registry,
     get_tracer, sample_device_telemetry)
 from analytics_zoo_tpu.observability.flightrec import record_event
+from analytics_zoo_tpu.observability.moe_stats import MoeStatsReader
 from analytics_zoo_tpu.observability.watchdog import (
     TrainingHalted, TrainingWatchdog, set_active_watchdog)
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
@@ -515,6 +516,9 @@ class Estimator:
                              iteration=it0):
                 value = float(loss)
             trainer.drain_finite()
+            # the same dispatch produced ``state``: the expert layers'
+            # counts are ready too, and are read at no wait
+            moe_stats.read(state, it0)
             return value
 
         def log_loss_crossing(loss, k):
@@ -595,6 +599,9 @@ class Estimator:
                 trainer.warm_start(params, opt_state, state,
                                    warm_batch, rng)
 
+        # the expert layers' routed-row counts, from here on (after any
+        # restore); a model without such layers makes this a no-op
+        moe_stats = MoeStatsReader(self.model, state)
         stop = False
         # install the watchdog only now: the finally below is the ONLY
         # teardown, so nothing may fail between install and the try
@@ -979,6 +986,7 @@ class Estimator:
             # (an epoch's end reads them; a recovery that ran into
             # the end trigger has not)
             trainer.drain_finite()
+            moe_stats.read(state, ts.iteration)
             health_check()
         finally:
             watchdog.stop()

@@ -20,7 +20,10 @@ from jax.sharding import SingleDeviceSharding
 
 from analytics_zoo_tpu.ops import activations as acts
 from analytics_zoo_tpu.ops import fused
-from analytics_zoo_tpu.ops.pallas_attention import flash_attention
+from analytics_zoo_tpu.ops.grouped_matmul import (
+    buffer_rows, group_layout, grouped_matmul)
+from analytics_zoo_tpu.ops.pallas_attention import (
+    block_diffusion, flash_attention)
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +69,11 @@ def _flash(q, k, v):
     return flash_attention(q, k, v)
 
 
+def _flash_block_diffusion(q, k, v):
+    return flash_attention(q, k, v, mask=block_diffusion(4096, 4),
+                           block_q=512, block_k=512)
+
+
 def _grad(fn, n_args):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
                     argnums=tuple(range(n_args)))
@@ -98,6 +106,10 @@ CASES = [
     ("flash-b4h8t4096d128", _flash, [(4, 8, 4096, 128)] * 3, BF16, 1),
     ("flash-grad-b4h8t4096d128", _grad(_flash, 3),
      [(4, 8, 4096, 128)] * 3, BF16, 3),
+    # 8,192 positions (over the old whole-K/V-in-VMEM cap), 32 query
+    # heads on 4 K/V heads, the block-diffusion mask's tile tables
+    ("flash-blockdiff-grad-h32kv4t8192d128", _grad(_flash_block_diffusion, 3),
+     [(1, 32, 8192, 128), (1, 4, 8192, 128), (1, 4, 8192, 128)], BF16, 3),
 ]
 # every activation the LayerNorm epilogue claims to run in-kernel
 CASES += [
@@ -114,6 +126,30 @@ def test_kernel_compiles_for_v5e(v5e, on_tpu, fn, shapes, dtype, kernels):
     # raises what the chip's compiler would raise
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_compiles_for_v5e(v5e, on_tpu, k, n):
+    """The expert products at the widths and the worst-case buffer of
+    the 128-expert cell: 65,536 assignments in 16 block-aligned groups,
+    bfloat16 rows against float32 expert matrices; forward, and the two
+    backward kernels under their names."""
+    rows = buffer_rows(8192 * 8, 16, 256)
+
+    def product(lhs, rhs, sizes):
+        return grouped_matmul(lhs, rhs, group_layout(sizes, rows, 256))
+
+    args = [jax.ShapeDtypeStruct((rows, k), BF16, sharding=v5e),
+            jax.ShapeDtypeStruct((16, k, n), F32, sharding=v5e),
+            jax.ShapeDtypeStruct((16,), jnp.int32, sharding=v5e)]
+    text = jax.jit(product).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "grouped_matmul_fwd" in text
+    grad = jax.grad(lambda *a: jnp.sum(product(*a).astype(F32)), (0, 1))
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "grouped_matmul_dlhs" in text and "grouped_matmul_drhs" in text
 
 
 NAMED = {

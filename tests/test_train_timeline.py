@@ -418,7 +418,8 @@ def test_every_dispatched_step_has_its_flag_read(engine):
 
 # ------------------------------------------------------ names on device work
 @pytest.mark.parametrize("module,sites", [
-    ("fused.py", 4), ("pallas_attention.py", 3)])
+    ("fused.py", 4), ("pallas_attention.py", 3),
+    ("grouped_matmul.py", 1)])
 def test_every_pallas_call_site_passes_a_name(module, sites):
     path = os.path.join(REPO_ROOT, "analytics_zoo_tpu", "ops", module)
     with open(path) as f:
