@@ -27,10 +27,14 @@ _DEFAULTS: Dict[str, Any] = {
     "dtype.compute": "bfloat16",
     # Matmul precision passed to jax ops ("default"|"high"|"highest").
     "dtype.matmul_precision": "default",
-    # Fused kernel suite (ops/fused.py): "auto" = Pallas kernels when
-    # the backend compiles them (one eager capability probe), lax
-    # otherwise; "lax" forces the lax forms; "off" disables the suite
-    # (call sites revert to their unfused pre-suite paths).
+    # Fused kernel suite (ops/fused.py).  For the epilogue kernels
+    # (bias_gelu, layernorm_act): "auto" = Pallas kernels when the
+    # backend compiles them (one eager capability probe) on a
+    # one-device topology, lax otherwise; "pallas" forces the kernels;
+    # "lax" forces the lax forms.  "off" disables the suite (call sites
+    # revert to their unfused pre-suite paths, the optimizer to optax's
+    # triple pass).  The fused optimizer update has one form, plain
+    # jnp arithmetic, and only "off" changes it.
     "ops.fused": "auto",
     # Mesh / distribution ---------------------------------------------
     # Default mesh shape; "auto" = all devices on the data axis,
@@ -66,8 +70,9 @@ _DEFAULTS: Dict[str, Any] = {
     # Fused optimizer update (ops/fused.py): grad clip + moment update
     # + param apply in one pass per leaf — replaces the optax
     # global_norm → update → apply_updates triple traversal (three full
-    # HBM sweeps of params+grads) for SGD/Adam.  Numerically the optax
-    # step (tests/test_fused_kernels.py); unsupported combinations
+    # HBM sweeps of params+grads) for SGD/Adam, as one XLA loop fusion
+    # a leaf in the leaf's own layout.  Numerically the optax step
+    # (tests/test_fused_kernels.py); unsupported combinations
     # (optimizer groups, other optimizers) fall back automatically.
     "train.fused_optimizer": True,
     # Resilience -------------------------------------------------------

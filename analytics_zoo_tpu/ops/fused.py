@@ -1,7 +1,4 @@
-"""Fused Pallas kernel suite — single-HBM-pass hot-path kernels.
-
-Three kernel families, each with a lax form beside it; ONE capability
-probe (``pallas_supported``) decides which runs:
+"""Fused kernel suite — single-HBM-pass hot-path code.
 
 * **Fused optimizer update** (``build_fused_update``): global-norm
   grad clip + SGD/Adam moment update + parameter apply in ONE pass over
@@ -10,28 +7,34 @@ probe (``pallas_supported``) decides which runs:
   materialises a clipped-grads tree, an updates tree, and a new params
   tree — three full HBM sweeps of params+grads per step.  The fused
   path reads each (param, grad, moment) triple once and writes the new
-  (param, moment) in place (``input_output_aliases`` on the Pallas
-  path; XLA elementwise fusion on the lax path — either way, no
-  intermediate trees).  The math REPRODUCES optax op-for-op (same
-  order, same dtypes, same bias-correction formulas), so the fused
-  step is numerically the optax step — proven by
-  ``tests/test_fused_kernels.py`` to the documented tolerance.
+  (param, moment) in place: plain ``jnp`` arithmetic that XLA compiles
+  to one loop fusion a leaf, in the leaf's own shape and layout, on
+  one device and on a mesh alike.  The math REPRODUCES optax op-for-op
+  (same order, same dtypes, same bias-correction formulas), so the
+  fused step is numerically the optax step — ``tests/test_fused_kernels.py``
+  holds it bit-identical.  It has no Pallas form: the one it had
+  needed every leaf as ``(rows, 128)``, a copy in and a copy out
+  (PERF.md, Findings, PR 28).
 
 * **Epilogue kernels** (``bias_gelu``, ``layernorm_act``): the
   bias-add→GeLU and LayerNorm→activation tails of the dense/attention
   stacks, computed without a round trip of the intermediate activation
-  through HBM.  Differentiable: a ``custom_vjp`` runs the Pallas
-  forward and takes the backward from the lax form's own derivative.
+  through HBM.  Each has a Pallas kernel and a lax form beside it; ONE
+  capability probe (``pallas_supported``) decides which runs.
+  Differentiable: a ``custom_vjp`` runs the Pallas forward and takes
+  the backward from the lax form's own derivative.
 
 * The flash-attention kernels live in ``ops/pallas_attention.py`` and
   the cross-chip ring schedule in ``parallel/ring_attention.py`` — this
   module is the single-chip elementwise/reduction half of the suite.
 
-Mode selection (``ops.fused`` config key):
+Mode selection (``ops.fused`` config key) — governs the epilogue
+kernels' path, and whether the suite is on at all:
 
 * ``auto`` (default) — Pallas kernels on a TPU backend (one eager
   probe; a kernel the TPU compiler refuses is an error, never a quiet
   switch to lax), lax on every other backend.
+* ``pallas`` — the Pallas kernels whatever the topology (expert use).
 * ``lax``  — always the lax form (same math, XLA fusion does the work).
 * ``off``  — disable the suite; call sites fall back to their
   pre-suite code paths (the trainer runs the optax triple pass).
@@ -74,8 +77,8 @@ _PALLAS_OK: Optional[bool] = None
 
 def _probe():
     """ONE representative suite kernel — SMEM scalar operand + grid +
-    ``input_output_aliases``, the exact features the optimizer kernels
-    use — as ``(jitted function, argument shapes)``."""
+    ``input_output_aliases`` — as ``(jitted function, argument
+    shapes)``."""
     def k(s_ref, x_ref, o_ref):
         o_ref[:] = x_ref[:] * s_ref[0]
 
@@ -146,17 +149,6 @@ def count_build(kernel: str, path: str) -> None:
         labels=("kernel", "path")).labels(kernel, path).inc()
 
 
-def _leaf_rows(a, min_size: int = 1024) -> Optional[int]:
-    """(rows, 128) layout for a Pallas-eligible leaf; None = use lax.
-    Eligible: f32, size a multiple of 8*128 (the f32 min tile) and at
-    least ``min_size`` elements — below that the kernel-launch overhead
-    buys nothing over XLA's own elementwise fusion."""
-    n = int(np.prod(a.shape)) if a.shape else 0
-    if a.dtype != jnp.float32 or n < min_size or n % (8 * 128):
-        return None
-    return n // 128
-
-
 # Half of the 16 MiB scoped-VMEM limit the v5e compiler enforces on one
 # kernel: the rest is headroom for what the model below does not count
 # (the (1, d) operands, compiler-internal scratch).
@@ -182,120 +174,48 @@ def _row_block(rows: int, d: int, itemsize: int,
     return None
 
 
-# ===================================================== optimizer kernels
-def _adam_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref,
-                 po_ref, mo_ref, vo_ref, *, b1: float, b2: float,
-                 eps: float, weight_decay: float, clip_lo, clip_hi,
-                 use_clip_scale: bool):
-    """One fused pass: clip → (wd) → moments → bias-correct → apply.
-    scal = [clip_scale, step_size, bias_corr1, bias_corr2] (SMEM)."""
-    g = g_ref[:]
-    if use_clip_scale:
-        g = g * scal_ref[0]
-    if clip_lo is not None:
-        g = jnp.clip(g, clip_lo, clip_hi)
+# ====================================================== optimizer update
+# One pass over each leaf WHERE IT LIES: XLA compiles a leaf's update
+# to one loop fusion in the leaf's own shape and tiling, its state
+# aliased in place (tests/test_tpu_aot_compile.py holds that for every
+# leaf shape the benchmark's cells have).  Keep it free of reshapes: on
+# a TPU an array is tiled over its last two dimensions, so another
+# shape is a copy of the leaf, not a view.
+def _prepared_grad(p, g, clip_scale, clip_const, weight_decay: float):
+    """Clip (global-norm scale or constant bounds), then weight decay
+    added to the gradient, in optax's order."""
+    if clip_scale is not None:
+        g = g * clip_scale
+    if clip_const:
+        g = jnp.clip(g, *clip_const)
     if weight_decay:
-        g = g + weight_decay * p_ref[:]
-    m = (1.0 - b1) * g + b1 * m_ref[:]
-    v = (1.0 - b2) * (g ** 2) + b2 * v_ref[:]
-    mo_ref[:] = m
-    vo_ref[:] = v
-    mh = m / scal_ref[2]
-    vh = v / scal_ref[3]
-    po_ref[:] = p_ref[:] + scal_ref[1] * (mh / (jnp.sqrt(vh) + eps))
+        g = g + weight_decay * p
+    return g
 
 
-def _sgd_kernel(scal_ref, p_ref, g_ref, t_ref, po_ref, to_ref, *,
-                momentum: float, nesterov: bool, weight_decay: float,
-                clip_lo, clip_hi, use_clip_scale: bool):
-    g = g_ref[:]
-    if use_clip_scale:
-        g = g * scal_ref[0]
-    if clip_lo is not None:
-        g = jnp.clip(g, clip_lo, clip_hi)
-    if weight_decay:
-        g = g + weight_decay * p_ref[:]
-    tr = g + momentum * t_ref[:]
-    to_ref[:] = tr
-    u = g + momentum * tr if nesterov else tr
-    po_ref[:] = p_ref[:] + scal_ref[1] * u
-
-
-def _pallas_moment_call(kernel, scal, arrays, n_out: int,
-                        interpret: bool, name: str):
-    """Dispatch a per-leaf optimizer kernel over the (rows, 128)
-    re-layout, params/moments aliased in place.  ``name`` is the
-    kernel's name in a compiled program and in a profile."""
-    rows = _leaf_rows(arrays[0])
-    shaped = [a.reshape(rows, 128) for a in arrays]
-    # _leaf_rows guarantees rows % 8 == 0, and 8 rows of 128 lanes fit
-    # the budget for any operand count the suite has
-    br = _row_block(rows, 128, 4, len(arrays) + n_out)
-    grid = (rows // br,)
-    blk = pl.BlockSpec((br, 128), lambda i: (i, 0))
-    # inputs: scal, p, g, (moments...); outputs alias p + moments —
-    # the in-place single sweep (g is the only non-aliased read)
-    aliases = {1: 0}
-    for j in range(n_out - 1):
-        aliases[3 + j] = 1 + j
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=tuple(jax.ShapeDtypeStruct((rows, 128), jnp.float32)
-                        for _ in range(n_out)),
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [blk] * len(shaped),
-        out_specs=tuple(blk for _ in range(n_out)),
-        input_output_aliases=aliases,
-        interpret=interpret,
-        name=name,
-    )(scal, *shaped)
-    shape = arrays[0].shape
-    return tuple(o.reshape(shape) for o in outs)
+def _scaled(step_size, u, step_is_schedule: bool):
+    return (jnp.array(step_size, dtype=u.dtype) * u if step_is_schedule
+            else step_size * u)
 
 
 def adam_leaf_update(p, g, mu, nu, *, b1: float, b2: float, eps: float,
                      step_size, bias_corr1, bias_corr2,
                      clip_scale=None, weight_decay: float = 0.0,
                      clip_const: Optional[Tuple[float, float]] = None,
-                     step_is_schedule: bool = False,
-                     interpret: bool = False):
+                     step_is_schedule: bool = False):
     """One-leaf fused Adam step.  Reproduces
     ``scale_by_adam → scale_by_learning_rate → apply_updates``
     op-for-op; ``bias_corr* = 1 - beta**count_inc`` and ``step_size``
     (the NEGATIVE learning rate) are computed once by the caller.
     Returns ``(new_p, new_mu, new_nu)``."""
-    lo, hi = clip_const if clip_const else (None, None)
-    if ((interpret or _use_pallas()) and _leaf_rows(p) is not None
-            and g.dtype == jnp.float32 and mu.dtype == jnp.float32):
-        count_build("fused_adam", "pallas")
-        scal = jnp.stack([
-            jnp.asarray(clip_scale if clip_scale is not None else 1.0,
-                        jnp.float32),
-            jnp.asarray(step_size, jnp.float32),
-            jnp.asarray(bias_corr1, jnp.float32),
-            jnp.asarray(bias_corr2, jnp.float32)])
-        kern = functools.partial(
-            _adam_kernel, b1=b1, b2=b2, eps=eps,
-            weight_decay=weight_decay, clip_lo=lo, clip_hi=hi,
-            use_clip_scale=clip_scale is not None)
-        return _pallas_moment_call(kern, scal, [p, g, mu, nu], 3,
-                                   interpret, "fused_adam")
     count_build("fused_adam", "lax")
-    if clip_scale is not None:
-        g = g * clip_scale
-    if lo is not None:
-        g = jnp.clip(g, lo, hi)
-    if weight_decay:
-        g = g + weight_decay * p
+    g = _prepared_grad(p, g, clip_scale, clip_const, weight_decay)
     # optax.tree_update_moment order: (1-decay)*(g**order) + decay*t
     mu_n = (1.0 - b1) * g + b1 * mu
     nu_n = (1.0 - b2) * (g ** 2) + b2 * nu
     mh = mu_n / jnp.asarray(bias_corr1, mu_n.dtype)
     vh = nu_n / jnp.asarray(bias_corr2, nu_n.dtype)
-    u = mh / (jnp.sqrt(vh) + eps)
-    u = (jnp.array(step_size, dtype=u.dtype) * u if step_is_schedule
-         else step_size * u)
+    u = _scaled(step_size, mh / (jnp.sqrt(vh) + eps), step_is_schedule)
     return ((p + u).astype(p.dtype), mu_n, nu_n)
 
 
@@ -303,42 +223,18 @@ def sgd_leaf_update(p, g, trace, *, momentum: float, nesterov: bool,
                     step_size, clip_scale=None,
                     weight_decay: float = 0.0,
                     clip_const: Optional[Tuple[float, float]] = None,
-                    step_is_schedule: bool = False,
-                    interpret: bool = False):
+                    step_is_schedule: bool = False):
     """One-leaf fused SGD(+momentum) step mirroring
     ``trace → scale`` + ``apply_updates``.  ``trace`` may be None
     (momentum 0).  Returns ``(new_p, new_trace_or_None)``."""
-    lo, hi = clip_const if clip_const else (None, None)
-    if (trace is not None and (interpret or _use_pallas())
-            and _leaf_rows(p) is not None
-            and g.dtype == jnp.float32):
-        count_build("fused_sgd", "pallas")
-        scal = jnp.stack([
-            jnp.asarray(clip_scale if clip_scale is not None else 1.0,
-                        jnp.float32),
-            jnp.asarray(step_size, jnp.float32),
-            jnp.float32(0.0), jnp.float32(0.0)])
-        kern = functools.partial(
-            _sgd_kernel, momentum=momentum, nesterov=nesterov,
-            weight_decay=weight_decay, clip_lo=lo, clip_hi=hi,
-            use_clip_scale=clip_scale is not None)
-        p_n, t_n = _pallas_moment_call(kern, scal, [p, g, trace], 2,
-                                       interpret, "fused_sgd")
-        return p_n, t_n
     count_build("fused_sgd", "lax")
-    if clip_scale is not None:
-        g = g * clip_scale
-    if lo is not None:
-        g = jnp.clip(g, lo, hi)
-    if weight_decay:
-        g = g + weight_decay * p
+    g = _prepared_grad(p, g, clip_scale, clip_const, weight_decay)
     if trace is not None:
         tr = g + momentum * trace           # optax.trace: f(g, t)
         u = g + momentum * tr if nesterov else tr
     else:
         tr, u = None, g
-    u = (jnp.array(step_size, dtype=u.dtype) * u if step_is_schedule
-         else step_size * u)
+    u = _scaled(step_size, u, step_is_schedule)
     return (p + u).astype(p.dtype), tr
 
 

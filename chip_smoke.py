@@ -62,7 +62,6 @@ from analytics_zoo_tpu.feature.image import decode_image_bytes
 from analytics_zoo_tpu.models.image.imageclassification import resnet
 from analytics_zoo_tpu.models.textclassification import TextClassifier
 from analytics_zoo_tpu.observability import get_registry
-from analytics_zoo_tpu.ops import fused
 from analytics_zoo_tpu.ops.attention import scaled_dot_product_attention
 from analytics_zoo_tpu.ops.pallas_attention import flash_attention
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
@@ -253,26 +252,21 @@ def _fit(model, x, y, batch: int, epochs: int, mesh=None):
 
 def _train_checks(history, before, after, programs,
                   params) -> Tuple[Dict, Dict]:
-    """Checks every training run shares: finite loss, the optimizer
-    kernel on the path the device calls for, one compile.  ``programs``
+    """Checks every training run shares: finite loss, the one-pass
+    optimizer update on every leaf, one compile.  ``programs``
     counts the backend compiles of the fit by program name."""
     losses = [float(h["loss"]) for h in history]
     engines = _delta(after, before, "train_steps_total")
     compiles = _delta(after, before, "jax_compiles_total")
     builds = _delta(after, before, "fused_kernel_builds_total")
     leaves = jax.tree_util.tree_leaves(params)
-    n_pallas = sum(fused._leaf_rows(a) is not None for a in leaves)
     opt = {k: v for k, v in builds.items() if "fused_" in k}
     pallas = sum(v for k, v in opt.items() if 'path="pallas"' in k)
     lax = sum(v for k, v in opt.items() if 'path="lax"' in k)
-    if fused._use_pallas():
-        # every leaf _leaf_rows accepts on the Pallas path, the rest lax:
-        # the two counts keep the leaves' ratio however often the step
-        # was traced
-        kernel_ok = pallas > 0 and \
-            pallas * (len(leaves) - n_pallas) == lax * n_pallas
-    else:
-        kernel_ok = pallas == 0 and lax > 0
+    # the one-pass update built for every leaf, however often the step
+    # was traced, and no optimizer custom call in the program: on one
+    # chip and on a mesh alike
+    kernel_ok = pallas == 0 and lax > 0 and lax % len(leaves) == 0
     train_fns = {k: v for k, v in compiles.items() if "train_" in k}
     slow = {k: v for k, v in programs.items() if v > 1}
     rec = {"loss_per_epoch": losses, "dispatch_engine": engines,
@@ -281,7 +275,7 @@ def _train_checks(history, before, after, programs,
            "programs_compiled_more_than_once": slow}
     checks = {
         "loss_finite_every_epoch": bool(np.all(np.isfinite(losses))),
-        "optimizer_kernel_path": kernel_ok,
+        "optimizer_one_pass_every_leaf": kernel_ok,
         # the registry's monitor keys on shapes and dtypes; a changed
         # input SHARDING recompiles unseen by it, so the backend's own
         # count of the step program (the scan engines' "epoch") decides

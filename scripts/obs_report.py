@@ -262,8 +262,9 @@ def render_report(label: str, snap: Dict,
                 k, path, sum(paths.values()),
                 _fmt_bytes(sv) + "/step" if sv else "-",
                 f"{rf:.2f}x" if rf is not None else "-"])
-        lines += ["", "fused kernel suite (path=lax means the Pallas "
-                  "probe declined — XLA fuses the same math; roofline "
+        lines += ["", "fused kernel suite (path=lax: XLA fuses the same "
+                  "math — the optimizer update's only form, and an "
+                  "epilogue's where the Pallas probe declined; roofline "
                   "1.0 = HBM-bandwidth-bound floor reached):",
                   _table(rows, ["kernel", "path", "builds",
                                 "bytes saved", "roofline"])]

@@ -70,7 +70,11 @@ def test_train_then_serve(one_chip, capsys, tmp_path):
     assert ok, line
     assert line["dispatch_engine"] == {'{path="epoch_scan"}': 8.0}
     assert line["compiles"] == {'{fn="train_epoch_scan"}': 1.0}
-    assert line["kernel_builds"]['{kernel="fused_sgd",path="pallas"}'] > 0
+    # the one-pass update on every leaf, a trace; no optimizer kernel
+    builds = line["kernel_builds"]
+    assert builds['{kernel="fused_sgd",path="lax"}'] > 0
+    assert '{kernel="fused_sgd",path="pallas"}' not in builds
+    assert line["checks"]["optimizer_one_pass_every_leaf"]
     assert len(line["loss_per_epoch"]) == TOY_RESNET["epochs"]
 
     ok, _, line = _phase(capsys, "serve", chip_smoke.serve, model,

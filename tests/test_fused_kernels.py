@@ -2,14 +2,15 @@
 
 Tolerance contract (documented in docs/perf-tuning.md "Kernel suite"):
 
-* lax fallback — BIT-IDENTICAL to the optax/unfused forms: it executes
-  the same ops in the same order inside the same jitted program, so a
-  real train run under the fused update reproduces the optax triple
-  pass exactly (asserted below with zero tolerance).
-* Pallas kernels (interpret mode here; compiled on TPU) — the same
-  formulas evaluated blockwise: ≤ 2e-6 absolute against the lax form
-  for the optimizer kernels and ≤ 2e-6 for the epilogues at unit-scale
-  inputs (float32 reassociation across blocks, nothing structural).
+* fused optimizer update — BIT-IDENTICAL to the optax/unfused forms:
+  it executes the same ops in the same order inside the same jitted
+  program, so a real train run under the fused update reproduces the
+  optax triple pass exactly (asserted below with zero tolerance).  It
+  is plain ``jnp`` arithmetic on every backend: no kernel.
+* epilogue Pallas kernels (interpret mode here; compiled on TPU) — the
+  same formulas evaluated blockwise: ≤ 2e-6 absolute against the lax
+  form at unit-scale inputs (float32 reassociation across blocks,
+  nothing structural).
 """
 
 import json
@@ -36,6 +37,40 @@ def _tree(rs, shapes=((16, 128), (128,), (8, 8))):
 
 
 # ------------------------------------------------ fused update vs optax
+def _assert_fused_is_optax(optim, clip, shapes, grad_dtypes, steps):
+    """Fused clip+update+apply ≡ optax global_norm → update →
+    apply_updates, bit for bit, over ``steps`` steps in one jitted
+    program each; the optax state pytree comes back with the same
+    structure, shapes and dtypes (checkpoints, shardings and
+    ``init_opt_state`` are unaffected)."""
+    fu = fused.build_fused_update(optim, clip)
+    assert fu is not None
+
+    def unfused(g, s, p):
+        g = _apply_clipping(g, clip)
+        upd, s = optim.tx.update(g, s, p)
+        return optax.apply_updates(p, upd), s
+    # deliberately plain jax.jit: a numerics fixture, not an engine
+    # program
+    step_f, step_o = jax.jit(fu), jax.jit(unfused)
+
+    rs = np.random.RandomState(0)
+    params = _tree(rs, shapes)
+    st_f = st_o = optim.tx.init(params)
+    p_f = p_o = params
+    for _ in range(steps):
+        grads = {k: jnp.array(rs.randn(*v.shape), dt)
+                 for (k, v), dt in zip(params.items(), grad_dtypes)}
+        p_f, st_f = step_f(grads, st_f, p_f)
+        p_o, st_o = step_o(grads, st_o, p_o)
+    assert jax.tree_util.tree_structure((p_f, st_f)) == \
+        jax.tree_util.tree_structure((p_o, st_o))
+    for a, b in zip(jax.tree_util.tree_leaves((p_f, st_f)),
+                    jax.tree_util.tree_leaves((p_o, st_o))):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 class TestFusedUpdateVsOptax:
     @pytest.mark.parametrize("name,optim,clip", [
         ("sgd_mom", SGD(0.1, momentum=0.9), None),
@@ -51,42 +86,22 @@ class TestFusedUpdateVsOptax:
         ("adam_decay", Adam(lr=1e-3, decay=0.01), None),
     ])
     def test_bit_identical_under_jit(self, name, optim, clip):
-        """Fused clip+update+apply ≡ optax global_norm → update →
-        apply_updates, bit for bit, over multiple steps in one jitted
-        program each."""
-        fu = fused.build_fused_update(optim, clip)
-        assert fu is not None, f"{name} should be fusable"
+        _assert_fused_is_optax(optim, clip, ((16, 128), (128,), (8, 8)),
+                               [jnp.float32] * 3, steps=6)
 
-        # jits are deliberately plain jax.jit: this is a numerics
-        # fixture, not an engine program
-        step_f = jax.jit(lambda g, s, p: fu(g, s, p))
-
-        def unfused(g, s, p):
-            g = _apply_clipping(g, clip)
-            upd, s = optim.tx.update(g, s, p)
-            return optax.apply_updates(p, upd), s
-        step_o = jax.jit(unfused)
-
-        rs = np.random.RandomState(0)
-        params = _tree(rs)
-        st_f = optim.tx.init(params)
-        st_o = optim.tx.init(params)
-        p_f = p_o = params
-        for _ in range(6):
-            grads = {k: jnp.array(rs.randn(*v.shape), jnp.float32)
-                     for k, v in params.items()}
-            p_f, st_f = step_f(grads, st_f, p_f)
-            p_o, st_o = step_o(grads, st_o, p_o)
-        for a, b in zip(jax.tree_util.tree_leaves(p_f),
-                        jax.tree_util.tree_leaves(p_o)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        # optax state pytree structure preserved exactly (checkpoints,
-        # shardings, init_opt_state all unaffected)
-        assert jax.tree_util.tree_structure(st_f) == \
-            jax.tree_util.tree_structure(st_o)
-        for a, b in zip(jax.tree_util.tree_leaves(st_f),
-                        jax.tree_util.tree_leaves(st_o)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    @pytest.mark.parametrize("clip", [
+        None, ClipSpec("const", -0.01, 0.01), ClipSpec("l2norm", 0.5)],
+        ids=["noclip", "const", "l2norm"])
+    @pytest.mark.parametrize("optim", [
+        SGD(0.05, momentum=0.9, weight_decay=1e-4), Adam(lr=1e-3)],
+        ids=["sgd", "adam"])
+    def test_awkward_leaves_bit_identical_under_jit(self, optim, clip):
+        """Every leaf takes the one path, whatever its shape or its
+        gradient's dtype: an odd-sized leaf, a 1-D one and a bfloat16
+        gradient beside an (8, 128)-tileable one."""
+        _assert_fused_is_optax(
+            optim, clip, ((7, 13), (100,), (3, 5, 8), (16, 128)),
+            [jnp.float32, jnp.float32, jnp.bfloat16, jnp.float32], steps=4)
 
     def test_unsupported_combinations_decline(self):
         assert fused.build_fused_update(RMSprop(1e-3), None) is None
@@ -158,36 +173,8 @@ class TestTrainerFusedPath:
         assert not trainer.fused_optimizer_active
 
 
-# -------------------------------------------- pallas kernels (interpret)
+# ----------------------------------- epilogue pallas kernels (interpret)
 class TestPallasKernelsInterpret:
-    def test_adam_kernel_matches_lax(self):
-        rs = np.random.RandomState(1)
-        p = jnp.array(rs.randn(16, 128), jnp.float32)
-        g = jnp.array(rs.randn(16, 128), jnp.float32)
-        m = jnp.array(rs.randn(16, 128), jnp.float32) * 0.1
-        v = jnp.array(np.abs(rs.randn(16, 128)), jnp.float32) * 0.01
-        kw = dict(b1=0.9, b2=0.999, eps=1e-8, step_size=-1e-3,
-                  bias_corr1=0.1, bias_corr2=1e-3,
-                  clip_scale=jnp.float32(0.5), weight_decay=0.0)
-        got = fused.adam_leaf_update(p, g, m, v, **kw, interpret=True)
-        want = fused.adam_leaf_update(p, g, m, v, **kw)
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=2e-6, rtol=0)
-
-    def test_sgd_kernel_matches_lax(self):
-        rs = np.random.RandomState(2)
-        p = jnp.array(rs.randn(16, 128), jnp.float32)
-        g = jnp.array(rs.randn(16, 128), jnp.float32)
-        t = jnp.array(rs.randn(16, 128), jnp.float32)
-        kw = dict(momentum=0.9, nesterov=True, step_size=-0.1,
-                  weight_decay=1e-4, clip_const=(-0.5, 0.5))
-        got = fused.sgd_leaf_update(p, g, t, **kw, interpret=True)
-        want = fused.sgd_leaf_update(p, g, t, **kw)
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=2e-6, rtol=0)
-
     def test_bias_gelu_matches_unfused(self):
         rs = np.random.RandomState(3)
         x = jnp.array(rs.randn(4, 8, 256), jnp.float32)
@@ -278,14 +265,6 @@ class TestPallasKernelsInterpret:
         got = fused.bias_gelu(x, b, approximate=False, interpret=True)
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(acts.gelu_erf(x + b)))
-
-    def test_ineligible_leaf_uses_lax(self):
-        # 100 elements: not a (8,128)-tile multiple — must not crash,
-        # must take the lax form
-        p = jnp.zeros((100,), jnp.float32)
-        out = fused.sgd_leaf_update(p, p, p, momentum=0.9,
-                                    nesterov=False, step_size=-0.1)
-        assert out[0].shape == (100,)
 
 
 # ----------------------------------------------------- epilogue wiring

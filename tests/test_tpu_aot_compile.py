@@ -12,6 +12,7 @@ a compile is written to it but cannot be read back without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,9 +97,6 @@ CASES = [
      [(16384, 3072), (3072,)], F32, 0),
     ("layernorm_act-grad-16384x3072", _grad(_layernorm_gelu, 3),
      [(16384, 3072), (3072,), (3072,)], F32, 0),
-    # ResNet-50's largest conv leaf through the optimizer kernels
-    ("adam-3x3x512x512", _adam, [(3, 3, 512, 512)] * 4, F32, 1),
-    ("sgd-3x3x512x512", _sgd, [(3, 3, 512, 512)] * 3, F32, 1),
     # flash attention forward and its two backward kernels
     ("flash-b2h12t512d64", _flash, [(2, 12, 512, 64)] * 3, BF16, 1),
     ("flash-grad-b2h12t512d64", _grad(_flash, 3),
@@ -155,8 +153,6 @@ def test_grouped_matmul_compiles_for_v5e(v5e, on_tpu, k, n):
 NAMED = {
     "bias_gelu-16384x768": ["bias_gelu"],
     "layernorm_act-16384x768": ["layernorm_act"],
-    "adam-3x3x512x512": ["fused_adam"],
-    "sgd-3x3x512x512": ["fused_sgd"],
     "flash-grad-b2h12t512d64": ["flash_attention_fwd", "flash_attention_dq",
                                 "flash_attention_dkv"],
 }
@@ -177,6 +173,44 @@ def test_kernel_name_is_the_compiled_instruction_name(v5e, on_tpu, case):
         hits = [c for c in calls if name in c.split(" = ")[0]]
         assert len(hits) == 1, (name, [c.split(" = ")[0] for c in calls])
         assert f"{name}" in hits[0].split("op_name=")[1]
+
+
+# Leaf shapes the benchmark's cells hold: one layer's experts, the head
+# and the embedding of the sparse cell, GPT's table and FFN, ResNet's
+# largest and smallest kernels, its stem and classifier, a bias.
+LEAVES = [(16, 2048, 768), (2048, 18992), (18992, 2048), (40990, 768),
+          (768, 3072), (3, 3, 512, 512), (1, 1, 64, 256), (7, 7, 3, 64),
+          (2048, 1000), (768,)]
+# (update, operands p g moments..., which of them are the train state)
+UPDATES = {"adam": (_adam, 4, (0, 2, 3)), "sgd": (_sgd, 3, (0, 2))}
+
+
+@pytest.mark.parametrize("shape", LEAVES,
+                         ids=["x".join(map(str, s)) for s in LEAVES])
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_optimizer_update_is_one_fusion_in_the_leafs_own_layout(
+        v5e, on_tpu, update, shape):
+    """The per-leaf update on one TPU device (where the suite takes a
+    Pallas branch wherever it has one), state donated as the train step
+    donates it: the compiled entry computation is ONE loop fusion over
+    the operands as they lie (whatever tiling the compiler gives that
+    shape), parameter and moments written in place.  No kernel, and
+    none of what a kernel's ``(rows, 128)`` operands cost: a
+    ``reshape`` or ``copy`` of a leaf, a staging ``copy-start`` /
+    ``slice-start``."""
+    fn, n_args, state = UPDATES[update]
+    args = [jax.ShapeDtypeStruct(shape, F32, sharding=v5e)] * n_args
+    text = jax.jit(fn, donate_argnums=state).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    for op in ("reshape", "copy", "copy-start", "slice-start"):
+        assert f" {op}(" not in entry, op
+    assert entry.count(" fusion(") == 1
+    alias = text[text.index("input_output_alias={"):]
+    alias = alias[:alias.index("entry_computation_layout")]
+    pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", alias)
+    assert [(int(o), int(i)) for o, i in pairs] == list(enumerate(state))
 
 
 def test_capability_probe_compiles_for_v5e(v5e):

@@ -418,7 +418,7 @@ def test_every_dispatched_step_has_its_flag_read(engine):
 
 # ------------------------------------------------------ names on device work
 @pytest.mark.parametrize("module,sites", [
-    ("fused.py", 4), ("pallas_attention.py", 3),
+    ("fused.py", 3), ("pallas_attention.py", 3),
     ("grouped_matmul.py", 1)])
 def test_every_pallas_call_site_passes_a_name(module, sites):
     path = os.path.join(REPO_ROOT, "analytics_zoo_tpu", "ops", module)
@@ -442,22 +442,16 @@ def test_kernel_names_reach_the_jaxpr():
     from analytics_zoo_tpu.ops import fused
     from analytics_zoo_tpu.ops.pallas_attention import flash_attention
 
-    def step(p, g, m, v, t, q, x, b):
-        adam = fused.adam_leaf_update(
-            p, g, m, v, b1=0.9, b2=0.999, eps=1e-8, step_size=-1e-3,
-            bias_corr1=0.1, bias_corr2=0.001, interpret=True)
-        sgd = fused.sgd_leaf_update(p, g, t, momentum=0.9, nesterov=False,
-                                    step_size=-0.1, interpret=True)
+    def step(q, x, b):
         att = jax.grad(lambda q: jnp.sum(flash_attention(
             q, q, q, causal=True, block_q=8, block_k=8,
             interpret=True)))(q)
         gelu = fused.bias_gelu(x, b, interpret=True)
         norm = fused.layernorm_act(x, b, b, interpret=True)
-        return adam, sgd, att, gelu, norm
+        return att, gelu, norm
 
-    leaf = jnp.ones((8, 128), jnp.float32)
     jaxpr = jax.make_jaxpr(step)(
-        leaf, leaf, leaf, leaf, leaf, jnp.ones((1, 1, 16, 8), jnp.float32),
+        jnp.ones((1, 1, 16, 8), jnp.float32),
         jnp.ones((8, 128), jnp.float32), jnp.ones((128,), jnp.float32))
     found = set()
 
@@ -470,9 +464,8 @@ def test_kernel_names_reach_the_jaxpr():
                 if hasattr(inner, "eqns"):
                     walk(inner)
     walk(jaxpr.jaxpr)
-    assert found == {"fused_adam", "fused_sgd", "flash_attention_fwd",
-                     "flash_attention_dq", "flash_attention_dkv",
-                     "bias_gelu", "layernorm_act"}
+    assert found == {"flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv", "bias_gelu", "layernorm_act"}
 
 
 def test_step_scopes_reach_the_compiled_op_names():
