@@ -13,9 +13,8 @@ COMPILE003 recompile hazards (jit-in-loop, f-strings on traced
            the static twin of diagnostics.CompileMonitor's churn
            warnings
 COMPILE011 direct jax.jit/pjit construction in analytics_zoo_tpu/
-           outside the compile/ chokepoint — the program silently
-           opts out of AOT warm-start + the persistent executable
-           cache (use compile.engine_jit)
+           outside the compile/ chokepoint — the program has no
+           name and no warm() (use compile.engine_jit)
 DONATE004  training steps that thread params/opt-state through jit
            without donate_argnums (double HBM for the update)
 RACE005    module-level mutable state written without a lock in
@@ -621,15 +620,13 @@ class EngineChokepointRule(Rule):
     """Every engine-built jit must go through the ``compile/``
     chokepoint.
 
-    Why: ``analytics_zoo_tpu.compile.engine_jit`` is the platform's
-    single lowering chokepoint — it is what gives every compiled
-    program the AOT fast path, the persistent executable cache (141s
-    ResNet-50 cold compile → ~seconds warm deserialize, BENCH_r05),
-    the compile-farm write policy, and the cache hit/miss accounting.
-    A direct ``jax.jit``/``pjit`` construction silently opts that
-    program OUT of all of it: it recompiles in every process forever
-    and its cold-start never shows up in the cache counters.  Scoped
-    to ``analytics_zoo_tpu/`` (examples/tests/scripts are free to jit
+    Why: ``analytics_zoo_tpu.compile.engine_jit`` is the one place a
+    compiled program is built: it gives the program its name
+    (``key_hint``) and ``warm()``, the compile ahead of the first
+    request or step.  A direct ``jax.jit``/``pjit`` construction has
+    neither, and a change to how programs are built (a compiler
+    option, a name in the profile) would miss it.  Scoped to
+    ``analytics_zoo_tpu/`` (examples/tests/scripts are free to jit
     directly); ``compile/`` itself is the one place allowed to touch
     the raw wrappers.
     """
@@ -637,8 +634,8 @@ class EngineChokepointRule(Rule):
     rule_id = "COMPILE011"
     severity = "error"
     doc = ("direct jax.jit/pjit construction outside the compile/ "
-           "chokepoint — bypasses the AOT path + persistent "
-           "executable cache (use engine_jit)")
+           "chokepoint — the program has no name and no warm() "
+           "(use engine_jit)")
 
     SCOPE = "analytics_zoo_tpu/"
     EXEMPT = ("analytics_zoo_tpu/compile/",)
@@ -652,8 +649,7 @@ class EngineChokepointRule(Rule):
         self.report(
             node,
             f"direct {name}(...) bypasses the engine_jit chokepoint — "
-            f"this program gets no AOT warm-start, no persistent "
-            f"executable cache entry, and no cache accounting; build "
+            f"this program gets no name and no warm(); build "
             f"it with analytics_zoo_tpu.compile.engine_jit (same "
             f"static_argnums/donate_argnums/shardings semantics)")
 

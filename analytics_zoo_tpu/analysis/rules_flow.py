@@ -10,8 +10,8 @@ the full entries with their runtime twins):
 DONATE012  use-after-donate: a value passed in a donated position of
            an ``engine_jit``/jit call is read again on some later
            path — a runtime error on TPU, a silent no-op on the CPU
-           tier-1 runs (rebinding re-arms; ``.aot``/``.warm`` never
-           execute and are exempt)
+           tier-1 runs (rebinding re-arms; ``.warm`` never executes
+           and is exempt)
 ACK013     stream-record obligations in ``serving/``: every consumed
            record must be discharged exactly once per ownership path
            (ack / ``dead_letter`` / quarantine / serve / a re-raise
@@ -274,8 +274,8 @@ class UseAfterDonateRule(Rule):
     raises may already have consumed its buffers, which is why
     ``DecodeSlotPool`` rebuilds state in its handlers).  Rebinding
     re-arms the name (``params, opt = step(params, opt)`` is the
-    sanctioned pattern); ``.warm(...)``/``.aot(...)`` pre-lower
-    without executing and never donate.
+    sanctioned pattern); ``.warm(...)`` compiles without executing
+    and never donates.
     """
 
     rule_id = "DONATE012"

@@ -86,9 +86,8 @@ def main(argv=None) -> int:
     p_demo.add_argument("--rows-per-shard", type=int, default=128)
     p_demo.add_argument("--batch-size", type=int, default=32)
     p_demo.add_argument("--keras", action="store_true",
-                        help="score through a jitted KerasNet (warms "
-                             "the run-dir compile farm) instead of "
-                             "the numpy stand-in")
+                        help="score through a jitted KerasNet "
+                             "instead of the numpy stand-in")
     p_demo.add_argument("--timeout", type=float, default=300.0)
     p_demo.add_argument("--report-out", default=None)
     p_demo.set_defaults(fn=cmd_demo)

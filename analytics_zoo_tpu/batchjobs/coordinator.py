@@ -11,9 +11,8 @@ Composes the existing control planes instead of inventing new ones:
   ``RetryBudget``, real errors too — budget exhaustion ends the job
   with the structured degraded record (exit 17 via the CLI), never a
   silent hang;
-* **compile farm (PR 8)**: the run dir IS the executable cache —
-  ZOO_TPU_RUN_DIR rides the worker env, process 0 pays the compiles,
-  replacement incarnations deserialize warm;
+* **compiles**: the first incarnation pays them; replacement
+  incarnations read JAX's persistent compilation cache;
 * **ledger (this PR)**: completion is a property of the manifest
   (every shard committed), NOT of worker exit codes — a worker that
   dies after its last commit changes nothing, a worker that exits 0

@@ -62,10 +62,8 @@ def demo_model(dim: int = 8, out_dim: int = 4, seed: int = 7,
 
 
 def demo_keras_model(dim: int = 8, out_dim: int = 4):
-    """The real jax path: a KerasNet behind ``InferenceModel`` — its
-    ``warm()`` runs under the PR 8 compile farm when the worker env
-    carries ZOO_TPU_RUN_DIR, so a replacement incarnation deserializes
-    the warm executable instead of recompiling."""
+    """The real jax path: a KerasNet behind ``InferenceModel``, whose
+    ``warm()`` the worker calls before its first shard."""
     from analytics_zoo_tpu.pipeline.api.keras import Sequential
     from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
     from analytics_zoo_tpu.pipeline.inference import InferenceModel

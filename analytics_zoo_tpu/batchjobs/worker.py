@@ -9,11 +9,10 @@ ZOO_TPU_CHAOS for fault drills).  Each incarnation:
   and beats the PR 6 heartbeat every batch — the heartbeat is what
   lets the coordinator's detector distinguish "slow" from "dead",
   while the *lease* renewal is what fences the shard ledger;
-* rebuilds source + model from the job spec.  Model warm-up happens
-  under the PR 8 compile farm automatically: the coordinator exports
-  ZOO_TPU_RUN_DIR, so ``engine_jit`` resolves ``<run_dir>/
-  compile-cache`` with process 0 writing and replacements/other hosts
-  deserializing warm executables instead of recompiling;
+* rebuilds source + model from the job spec.  The model's
+  ``warm()`` compiles its program before the first shard; a
+  replacement incarnation reads the executable from JAX's persistent
+  compilation cache instead of recompiling;
 * runs the claim→score→commit loop.  The loop carries the same
   exactly-once obligation the serving consumer does (zoolint ACK013,
   now scoped over ``batchjobs/``): every claimed shard is committed,
@@ -257,9 +256,8 @@ def main() -> int:
     model = build_model(job)
     worker = BatchWorker(job, run_dir, process_id=pid, model=model,
                          heartbeat=heartbeat, chaos=chaos)
-    # best-effort AOT warm through the compile farm (PR 8): with
-    # ZOO_TPU_RUN_DIR set the executable cache lives in the run dir,
-    # process 0 writes, replacements deserialize warm
+    # best-effort warm: the compile lands here, not in the first
+    # shard (a replacement reads it from JAX's persistent cache)
     warm = getattr(model, "warm", None)
     if callable(warm):
         try:

@@ -82,17 +82,12 @@ def run_resnet_bench(device, batch_size: int = 128, image_size: int = 224,
     epoch_fn = trainer.epoch_scan_fn(scan_steps, batch_size,
                                      unroll=unroll)
 
-    # AOT-compile ONCE through the engine chokepoint; the compiled
-    # object serves every execution AND the FLOPs query (lowering via
-    # the jit dispatch path would compile the multi-minute epoch
-    # program a second time).  With ZOO_TPU_COMPILE_CACHE set (bench
-    # --compile-cache), THIS is the 141s program that round-trips the
-    # persistent cache: the first round compiles + persists, every
-    # later round deserializes in seconds — t_compile below is the
-    # number bench_metrics.json's compile_cache provenance explains.
+    # Compile ONCE; the compiled object serves every execution AND
+    # the FLOPs query.  JAX's persistent compilation cache answers
+    # this compile on a later round over the same cache directory.
     t_compile = time.time()
-    compiled = epoch_fn.aot(params, opt_state, state, x_dev, y_dev,
-                            rng)
+    compiled = epoch_fn.lower(params, opt_state, state, x_dev, y_dev,
+                              rng).compile()
 
     flops, hbm_bytes = cost_of_compiled(compiled)
     if flops:

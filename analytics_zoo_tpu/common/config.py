@@ -90,28 +90,6 @@ _DEFAULTS: Dict[str, Any] = {
     # .check_health) BEFORE a collective hangs on it.
     "resilience.heartbeat_interval_s": 5.0,
     "resilience.heartbeat_timeout_s": 30.0,
-    # AOT compilation / executable cache ------------------------------
-    # Route engine-built jits through the AOT fast path (lower once,
-    # compile explicitly, dispatch the Compiled).  Off = every
-    # engine_jit degrades to plain jax.jit dispatch.
-    "compile.aot": True,
-    # Persistent executable-cache directory ("" = no explicit dir; the
-    # ZOO_TPU_COMPILE_CACHE env overrides, and farm mode below may
-    # derive one from the launcher run dir).  A warm directory turns
-    # the 141s ResNet-50 cold compile (BENCH_r05) into a ~seconds
-    # deserialize.
-    "compile.cache_dir": "",
-    # Whether this process persists entries (reads are always on when
-    # a dir resolves).  Farm mode forces workers read-only.
-    "compile.cache_write": True,
-    # Cache-directory size cap in MB; oldest-by-recency entries are
-    # LRU-evicted past it (compile_cache_evictions_total). 0 = no cap.
-    "compile.cache_max_mb": 2048,
-    # Compile-farm mode: inside a launcher run dir (ZOO_TPU_RUN_DIR)
-    # with no explicit cache dir, host 0 compiles + persists into
-    # <run_dir>/compile-cache and workers deserialize instead of
-    # recompiling (rides the PR 4 run-dir env contract).
-    "compile.farm": True,
     # Input pipeline ---------------------------------------------------
     # Device-batch prefetch depth (background thread overlapping host
     # batch assembly + H2D copy with device compute); 0 disables.

@@ -1,15 +1,5 @@
-"""AOT compilation + persistent executable cache.
+"""``engine_jit``: the one place a compiled program is built
+(docs/aot-compile.md)."""
 
-``engine_jit`` is the single lowering chokepoint every compiled
-program in ``analytics_zoo_tpu/`` is built through (zoolint COMPILE011
-enforces it); :mod:`.cache` turns compiled XLA executables into
-content-addressed on-disk artifacts so a warm process deserializes in
-seconds where a cold one pays the full compile (141s for ResNet-50,
-BENCH_r05).  See docs/aot-compile.md.
-"""
-
-from analytics_zoo_tpu.compile.cache import (  # noqa: F401
-    ENV_CACHE_DIR, ExecutableCache, backend_signature, cache_key,
-    get_cache, reset_cache_state, resolve_cache_dir, runtime_versions)
 from analytics_zoo_tpu.compile.engine import (  # noqa: F401
-    EngineJit, call_signature, engine_jit)
+    EngineJit, engine_jit)

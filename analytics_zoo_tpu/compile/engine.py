@@ -1,384 +1,77 @@
-"""engine_jit — the single lowering chokepoint for every engine-built
-jit.
+"""engine_jit — ``jax.jit`` with a name: the one place every compiled
+program in ``analytics_zoo_tpu/`` is built (zoolint COMPILE011 holds
+every other module to it).
 
-Every compiled program in ``analytics_zoo_tpu/`` (trainer steps, the
-estimators, serving/inference predict, utility gathers) is built
-through :func:`engine_jit` instead of raw ``jax.jit``/``pjit`` —
-enforced by zoolint COMPILE011.  The chokepoint is what makes three
-things possible without touching any call site:
-
-* **AOT compilation**: per abstract signature, the wrapper lowers
-  once (``jax.jit(...).lower()``), compiles explicitly, and dispatches
-  the resulting ``Compiled`` — the pattern from the pjit AOT
-  internals (SNIPPETS.md [1]) — instead of relying on the implicit
-  per-process jit cache.
-* **The persistent executable cache** (:mod:`.cache`): the lowered
-  program's content digest addresses an on-disk serialized
-  executable; a warm process deserializes in ~seconds where a cold
-  one pays the full XLA compile (141s for ResNet-50, BENCH_r05).
-* **Warm-start entrypoints**: :meth:`EngineJit.warm` lowers and
-  compiles (or cache-loads) ahead of the first dispatch, so
-  Estimator/serving pre-pay the compile at startup where it is
-  attributable — and a PR 6 re-formed mesh whose signature was seen
-  before skips recompilation entirely.
-
-Fallback ladder (never a behavior change, only a speed change):
-no cache dir configured → plain ``jax.jit`` dispatch; lowering or
-(de)serialization fails → plain jit with a loud counter; a
-``Compiled`` rejects its call args (stricter placement rules than
-jit's auto-reshard) → that signature permanently falls back to jit.
-Execution errors (OOM, collective failures) are NEVER absorbed — they
-propagate exactly as the jit path would, so the estimator's recovery
-machinery keeps its contract.
+Dispatch is jit's own, and so are both caches: the in-process one that
+``CompileMonitor`` reads for its accounting, and the persistent one on
+disk (``analytics_zoo_tpu._place_compile_cache``;
+``JAX_COMPILATION_CACHE_DIR`` places it from outside).  What the
+chokepoint adds is ``key_hint``, the program's name, and :meth:`warm`,
+which pays a program's compile before its first request or step.  See
+docs/aot-compile.md.
 """
 
 from __future__ import annotations
 
-import hashlib
 import logging
-import os
-import threading
-import time
-from typing import Any, Dict, Optional, Set, Tuple
+from typing import Optional
+
+import jax
 
 log = logging.getLogger("analytics_zoo_tpu.compile")
 
 _UNSPECIFIED = object()
 
 
-def _sharding_sig(leaf) -> Any:
-    """Canonical, hashable form of a leaf's placement for the call
-    signature: named shardings by (mesh shape, spec) — the part that
-    determines the compiled program — single-device/uncommitted
-    buffers collapse to one bucket (the Compiled call validates the
-    actual device; a mismatch falls back per-signature)."""
-    sharding = getattr(leaf, "sharding", None)
-    if sharding is None:
-        return None
-    try:
-        from jax.sharding import NamedSharding, SingleDeviceSharding
-        if isinstance(sharding, NamedSharding):
-            return ("mesh", tuple(sharding.mesh.shape.items()),
-                    str(sharding.spec))
-        if isinstance(sharding, SingleDeviceSharding):
-            # same bucket as sharding-less leaves (ShapeDtypeStruct):
-            # a spec-based warm() must produce the signature the
-            # concrete first call will look up
-            return None
-        return repr(sharding)[:120]
-    except Exception:   # noqa: BLE001
-        return "?"
-
-
-def call_signature(args: Tuple, static_argnums: Tuple[int, ...] = ()
-                   ) -> Tuple:
-    """Hashable abstract signature of a call: per argument the pytree
-    structure plus (shape, dtype, sharding) per leaf — the same
-    shape/dtype keys CompileMonitor and COMPILE003 track, extended
-    with placement.  Static positions key on their VALUE (they are
-    baked into the program); python scalars elsewhere key on type
-    only (weak-typed: the value never retraces)."""
-    import jax
-    parts = []
-    for i, a in enumerate(args):
-        if i in static_argnums:
-            parts.append(("static", repr(a)))
-            continue
-        treedef = jax.tree_util.tree_structure(
-            a, is_leaf=lambda v: v is None)
-        leaves = []
-        for leaf in jax.tree_util.tree_leaves(
-                a, is_leaf=lambda v: v is None):
-            if leaf is None:
-                leaves.append(None)
-            elif hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-                leaves.append((tuple(leaf.shape), str(leaf.dtype),
-                               _sharding_sig(leaf)))
-            else:
-                leaves.append(("py", type(leaf).__name__))
-        parts.append((treedef, tuple(leaves)))
-    return tuple(parts)
-
-
 class EngineJit:
-    """A jit-compatible callable with an AOT + persistent-cache fast
-    path.  Transparent to wrappers: unknown attributes (``lower``,
-    ``eval_shape``, ...) forward to the underlying jitted function,
-    so ``CompileMonitor.wrap`` and ``benchmarks.compiled_flops`` keep
-    working on it unchanged."""
+    """A jitted function that knows its name.  Attributes it does not
+    have (``lower``, ``eval_shape``, ``_cache_size``, ...) are the
+    jitted function's."""
 
     def __init__(self, fn, *, static_argnums=(), donate_argnums=(),
                  in_shardings=_UNSPECIFIED,
                  out_shardings=_UNSPECIFIED,
                  key_hint: Optional[str] = None):
-        import jax
-        if isinstance(static_argnums, int):
-            static_argnums = (static_argnums,)
-        kwargs: Dict[str, Any] = {
-            "static_argnums": tuple(static_argnums),
-            "donate_argnums": donate_argnums,
-        }
+        kwargs = {"static_argnums": static_argnums,
+                  "donate_argnums": donate_argnums}
         if in_shardings is not _UNSPECIFIED:
             kwargs["in_shardings"] = in_shardings
         if out_shardings is not _UNSPECIFIED:
             kwargs["out_shardings"] = out_shardings
-        self._fn = fn
         self._jit = jax.jit(fn, **kwargs)
-        self._static = tuple(static_argnums)
-        self._donate = donate_argnums
         self.key_hint = key_hint or getattr(fn, "__qualname__", None) \
             or getattr(fn, "__name__", None) or "fn"
-        #: signature -> live jax.stages.Compiled
-        self._compiled: Dict[Tuple, Any] = {}
-        #: the ONE live executable while exactly one signature exists —
-        #: the hot-path shortcut: dispatch it optimistically without
-        #: recomputing the call signature (a whole-pytree walk; params
-        #: can be thousands of leaves).  The Compiled validates its
-        #: args BEFORE executing/donating, so shape/dtype/placement
-        #: drift raises cleanly into the slow path instead of running
-        #: wrong.  Cleared the moment a second signature (or any
-        #: fallback) appears.
-        self._solo: Optional[Any] = None
-        #: signatures permanently routed to the plain jit path
-        self._fallback: Set[Tuple] = set()
-        self._cache = _UNSPECIFIED   # resolved lazily on first call
-        self._lock = threading.Lock()
 
-    # ------------------------------------------------------------ plumbing
     def __getattr__(self, item):
         return getattr(self._jit, item)
 
-    def _resolve_cache(self):
-        if self._cache is _UNSPECIFIED:
-            try:
-                from analytics_zoo_tpu.compile.cache import get_cache
-                self._cache = get_cache()
-            except Exception:   # noqa: BLE001
-                self._cache = None
-        return self._cache
-
-    def _signature(self, args) -> Optional[Tuple]:
-        try:
-            return call_signature(args, self._static)
-        except Exception:   # noqa: BLE001 — unhashable exotic args
-            return None
-
-    def _dynamic_args(self, args) -> Tuple:
-        """Static positions are baked into the Compiled — drop them."""
-        if not self._static:
-            return args
-        return tuple(a for i, a in enumerate(args)
-                     if i not in self._static)
-
-    def _aot_enabled(self) -> bool:
-        """The ``compile.aot`` kill switch: False must disable the
-        WHOLE AOT path — including warm()/aot(), which otherwise
-        compile and install a Compiled that __call__ would then
-        dispatch (the documented contract is 'off = plain jax.jit
-        dispatch')."""
-        try:
-            from analytics_zoo_tpu.common.config import get_config
-            return bool(get_config().get("compile.aot", True))
-        except Exception:   # noqa: BLE001
-            return True
-
-    def _monitor(self):
-        try:
-            from analytics_zoo_tpu.observability.diagnostics import (
-                get_compile_monitor)
-            return get_compile_monitor()
-        except Exception:   # noqa: BLE001
-            return None
-
-    # ---------------------------------------------------------------- AOT
-    def _cache_key(self, lowered, sig) -> Optional[str]:
-        from analytics_zoo_tpu.compile.cache import cache_key
-        try:
-            hlo = lowered.as_text()
-        except Exception:   # noqa: BLE001
-            return None
-        return cache_key(
-            hashlib.sha256(hlo.encode()).hexdigest(),
-            repr(sig), donate_repr=repr(self._donate),
-            static_repr=repr(self._static))
-
-    def _acquire(self, args, sig, persist: bool = True):
-        """Load-or-compile the executable for ``sig``: lower, look the
-        content key up in the persistent cache, deserialize on hit,
-        compile (and persist) on miss.  Returns None when the AOT path
-        is unavailable for these args (caller falls back to jit)."""
-        cache = self._resolve_cache()
-        monitor = self._monitor()
-        t0 = time.perf_counter()
-        try:
-            lowered = self._jit.lower(*args)
-        except Exception:   # noqa: BLE001 — fall back, don't guess
-            log.debug("engine_jit %r: lowering failed; plain jit path",
-                      self.key_hint, exc_info=True)
-            return None
-        key = None
-        if cache is not None:
-            key = self._cache_key(lowered, sig)
-            if key is not None:
-                exe = cache.load(key)
-                if exe is not None:
-                    if monitor is not None:
-                        monitor.record_cache_event(
-                            self.key_hint, hit=True,
-                            seconds=time.perf_counter() - t0)
-                    log.info(
-                        "engine_jit %r: executable cache HIT "
-                        "(%.2fs load, key %s...)", self.key_hint,
-                        time.perf_counter() - t0, key[:12])
-                    return exe
-            if monitor is not None:
-                monitor.record_cache_event(self.key_hint, hit=False)
-        try:
-            exe = lowered.compile()
-        except Exception:   # noqa: BLE001
-            log.debug("engine_jit %r: AOT compile failed; plain jit "
-                      "path", self.key_hint, exc_info=True)
-            return None
-        if cache is not None and key is not None and persist:
-            cache.store(key, exe, key_hint=self.key_hint)
-        return exe
-
-    # --------------------------------------------------------------- calls
     def __call__(self, *args):
-        # static-argnum programs never take the shortcut: a changed
-        # static VALUE leaves the dynamic avals identical, so the
-        # Compiled's validation could not catch the drift and would
-        # silently run the old baked-in constant
-        exe = self._solo if not self._static else None
-        if exe is not None:
-            try:
-                return exe(*args)
-            except (TypeError, ValueError):
-                # signature drift (or a genuinely bad call): recompute
-                # the signature on the slow path, which compiles the
-                # new shape or surfaces the real error via plain jit.
-                # Validation raises BEFORE execution/donation, so the
-                # caller's buffers are intact for the retry.
-                pass
-        return self._call_slow(*args)
-
-    def _call_slow(self, *args):
-        cache = self._resolve_cache()
-        if cache is None and not self._compiled:
-            return self._jit(*args)
-        sig = self._signature(args)
-        if sig is None or sig in self._fallback:
-            return self._jit(*args)
-        exe = self._compiled.get(sig)
-        if exe is None:
-            if cache is None:
-                return self._jit(*args)
-            with self._lock:
-                exe = self._compiled.get(sig)
-                if exe is None:
-                    exe = self._acquire(args, sig)
-                    if exe is None:
-                        # zoolint: disable=ATOM017 — the unlocked guard at the top of _call_slow is a fast-path skip; set.add is idempotent, so two threads passing it merely both mark the same sig
-                        self._fallback.add(sig)
-                        self._solo = None
-                        return self._jit(*args)
-                    self._compiled[sig] = exe
-                    self._solo = exe if len(self._compiled) == 1 \
-                        else None
-        try:
-            return exe(*self._dynamic_args(args))
-        except (TypeError, ValueError):
-            # a Compiled validates placement strictly where jit would
-            # auto-reshard (e.g. a committed arg on an unexpected
-            # device); validation raises BEFORE execution/donation, so
-            # the plain jit retry sees intact buffers.  Execution
-            # errors are other types and propagate above.
-            log.warning(
-                "engine_jit %r: compiled executable rejected its call "
-                "args; this signature falls back to the plain jit "
-                "path", self.key_hint, exc_info=True)
-            from analytics_zoo_tpu.compile.cache import _count_error
-            _count_error("call")
-            with self._lock:
-                # eviction after the executable itself raised: keyed on
-                # the exception, not on the earlier (unlocked fast-path)
-                # cache probes, and add/pop-with-default are idempotent
-                # zoolint: disable=ATOM017 — idempotent eviction, not a stale-guard decision
-                self._fallback.add(sig)
-                # zoolint: disable=ATOM017 — idempotent eviction, not a stale-guard decision
-                self._compiled.pop(sig, None)
-                self._solo = None
-            return self._jit(*args)
-
-    # ---------------------------------------------------------- warm-start
-    def aot(self, *args):
-        """Load-or-compile the AOT executable for these args and
-        return the live ``jax.stages.Compiled`` — for callers that
-        hold the compiled object directly (the bench's
-        ``epoch_fn.lower().compile()`` idiom, which would bypass the
-        persistent cache).  Falls back to a direct lower+compile when
-        the AOT path is unavailable for these args, so it always
-        returns a Compiled.  Remember statics are baked in: call the
-        result with the dynamic args only."""
-        if not self._aot_enabled():
-            return self._jit.lower(*args).compile()
-        sig = self._signature(args)
-        if sig is not None and sig not in self._fallback:
-            with self._lock:
-                exe = self._compiled.get(sig)
-                if exe is None:
-                    exe = self._acquire(args, sig)
-                    if exe is not None:
-                        self._compiled[sig] = exe
-                        self._solo = exe if len(self._compiled) == 1 \
-                            else None
-            if exe is not None:
-                return exe
-        return self._jit.lower(*args).compile()
+        return self._jit(*args)
 
     def warm(self, *args) -> bool:
-        """AOT warm-start: ensure an executable for this signature is
-        ready — deserialized from the persistent cache or compiled now
-        (and persisted) — WITHOUT executing anything.  ``args`` may be
-        concrete arrays or ``jax.ShapeDtypeStruct``s (with shardings
-        attached for sharded programs).  Never donates, never runs a
-        step.  Returns whether the AOT executable is in place."""
-        if not self._aot_enabled():
+        """Compile the program for these arguments without running it:
+        ``args`` are concrete arrays or ``jax.ShapeDtypeStruct``s
+        (with their shardings for a sharded program); nothing is
+        donated.  jit finds the executable again at the first call of
+        the same signature, which then fires no backend compile
+        (``tests/test_engine_jit.py`` holds that).  False when the
+        program could not be compiled for them: the first call then
+        compiles, or raises what this swallowed."""
+        try:
+            self._jit.lower(*args).compile()
+        except Exception:   # noqa: BLE001 — warming is best-effort
+            log.debug("engine_jit %r: warm failed; the first call "
+                      "compiles", self.key_hint, exc_info=True)
             return False
-        sig = self._signature(args)
-        if sig is None or sig in self._fallback:
-            return False
-        if sig in self._compiled:
-            return True
-        with self._lock:
-            if sig in self._compiled:
-                return True
-            exe = self._acquire(args, sig)
-            if exe is None:
-                return False
-            self._compiled[sig] = exe
-            self._solo = exe if len(self._compiled) == 1 else None
         return True
-
-    @property
-    def aot_signatures(self) -> int:
-        """How many signatures currently dispatch through an AOT
-        executable (introspection for tests/diagnostics)."""
-        return len(self._compiled)
 
 
 def engine_jit(fn, *, static_argnums=(), donate_argnums=(),
                in_shardings=_UNSPECIFIED, out_shardings=_UNSPECIFIED,
                key_hint: Optional[str] = None) -> EngineJit:
-    """Build a compiled callable through the platform chokepoint —
-    the drop-in replacement for every ``jax.jit``/``pjit`` site in
-    ``analytics_zoo_tpu/`` (zoolint COMPILE011 enforces this).
-
-    Semantics match ``jax.jit(fn, static_argnums=..., donate_argnums=
-    ..., in_shardings=..., out_shardings=...)`` exactly; ``key_hint``
-    names the program in cache metadata and the
-    ``compile_cache_hits_total{fn=...}`` counters.
-    """
+    """``jax.jit(fn, static_argnums=..., donate_argnums=...,
+    in_shardings=..., out_shardings=...)`` through the platform's
+    chokepoint; ``key_hint`` names the program."""
     return EngineJit(fn, static_argnums=static_argnums,
                      donate_argnums=donate_argnums,
                      in_shardings=in_shardings,

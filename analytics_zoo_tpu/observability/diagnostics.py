@@ -10,19 +10,21 @@ and "what fraction of peak FLOPs are we getting?".
 Three pieces:
 
 * :class:`CompileMonitor` — wraps jitted functions, counts
-  compilations (new abstract signatures) and compile seconds per
-  function, detects recompilation *churn* after a configurable warmup
-  with a loud structured warning naming the offending signature, and
-  pulls ``jax.stages`` cost analysis (FLOPs / bytes accessed) into
-  gauges so the trainer can publish a live MFU estimate.
+  compilations (new entries in jit's own cache) and compile seconds
+  per function, detects recompilation *churn* after a configurable
+  warmup with a loud structured warning naming the offending
+  signature, and pulls ``jax.stages`` cost analysis (FLOPs / bytes
+  accessed) into gauges so the trainer can publish a live MFU
+  estimate.
 * :func:`step_attribution_histogram` — the shared
   ``train_step_time_seconds{component}`` family decomposing each
   wall-clock step into ``data_wait`` (host batch wait), and
   ``host_dispatch`` / ``device`` (dispatch wall vs the sampled
   dispatch→``block_until_ready`` bracket).
-* A ``jax.monitoring`` listener accumulating the runtime's own
+* ``jax.monitoring`` listeners accumulating the runtime's own
   ``backend_compile`` durations — the ground-truth compile clock that
-  first-call walls (which include the first execution) only bound.
+  first-call walls (which include the first execution) only bound —
+  and the persistent compilation cache's hits and misses.
 
 Everything here must degrade to "fewer gauges", never to an exception
 on a hot path.
@@ -67,9 +69,11 @@ def _short_signature(sig: Tuple, limit: int = 400) -> str:
 
 
 def abstract_signature(args: Tuple) -> Tuple:
-    """Shape/dtype key of a call's arguments — the same information a
-    jit cache keys on (minus shardings/static args, which the training
-    engine holds fixed).  Cheap: no device sync, no tracing."""
+    """Shapes and dtypes of a call's arguments, for the text of the
+    compile log and the churn warning (jit's cache also keys on
+    sharding, weak type and static values: this is not its key).  No
+    device sync, no tracing; a donated, deleted array still has
+    both."""
     leaves = []
     for a in _tree_leaves(args):
         if a is None:
@@ -113,9 +117,29 @@ def _backend_compile_listener(event: str, duration: float, **_kw) -> None:
         pass
 
 
+def _persistent_cache_listener(event: str, **_kw) -> None:
+    """jax.monitoring event listener: what JAX's persistent compilation
+    cache answered.  Never raises (it runs inside jax internals)."""
+    try:
+        if event == "/jax/compilation_cache/cache_hits":
+            get_registry().counter(
+                "compile_cache_hits_total",
+                "compiles answered by JAX's persistent compilation "
+                "cache (an executable read from disk)").inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            get_registry().counter(
+                "compile_cache_misses_total",
+                "compiles JAX's persistent compilation cache could "
+                "not answer and has written (full XLA compile paid; "
+                "one shorter than jax_persistent_cache_min_compile_"
+                "time_secs counts as neither)").inc()
+    except Exception:
+        pass
+
+
 def install_compile_listener() -> bool:
-    """Register the ``jax.monitoring`` compile-duration listener once
-    per process; returns whether the hook is active."""
+    """Register the ``jax.monitoring`` compile listeners once per
+    process; returns whether the hooks are active."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
@@ -124,6 +148,8 @@ def install_compile_listener() -> bool:
             import jax.monitoring
             jax.monitoring.register_event_duration_secs_listener(
                 _backend_compile_listener)
+            jax.monitoring.register_event_listener(
+                _persistent_cache_listener)
             _listener_installed = True
         except Exception:
             return False
@@ -132,57 +158,40 @@ def install_compile_listener() -> bool:
 
 # -------------------------------------------------------- CompileMonitor
 class _MonitoredJit:
-    """A jitted callable wrapped with per-signature compile tracking.
+    """A jitted callable wrapped with compile tracking.
 
-    Warmup/churn state lives on the WRAPPER (one per built program),
-    so a freshly built trainer starts a fresh warmup; the metrics it
-    feeds aggregate per function *name* in the shared registry.
-    Unknown attributes (``lower``, ``trace``, ...) forward to the
-    underlying jitted function, so AOT helpers like
-    ``benchmarks.compiled_flops`` keep working on the wrapped object.
+    A compile is a new entry in jit's own cache (``_cache_size``, the
+    one JAX-private name this module takes on): whatever jit keys on —
+    shape, dtype, sharding, weak type, a static value — is counted,
+    and a steady dispatch pays two integer reads.  Warmup/churn state
+    lives on the WRAPPER (one per built program), so a freshly built
+    trainer starts a fresh warmup; the metrics it feeds aggregate per
+    function *name* in the shared registry.  Unknown attributes
+    (``lower``, ``warm``, ...) forward to the underlying jitted
+    function.
     """
-
-    # after this many consecutive same-signature checks the wrapper is
-    # "stable" and only every CHECK_EVERY-th call pays the signature
-    # walk — per-step churn is still caught at the sampled calls, and
-    # the hot path stops paying a whole-pytree walk (params can be
-    # thousands of leaves) on every dispatch
-    STABLE_STREAK = 32
-    CHECK_EVERY = 8
 
     def __init__(self, monitor: "CompileMonitor", name: str, fn):
         self._monitor = monitor
         self._name = name
         self._fn = fn
-        self._signatures: set = set()
+        self._cache_size = fn._cache_size
         self._calls = 0
-        self._stable_streak = 0
 
     def __call__(self, *args):
-        mon, name = self._monitor, self._name
-        check = (self._stable_streak < self.STABLE_STREAK
-                 or self._calls % self.CHECK_EVERY == 0)
-        is_new = False
-        key = None
-        if check:
-            try:
-                key = abstract_signature(args)
-                is_new = key not in self._signatures
-            except Exception:
-                key, is_new = None, False
+        before = self._cache_size()
         t0 = time.perf_counter()
         out = self._fn(*args)
-        if is_new:
-            self._signatures.add(key)
-            self._stable_streak = 0
+        entries = self._cache_size()
+        if entries > before:
+            mon = self._monitor
             mon._record_compile(
-                name, key, time.perf_counter() - t0,
+                self._name, abstract_signature(args),
+                time.perf_counter() - t0,
                 calls_before=self._calls,
                 warmed_up=self._calls >= mon.warmup_calls,
-                n_signatures=len(self._signatures))
-            mon._maybe_cost_analysis(name, self._fn, args)
-        elif check:
-            self._stable_streak += 1
+                n_signatures=entries)
+            mon._maybe_cost_analysis(self._name, self._fn, args)
         self._calls += 1
         return out
 
@@ -194,12 +203,12 @@ class CompileMonitor:
     """Per-function compile accounting over the shared registry.
 
     ``wrap(name, jitted)`` returns a transparent callable; each call
-    whose abstract signature (arg shapes/dtypes) was not seen by that
-    wrapper counts as a compilation.  Signatures appearing after
-    ``warmup_calls`` calls are *recompilation churn* — the classic
-    silent TPU perf killer (a shape/dtype drifting per step recompiles
-    every step) — and emit one loud structured warning each, naming
-    the offending abstract signature.
+    that adds an entry to jit's cache counts as a compilation.
+    Entries appearing after ``warmup_calls`` calls are *recompilation
+    churn* — the classic silent TPU perf killer (a shape, dtype or
+    sharding drifting per step recompiles every step) — and emit one
+    loud structured warning each, naming the offending arguments'
+    shapes and dtypes.
 
     First-call wall time is recorded as ``jax_compile_seconds_total``
     (an upper bound: it includes the first execution); the
@@ -263,7 +272,7 @@ class CompileMonitor:
         reg.counter(
             "jax_compiles_total",
             "jit compilations observed per monitored function (new "
-            "abstract signatures)", labels=("fn",)).labels(name).inc()
+            "entries in jit's cache)", labels=("fn",)).labels(name).inc()
         reg.counter(
             "jax_compile_seconds_total",
             "first-call wall seconds per new signature (upper bound "
@@ -276,50 +285,14 @@ class CompileMonitor:
                 labels=("fn",)).labels(name).inc()
             log.warning(
                 "recompilation churn: %r compiled signature #%d on "
-                "call %d (after its %d-call warmup), %.2fs — a "
-                "shape/dtype is drifting between steps; offending "
-                "abstract signature: %s",
+                "call %d (after its %d-call warmup), %.2fs — a shape, "
+                "dtype or sharding is drifting between steps; the "
+                "offending call's shapes and dtypes: %s",
                 name, n_signatures, calls_before + 1,
                 self.warmup_calls, wall_s, _short_signature(key))
         else:
             log.info("compiled %r signature #%d in %.2fs (call %d)",
                      name, n_signatures, wall_s, calls_before + 1)
-
-    # ----------------------------------------------- executable cache
-    def record_cache_event(self, name: str, hit: bool,
-                           seconds: Optional[float] = None) -> None:
-        """Persistent-executable-cache accounting (fed by
-        ``compile.engine.EngineJit``): hits/misses per function plus
-        the deserialize wall on hits — the cold-vs-warm evidence
-        ``obs_report`` renders as the cache-effectiveness line.  A hit
-        replaces an XLA compile (141s for ResNet-50, BENCH_r05) with a
-        ~seconds load, so ``compile_cache_load_seconds`` vs
-        ``jax_compile_seconds_total`` IS the warm-start win."""
-        reg = self._reg()
-        with self._lock:
-            st = self._state(name)
-            st["cache_hits"] = st.get("cache_hits", 0) + (1 if hit else 0)
-            st["cache_misses"] = st.get("cache_misses", 0) + \
-                (0 if hit else 1)
-            if hit and seconds is not None:
-                st["cache_load_seconds"] = \
-                    st.get("cache_load_seconds", 0.0) + seconds
-        if hit:
-            reg.counter(
-                "compile_cache_hits_total",
-                "persistent executable-cache hits (deserialized "
-                "instead of compiled)", labels=("fn",)).labels(name).inc()
-            if seconds is not None:
-                reg.counter(
-                    "compile_cache_load_seconds",
-                    "seconds spent deserializing cached executables "
-                    "(the warm-start cost that replaces a full XLA "
-                    "compile)", labels=("fn",)).labels(name).inc(seconds)
-        else:
-            reg.counter(
-                "compile_cache_misses_total",
-                "persistent executable-cache misses (full XLA compile "
-                "paid)", labels=("fn",)).labels(name).inc()
 
     # ---------------------------------------------------- cost analysis
     def _maybe_cost_analysis(self, name: str, fn, args) -> None:
@@ -329,8 +302,8 @@ class CompileMonitor:
         second backend compile); falls back to compiling the lowered
         program (``jax.stages.Compiled.cost_analysis()``), which recent
         runtimes dedupe via the compilation cache.  Lowering uses
-        ShapeDtypeStructs built *before* the call, so donated/deleted
-        buffers are never touched."""
+        ShapeDtypeStructs of the arguments' shapes and dtypes, which a
+        donated, deleted buffer still has."""
         if not self.cost_analysis:
             return
         try:

@@ -40,9 +40,8 @@ kernels' path, and whether the suite is on at all:
   pre-suite code paths (the trainer runs the optax triple pass).
 
 Every call site sits INSIDE an ``engine_jit`` program (train step,
-predict step, bench workloads), so the suite inherits the AOT compile
-cache: serving replicas and repeat bench runs load the fused kernels
-warm (docs/aot-compile.md).
+predict step, bench workloads), so the kernels are compiled and
+cached with it (docs/aot-compile.md).
 """
 
 from __future__ import annotations

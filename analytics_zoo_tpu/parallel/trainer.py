@@ -590,14 +590,13 @@ class DistributedTrainer:
             self._train_step_at, params, opt_state, state, batch, rng,
             step, iteration=int(step))
 
-    # ----------------------------------------------------- AOT warm-start
+    # --------------------------------------------------------- warm-start
     def warm_start(self, params, opt_state, state, host_batch,
                    rng) -> bool:
-        """Pre-lower-and-compile (or cache-load) the per-step train
-        program BEFORE the first real batch arrives, so the compile —
-        or the ~seconds deserialize from a warm executable cache — is
-        paid at startup where it is attributable, not inside the first
-        training step.
+        """Compile the per-step train program BEFORE the first real
+        batch arrives, so the compile — or the read from JAX's
+        persistent compilation cache — is paid at startup where it is
+        attributable, not inside the first training step.
 
         ``params``/``opt_state``/``state`` are the live device trees
         (their shardings are part of the program signature);
@@ -605,9 +604,8 @@ class DistributedTrainer:
         device-placed exactly like a real step's batch (``put_batch``)
         so the warmed signature is bit-for-bit the one the training
         loop will dispatch.  Nothing is executed and nothing is
-        donated.  Returns whether an AOT executable is in place
-        (False = the plain jit path will compile lazily — never an
-        error)."""
+        donated.  Returns whether the program is compiled (False =
+        the first step compiles — never an error)."""
         try:
             if self._train_step_at is None:
                 self._train_step_at = self._build_train_step(

@@ -564,17 +564,15 @@ class Estimator:
                     save_snapshot()
                 return bool(end_trigger(ts))
 
-        # AOT warm-start (docs/aot-compile.md): pre-lower-and-compile
-        # the per-step train program — deserialized from the
-        # persistent executable cache when one is configured
-        # (ZOO_TPU_COMPILE_CACHE / compile.cache_dir / farm run-dir) —
-        # so the compile lands at startup, attributably, instead of
-        # inside the first dispatched step.  Per-step/pipeline paths
-        # only: the fused paths (hbm scan, chunked) build their
-        # programs through the same chokepoint and warm on first
-        # dispatch.  The peeked batch is NOT consumed: the pipeline
-        # position only commits per batch the DeviceLoader delivers,
-        # and epoch_batches is a fresh generator every epoch.
+        # Warm-start (docs/aot-compile.md): compile the per-step train
+        # program, or read it from JAX's persistent compilation cache,
+        # under its own span (aot_warm_start) before the first
+        # dispatched step, which then finds the executable in jit's
+        # cache.  Per-step/pipeline paths only: the fused paths (hbm
+        # scan, chunked) compile on first dispatch.  The peeked batch
+        # is NOT consumed: the pipeline position only commits per
+        # batch the DeviceLoader delivers, and epoch_batches is a
+        # fresh generator every epoch.
         if hbm_src is None and not use_chunks and \
                 getattr(train_set, "num_slices", 1) == 1:
             warm_batch = None

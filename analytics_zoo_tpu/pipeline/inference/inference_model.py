@@ -201,13 +201,13 @@ class InferenceModel:
     # ----------------------------------------------------------- warm-start
     def warm(self, input_shape, batch_size: int,
              dtype=np.float32) -> bool:
-        """AOT warm-start: pre-lower-and-compile (or deserialize from
-        the persistent executable cache) the predict program for
-        ``(batch_size,) + input_shape`` before the first request
-        arrives — a serving replica pays its cold-start at spawn,
+        """Compile the predict program for ``(batch_size,) +
+        input_shape`` before the first request arrives (JAX's
+        persistent compilation cache answers where it holds the
+        program) — a serving replica pays its cold-start at spawn,
         attributably, instead of inside the first client's request.
-        Never executes the model.  Returns whether an AOT executable
-        is ready (False = the first request compiles lazily)."""
+        Never executes the model.  Returns whether the program is
+        compiled (False = the first request compiles)."""
         if self._predict_fn is None:
             raise RuntimeError("no model loaded")
         warm = getattr(self._predict_fn, "warm", None)

@@ -226,10 +226,10 @@ class DecodeSlotPool:
 
     # ----------------------------------------------------------- warm start
     def warm(self) -> int:
-        """AOT warm-start every ``(batch_bucket, capacity)`` rung of
-        BOTH pool programs (step + prefill) — deserialized from the
-        persistent executable cache when one is configured.  After a
-        full warm, no fill level compiles.  Returns #programs
+        """Compile every ``(batch_bucket, capacity)`` rung of BOTH
+        pool programs (step + prefill) ahead of traffic — read from
+        JAX's persistent compilation cache where it holds them.  After
+        a full warm, no fill level compiles.  Returns #programs
         readied."""
         import jax.numpy as jnp
         warmed = 0
@@ -246,11 +246,6 @@ class DecodeSlotPool:
                 log.exception("decode warm-up failed for bucket %d",
                               b)
         return warmed
-
-    @property
-    def aot_signatures(self) -> int:
-        return (self._step.aot_signatures
-                + self._prefill.aot_signatures)
 
     # ------------------------------------------------------------ admission
     def admit(self, requests: List, now: Optional[float] = None

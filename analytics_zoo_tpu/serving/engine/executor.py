@@ -10,8 +10,8 @@ top-N softmax postprocess → complete each request.
 
 Buckets are the core of the latency story: instead of ONE padded
 shape (always ``batch_size``, PR 9), each endpoint keeps a small
-ladder of batch sizes, every rung AOT-warmed at model load (the PR 8
-``compile/`` cache makes that a deserialize, not a compile), so a
+ladder of batch sizes, every rung compiled at model load (a read
+from JAX's persistent compilation cache where it holds it), so a
 partial batch pays a partial predict — a lone request on a bucket-1
 program, not a 31/32-padding full batch.
 """

@@ -223,11 +223,9 @@ class ServingConfig:
         self.consumer_group = consumer_group
         self.consumer_name = consumer_name
         # per-record input shape (no batch dim), e.g. (224, 224, 3):
-        # when set, the worker AOT warm-starts the padded-batch predict
-        # program at startup — from the persistent executable cache
-        # when one is configured — instead of compiling inside the
-        # first client's request (config.yaml ``params.input_shape:
-        # 224,224,3``)
+        # when set, the worker compiles the padded-batch predict
+        # program at startup instead of inside the first client's
+        # request (config.yaml ``params.input_shape: 224,224,3``)
         if isinstance(input_shape, str):
             input_shape = tuple(
                 int(d) for d in input_shape.replace("x", ",").split(",")
@@ -506,15 +504,14 @@ class ClusterServing:
 
     # ----------------------------------------------------------- warm-start
     def warm_start(self) -> bool:
-        """AOT warm-start of EVERY endpoint's full bucket ladder (the
-        batcher pads in-flight batches to the nearest bucket, so each
-        rung is its own executable — warm them all and a post-warm-up
-        run never compiles, whatever the fill level).  With a
-        persistent executable cache configured
-        (``ZOO_TPU_COMPILE_CACHE`` / ``compile.cache_dir``), a replica
-        respawn deserializes in seconds instead of recompiling — the
-        serving half of the 141s-cold-start fix.  No-op for endpoints
-        without an ``input_shape``."""
+        """Compile EVERY endpoint's full bucket ladder ahead of
+        traffic (the batcher pads in-flight batches to the nearest
+        bucket, so each rung is its own executable — warm them all and
+        a post-warm-up run never compiles, whatever the fill level).
+        A replica respawn reads the executables from JAX's persistent
+        compilation cache (``JAX_COMPILATION_CACHE_DIR``, or
+        ``<checkout>/.jax_cache``) instead of recompiling.  No-op for
+        endpoints without an ``input_shape``."""
         t0 = time.perf_counter()
         warmed = self.engine.warm_start()
         total = sum(warmed.values())
@@ -1351,7 +1348,7 @@ class ClusterServing:
         started = time.time()
         self._serve_start = self._serve_start or time.perf_counter()
         # publish /healthz BEFORE the warm start: a cold compile can
-        # run minutes (the 141s north star), far past any supervisor
+        # run a minute or more, far past any supervisor
         # startup grace — the port must be discoverable and answering
         # (503 warming_up = alive, deliberately not-ready) while the
         # predict program compiles, or every cold-cache replica would

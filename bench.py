@@ -1402,9 +1402,9 @@ def bench_kernels(update_iters: int = 30, predict_rows: int = 65536,
         unfused_s = timed(unfused_prog, grads, opt_state, params,
                           iters=update_iters)
         _f, f_bytes = cost_of_compiled(
-            fused_prog.aot(grads, opt_state, params))
+            fused_prog.lower(grads, opt_state, params).compile())
         _u, u_bytes = cost_of_compiled(
-            unfused_prog.aot(grads, opt_state, params))
+            unfused_prog.lower(grads, opt_state, params).compile())
         bytes_saved = (u_bytes - f_bytes) if (f_bytes and u_bytes) \
             else None
         opt_roofline = None
@@ -1452,8 +1452,8 @@ def bench_kernels(update_iters: int = 30, predict_rows: int = 65536,
         "layernorm_gelu_us": round(
             timed(ln_fused, x, gamma, beta, iters=50) * 1e6, 1),
     }
-    bg_bytes = cost_of_compiled(bg_fused.aot(x, b))[1]
-    bgu_bytes = cost_of_compiled(bg_unf.aot(x, b))[1]
+    bg_bytes = cost_of_compiled(bg_fused.lower(x, b).compile())[1]
+    bgu_bytes = cost_of_compiled(bg_unf.lower(x, b).compile())[1]
     if bg_bytes and bgu_bytes:
         g_saved.labels("bias_gelu").set(float(bgu_bytes - bg_bytes))
         epi_section["bytes_saved_per_step"] = bgu_bytes - bg_bytes
@@ -1623,22 +1623,16 @@ def _derive_health_fields(snapshot):
             out["compiles_total"] = int(compiles)
         if recompiles:
             out["recompiles_after_warmup"] = int(recompiles)
-        # executable-cache provenance: did this run's programs compile
-        # cold or deserialize from a warm ZOO_TPU_COMPILE_CACHE dir?
-        # Round-over-round bench runs with --compile-cache DIR prove
-        # the 141s→warm drop by this field flipping cold→warm while
-        # load_seconds stays ~seconds.
+        # did JAX's persistent compilation cache answer this run's
+        # compiles (JAX_COMPILATION_CACHE_DIR, or <checkout>/.jax_cache)?
         hits = sum(v for k, v in counters.items()
                    if k.startswith("compile_cache_hits_total"))
         misses = sum(v for k, v in counters.items()
                      if k.startswith("compile_cache_misses_total"))
         if hits or misses:
-            load_s = sum(v for k, v in counters.items()
-                         if k.startswith("compile_cache_load_seconds"))
             out["compile_cache"] = {
                 "provenance": "warm" if hits else "cold",
                 "hits": int(hits), "misses": int(misses),
-                "warm_load_seconds": round(load_s, 3),
             }
         # communication pressure: the sharding-implied collective
         # traffic per step (observability/collectives.py) — a headline
@@ -1868,7 +1862,7 @@ def _compare_against_baseline(baseline_path, threshold=0.10):
     except Exception:  # noqa: BLE001
         pass
     # compile-time changes are INFORMATIONAL, never a regression: a
-    # cold→warm flip (a populated --compile-cache dir) legitimately
+    # cold→warm flip (a populated compilation cache) legitimately
     # collapses compile_time_s by orders of magnitude, and a warm→cold
     # flip (fresh cache) legitimately restores it — neither says
     # anything about throughput
@@ -1963,15 +1957,6 @@ def main(argv=None):
     # drop in any shared metric
     ap.add_argument("--compare", metavar="BASELINE.json", default=None)
     ap.add_argument("--compare-threshold", type=float, default=0.10)
-    # persistent executable cache: exported to every workload child as
-    # ZOO_TPU_COMPILE_CACHE, so round-over-round bench runs against the
-    # SAME dir prove the cold→warm compile drop (the first round pays
-    # the compiles and persists; later rounds deserialize in seconds —
-    # bench_metrics.json records compile_cache.provenance per workload)
-    ap.add_argument("--compile-cache", metavar="DIR", default=None,
-                    help="persistent executable-cache directory for "
-                         "all workloads (sets ZOO_TPU_COMPILE_CACHE "
-                         "in each child)")
     # a backend other jobs share can be busy for MINUTES at a time
     # (rounds 1 and 3) — the probe is deadline-based: keep probing with
     # exponential backoff until --probe-budget seconds are spent.  The
@@ -2001,11 +1986,6 @@ def main(argv=None):
                          "of best-value merging into it (use after a "
                          "config change that legitimately lowers values)")
     args = ap.parse_args(argv)
-    if args.compile_cache:
-        # inherited by every --child subprocess (and honored by this
-        # process if a workload ever runs in-process)
-        os.environ["ZOO_TPU_COMPILE_CACHE"] = \
-            os.path.abspath(args.compile_cache)
     if args.fresh_artifact:
         try:
             os.remove(ARTIFACT_PATH)

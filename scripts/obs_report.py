@@ -215,27 +215,14 @@ def render_report(label: str, snap: Dict,
             f"backend compile: "
             f"{int(counters.get('jax_backend_compiles_total', 0))} "
             f"XLA compilations, {backend_s:.2f}s total")
-    # ---- executable-cache effectiveness (docs/aot-compile.md) ------
-    hits = sum(v for _l, v in
-               _labeled(counters, "compile_cache_hits_total"))
-    misses = sum(v for _l, v in
-                 _labeled(counters, "compile_cache_misses_total"))
+    # ---- JAX's persistent compilation cache (docs/aot-compile.md) --
+    hits = counters.get("compile_cache_hits_total", 0)
+    misses = counters.get("compile_cache_misses_total", 0)
     if hits or misses:
-        load_s = sum(v for _l, v in
-                     _labeled(counters, "compile_cache_load_seconds"))
-        cold_s = sum(v for _l, v in
-                     _labeled(counters, "jax_compile_seconds_total"))
         rate = 100.0 * hits / (hits + misses)
         lines.append(
             f"executable cache: {int(hits)} hit(s) / {int(misses)} "
-            f"miss(es) ({rate:.0f}% hit rate) — warm loads "
-            f"{load_s:.2f}s vs {cold_s:.2f}s cold first-call compile")
-        errors = _labeled(counters, "compile_cache_errors_total")
-        evict = counters.get("compile_cache_evictions_total")
-        for lab, n in errors:
-            lines.append(f"  cache entries rejected [{lab}]: {int(n)}")
-        if evict:
-            lines.append(f"  cache entries LRU-evicted: {int(evict)}")
+            f"miss(es) ({rate:.0f}% hit rate)")
 
     # ---- fused kernel suite / roofline (docs/perf-tuning.md) -------
     builds = _labeled(counters, "fused_kernel_builds_total")

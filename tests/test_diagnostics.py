@@ -64,19 +64,87 @@ class TestCompileMonitor:
         lowered = fn.lower(jnp.ones((4, 4)))
         assert lowered.compile() is not None
 
-    def test_churn_still_detected_after_stable_amortization(self):
-        # past STABLE_STREAK the wrapper only samples the signature
-        # walk every CHECK_EVERY calls — a drifting shape must still
-        # be flagged within one sampling period
+    def test_churn_detected_at_the_first_drifting_call(self):
+        # however long the steady run before it: nothing is sampled
         mon = CompileMonitor(warmup_calls=2, registry=MetricsRegistry())
         fn = mon.wrap("stable", jax.jit(lambda a: a.sum()))
         for _ in range(50):
             fn(jnp.ones((4,)))
-        from analytics_zoo_tpu.observability.diagnostics import (
-            _MonitoredJit)
-        for _ in range(_MonitoredJit.CHECK_EVERY):
-            fn(jnp.ones((8,)))
-        assert mon.stats("stable")["recompiles_after_warmup"] >= 1
+        fn(jnp.ones((8,)))
+        assert mon.stats("stable")["recompiles_after_warmup"] == 1
+
+    def test_steady_calls_compute_no_signature(self, monkeypatch):
+        """N same-signature calls: ONE compile, and no dispatch after
+        it walks the arguments (the signature is the warning's text,
+        built only when jit's cache has grown)."""
+        from analytics_zoo_tpu.observability import diagnostics
+        mon = CompileMonitor(warmup_calls=2, registry=MetricsRegistry())
+        fn = mon.wrap("steady", jax.jit(
+            lambda p, x: jax.tree_util.tree_map(
+                lambda a: a + x.sum(), p)))
+        p = {"w": jnp.ones((4,)), "b": jnp.ones((2,))}
+        p = fn(p, jnp.ones((8,)))
+
+        walks = []
+
+        def walked(args):
+            walks.append(args)
+            raise AssertionError("a steady dispatch walked its "
+                                 "arguments")
+        monkeypatch.setattr(diagnostics, "abstract_signature", walked)
+        for _ in range(40):
+            p = fn(p, jnp.ones((8,)))
+        assert walks == []
+        assert mon.stats("steady")["compiles"] == 1
+        assert mon.stats("steady")["recompiles_after_warmup"] == 0
+
+    def test_sharding_only_recompile_is_counted(self):
+        """Unchanged shapes and dtypes under a changed input sharding
+        compile a new program (ROADMAP D12: a 45 s ResNet-50 recompile
+        went uncounted): it counts, and after the warm-up it is
+        churn."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        reg = MetricsRegistry()
+        mon = CompileMonitor(warmup_calls=2, registry=reg)
+        fn = mon.wrap("resharded", jax.jit(lambda a: a * 2))
+        mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
+        x = np.arange(16, dtype=np.float32)
+        split = jax.device_put(x, NamedSharding(mesh, P("data")))
+        whole = jax.device_put(x, NamedSharding(mesh, P()))
+        for _ in range(3):
+            fn(split)
+        assert mon.stats("resharded")["compiles"] == 1
+        out = fn(whole)
+        assert np.asarray(out).tolist() == (x * 2).tolist()
+        st = mon.stats("resharded")
+        assert st["compiles"] == 2
+        assert st["recompiles_after_warmup"] == 1
+        text = reg.prometheus_text()
+        assert 'jax_compiles_total{fn="resharded"} 2' in text
+        assert 'jax_recompiles_total{fn="resharded"} 1' in text
+
+    def test_churn_warning_reads_donated_buffers(self, caplog):
+        """The warning's text is built AFTER the call, from arguments
+        the call has donated: a deleted array still has its shape and
+        dtype."""
+        import logging
+        mon = CompileMonitor(warmup_calls=1, registry=MetricsRegistry())
+        fn = mon.wrap("donating", jax.jit(
+            lambda p, x: jax.tree_util.tree_map(
+                lambda a: a + x.sum(), p), donate_argnums=(0,)))
+        p = fn({"w": jnp.ones((4,))}, jnp.ones((8,)))
+        gone = p
+        with caplog.at_level(logging.WARNING,
+                             logger="analytics_zoo_tpu.observability"):
+            p = fn(gone, jnp.ones((16,)))     # churn: x drifted
+        assert gone["w"].is_deleted()
+        assert np.asarray(p["w"]).tolist() == [25.0] * 4
+        assert mon.stats("donating")["recompiles_after_warmup"] == 1
+        churn = [r.getMessage() for r in caplog.records
+                 if "recompilation churn" in r.getMessage()]
+        assert len(churn) == 1
+        assert "((4,), 'float32')" in churn[0]
+        assert "((16,), 'float32')" in churn[0]
 
     def test_fresh_wrapper_restarts_warmup(self):
         # churn state is per built program: a rebuilt trainer must not

@@ -237,17 +237,16 @@ class TestDecodeSlotPool:
 
     def test_zero_recompiles_across_all_fill_levels(self):
         """After ``warm()`` every (batch_bucket, state_bucket) rung of
-        the step AND prefill programs is AOT-resident: traffic at
-        every occupancy records zero backend compiles and mints zero
-        new AOT signatures."""
+        the step AND prefill programs is compiled: traffic at every
+        occupancy, so a request in every bucket, fires no backend
+        compile."""
         from analytics_zoo_tpu.observability.diagnostics import (
             get_compile_monitor)
         get_compile_monitor()     # backend-compile listener active
         eng, ep = _gen_engine(slots=4)
         try:
             # ladder (1, 2, 4) x (step, prefill) = 6 programs
-            assert ep.warm() in (0, 6)      # 0 if already AOT-resident
-            assert ep.pool.aot_signatures == 6
+            assert ep.warm() == 6
             compiles = get_registry().counter(
                 "jax_backend_compiles_total",
                 "XLA backend compilations (jax.monitoring)")
@@ -259,7 +258,6 @@ class TestDecodeSlotPool:
                 eng.submit_wait(reqs, timeout_s=60)
                 assert all(r.error is None for r in reqs)
             assert compiles.value == before
-            assert ep.pool.aot_signatures == 6
         finally:
             eng.stop()
 
@@ -560,17 +558,20 @@ class TestDecodeCacheWarmStart:
 
     def test_second_process_warm_loads_decode_step(self, tmp_path):
         """ISSUE 12 acceptance: the decode-step executables round-trip
-        the persistent cache — a second process warm-loads (>=1 hit),
+        JAX's persistent compilation cache — a second process reads
+        them (the six of the ladder at least, and misses nothing),
         records zero post-warm backend compiles at any fill level, and
         emits identical tokens."""
         cache_dir = str(tmp_path / "gen-cache")
         cold = self._run(cache_dir)
+        assert cold["warmed_programs"] == 6
         assert cold["cache_hits"] == 0
-        assert cold["cache_misses"] >= 1
-        assert cold["cache_writes"] >= 1
+        assert cold["cache_misses"] >= 6
+        assert len(os.listdir(cache_dir)) >= 6
         assert cold["post_warm_compiles"] == 0
         warm = self._run(cache_dir)
-        assert warm["cache_hits"] >= 1
-        assert warm["cache_errors"] == 0
+        assert warm["warmed_programs"] == 6
+        assert warm["cache_hits"] == cold["cache_misses"]
+        assert warm["cache_misses"] == 0
         assert warm["post_warm_compiles"] == 0
         assert warm["tokens_digest"] == cold["tokens_digest"]

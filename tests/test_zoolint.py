@@ -2165,7 +2165,7 @@ class TestDONATE012:
         assert [f.rule for f in out] == ["DONATE012"]
         assert "'self._tokens'" in out[0].message
 
-    def test_warm_and_aot_are_exempt(self):
+    def test_warm_is_exempt(self):
         src = (
             "from analytics_zoo_tpu.compile import engine_jit\n"
             "class P:\n"
@@ -2173,7 +2173,6 @@ class TestDONATE012:
             "        self._step = engine_jit(fn, donate_argnums=(0,))\n"
             "    def warm(self, state, ids):\n"
             "        self._step.warm(state, ids)\n"
-            "        self._step.aot(state, ids)\n"
             "        return state.shape\n")
         assert lint(src, rules=["DONATE012"]) == []
 
