@@ -9,23 +9,37 @@ attention.  Pairs with parallel/ring_attention.py (across-chip SP):
 ring handles the inter-chip blocks, this kernel is what each chip
 should run on its local block.
 
-The public ``flash_attention`` is differentiable: a ``custom_vjp``
-routes the backward through two Pallas kernels (the standard
-flash-attention backward — recompute the probability blocks from the
-forward's saved log-sum-exp, then ``dv = PᵀdO``, ``ds = P∘(dOVᵀ - D)``,
-``dq = dsK``, ``dk = dsᵀQ``), so the same memory bound holds in
-training.
+``flash_attention_token_major`` (and ``flash_attention``, the same for
+callers that hold head-major arrays) is differentiable: a
+``custom_vjp`` routes the backward through two Pallas kernels (the
+standard flash-attention backward — recompute the probability blocks
+from the forward's saved log-sum-exp, then ``dv = PᵀdO``,
+``ds = P∘(dOVᵀ - D)``, ``dq = dsK``, ``dk = dsᵀQ``), so the same memory
+bound holds in training.
 
-Grid: every operand enters through the grid, one (block_q, d) or
-(block_k, d) tile at a time, so no sequence length is capped by VMEM.
-The second grid axis walks a STATIC list of (q tile, k tile) pairs,
-worked out on the host from the mask's description and handed to the
-kernels as scalar-prefetch tables: a tile with no allowed pair is not
-in the list, so it costs neither a grid step nor a DMA, in the forward,
-dq and dkv kernels alike; a tile every pair of which is allowed skips
-the mask's arithmetic.  The mask itself is evaluated from iotas inside
-the kernel (``_key_interval``: each query row may read one interval of
-key positions in a key tile), never materialised.
+Layout: the operands lie where a projection wrote them, (B, T, H·D),
+and a head is a BLOCK OF THE LAST DIMENSION, picked by the index map:
+no head axis is moved in front of the positions, on the way in or out.
+A head that is a lane multiple is one block; heads narrower than the
+128 lanes share a block, ``128 // D`` of them (``_heads_per_tile``),
+and one grid step forms each head's logits and products from the shared
+tile (every lane but the head's zeroed on one operand of each product,
+so the other heads' lanes add exact zeros), with its own ``m``, ``l``,
+``lse`` and ``delta``; the tile's output is written once, lane-dense.
+q, k and v may be ONE array, the fused projection's result: the three
+are then block offsets into it.
+
+Grid: (batch, lane tiles of heads, pairs).  Every operand enters
+through the grid, one (block_q, width) or (block_k, width) tile at a
+time, so no sequence length is capped by VMEM.  The pairs axis walks a
+STATIC list of (q tile, k tile) pairs, worked out on the host from the
+mask's description and handed to the kernels as scalar-prefetch tables:
+a tile with no allowed pair is not in the list, so it costs neither a
+grid step nor a DMA, in the forward, dq and dkv kernels alike; a tile
+every pair of which is allowed skips the mask's arithmetic.  The mask
+itself is evaluated from iotas inside the kernel (``_key_interval``:
+each query row may read one interval of key positions in a key tile),
+never materialised.
 
 Grouped-query attention: ``k``/``v`` may have fewer heads than ``q``;
 query head ``h`` reads K/V head ``h // group`` through the index map,
@@ -44,6 +58,8 @@ import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from analytics_zoo_tpu.compile.engine import engine_jit
 
 NEG = -1e30
 # flags of one (q tile, k tile) pair in a kernel's walk
@@ -188,12 +204,56 @@ def _row_to_col(row):
     return jnp.broadcast_to(row, (_LANES, n)).T[:, 0:1]
 
 
+def _heads_per_tile(h: int, h_kv: int, d: int) -> int:
+    """How many heads one lane tile of the token-major operands holds:
+    1 where a head is a lane multiple; ``128 // d`` consecutive heads
+    where a narrower head divides the 128 lanes, every query head has
+    its own K/V head and the tiles come out whole; 0 where neither
+    holds (no lane-aligned block picks such a head: Mosaic refuses it,
+    and interpret mode runs it at one head a block)."""
+    if d % _LANES == 0:
+        return 1
+    per = _LANES // d
+    if _LANES % d == 0 and h_kv == h and h % per == 0:
+        return per
+    return 0
+
+
+def _head_lanes(j: int, per: int, *xs):
+    """The operands ``xs``, each (rows, per x d), with the lanes of
+    every head but the tile's ``j``-th zeroed: one operand of each
+    product is cut so, and the other heads' lanes add exact zeros to
+    it."""
+    if per == 1:
+        return xs
+    d = xs[0].shape[1] // per
+    lane = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
+    own = (lane >= j * d) & (lane < (j + 1) * d)
+    return tuple(jnp.where(own, x, jnp.zeros_like(x)) for x in xs)
+
+
+def _spread(cols, width: int):
+    """Per-head (n, 1) columns -> (n, width), head ``j``'s lanes holding
+    ``cols[j]`` (one head: the column itself, which broadcasts)."""
+    out = cols[0]
+    if len(cols) > 1:
+        d = width // len(cols)
+        out = jnp.broadcast_to(out, (out.shape[0], width))
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for j in range(1, len(cols)):
+            out = jnp.where(lane >= j * d, cols[j], out)
+    return out
+
+
 def _flash_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
                   lse_ref, acc_ref, m_ref, l_ref, *, mask, mask_all: bool,
                   scale: float, block_q: int, block_k: int, half: int):
-    """One (q tile, k tile) pair of the forward's online softmax."""
-    p_id = pl.program_id(1)
+    """One (q tile, k tile) pair of the forward's online softmax, for
+    the heads of one lane tile: each head its own logits, ``m`` and
+    ``l``; the tile's accumulator is updated and written whole."""
+    p_id = pl.program_id(2)
     flags = fl_ref[p_id]
+    per, width = m_ref.shape[0], acc_ref.shape[1]
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
@@ -201,46 +261,62 @@ def _flash_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...] * scale                     # (bq, d), input dtype
+    q = q_ref[...] * scale                     # (bq, width), input dtype
     k_blk, v_blk = k_ref[...], v_ref[...]
-    s = jax.lax.dot_general(q, k_blk, _NT,
-                            preferred_element_type=jnp.float32)
     k_start = ki_ref[p_id] * block_k
-    s = _masked(s, mask, mask_all, flags,
-                _positions(qi_ref[p_id] * block_q, block_q, 0),
-                _positions(k_start, block_k, 1), k_start >= half)
-    m = m_ref[...]
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    corr = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    q_pos = _positions(qi_ref[p_id] * block_q, block_q, 0)
+    k_pos = _positions(k_start, block_k, 1)
+    corr, pv = [], None
+    for j in range(per):
+        k_j, v_j = _head_lanes(j, per, k_blk, v_blk)
+        s = jax.lax.dot_general(q, k_j, _NT,
+                                preferred_element_type=jnp.float32)
+        s = _masked(s, mask, mask_all, flags, q_pos, k_pos, k_start >= half)
+        m = m_ref[j]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr.append(jnp.exp(m - m_new))
+        p = jnp.exp(s - m_new)
+        l_ref[j] = l_ref[j] * corr[j] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[j] = m_new
+        pv_j = jnp.dot(p.astype(v_blk.dtype), v_j,
+                       preferred_element_type=jnp.float32)
+        pv = pv_j if pv is None else pv + pv_j
+    acc_ref[...] = acc_ref[...] * _spread(corr, width) + pv
 
     @pl.when((flags & _LAST) != 0)
     def _store():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        # lse leaves as a lane-dense (1, block_q) row: a (t, 1) float32
+        l_safe = [jnp.maximum(l_ref[j], 1e-30) for j in range(per)]
+        o_ref[...] = (acc_ref[...] / _spread(l_safe, width)
+                      ).astype(o_ref.dtype)
+        # lse leaves as lane-dense (1, block_q) rows: a (t, 1) float32
         # array is tiled to 128 lanes in HBM, 128 times its size
-        lse_ref[...] = _col_to_row(m_ref[...] + jnp.log(l_safe))
+        for j in range(per):
+            lse_ref[j] = _col_to_row(m_ref[j] + jnp.log(l_safe[j]))
 
 
 def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
-                     lse_ref, delta_ref, dq_ref, acc_ref, lse_col, delta_col,
-                     *, mask, mask_all: bool, scale: float, block_q: int,
-                     block_k: int, half: int):
-    """dq for one q tile: walk its k tiles, recompute P from lse.  lse
-    and delta enter as rows and are turned into columns once a tile."""
-    p_id = pl.program_id(1)
+                     o_ref, lse_ref, dq_ref, delta_ref, acc_ref, lse_col,
+                     delta_col, *, mask, mask_all: bool, scale: float,
+                     block_q: int, block_k: int, half: int):
+    """dq for one q tile of one lane tile's heads: walk its k tiles,
+    recompute each head's P from its lse.  On the tile's first pair it
+    forms D_i = rowsum(dO_i ∘ O_i) over each head's lanes, keeps it as a
+    column beside lse's (which enters as a row) and writes it as a
+    lane-dense row for the dkv kernel."""
+    p_id = pl.program_id(2)
     flags = fl_ref[p_id]
+    per = lse_col.shape[0]
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        lse_col[...] = _row_to_col(lse_ref[...])
-        delta_col[...] = _row_to_col(delta_ref[...])
+        do_o = (do_ref[...].astype(jnp.float32)
+                * o_ref[...].astype(jnp.float32))
+        for j in range(per):
+            lse_col[j] = _row_to_col(lse_ref[j])
+            delta_col[j] = jnp.sum(*_head_lanes(j, per, do_o), axis=1,
+                                   keepdims=True)
+            delta_ref[j] = _col_to_row(delta_col[j])
 
     # recompute logits EXACTLY as the forward did (same dtype for the
     # q*scale product), so exp(s - lse) reproduces the forward's P —
@@ -248,18 +324,23 @@ def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     # lse under bf16
     q = q_ref[...] * scale
     k_blk, v_blk, do = k_ref[...], v_ref[...], do_ref[...]
-    s = jax.lax.dot_general(q, k_blk, _NT,
-                            preferred_element_type=jnp.float32)
     k_start = ki_ref[p_id] * block_k
-    s = _masked(s, mask, mask_all, flags,
-                _positions(qi_ref[p_id] * block_q, block_q, 0),
-                _positions(k_start, block_k, 1), k_start >= half)
-    p = jnp.exp(s - lse_col[...])                       # (bq, bk)
-    dp = jax.lax.dot_general(do, v_blk, _NT,
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_col[...])
-    acc_ref[...] += jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                            preferred_element_type=jnp.float32)
+    q_pos = _positions(qi_ref[p_id] * block_q, block_q, 0)
+    k_pos = _positions(k_start, block_k, 1)
+    dq = None
+    for j in range(per):
+        k_j, v_j = _head_lanes(j, per, k_blk, v_blk)
+        s = jax.lax.dot_general(q, k_j, _NT,
+                                preferred_element_type=jnp.float32)
+        s = _masked(s, mask, mask_all, flags, q_pos, k_pos, k_start >= half)
+        p = jnp.exp(s - lse_col[j])                         # (bq, bk)
+        dp = jax.lax.dot_general(do, v_j, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_col[j])
+        dq_j = jnp.dot(ds.astype(k_blk.dtype), k_j,
+                       preferred_element_type=jnp.float32)
+        dq = dq_j if dq is None else dq + dq_j
+    acc_ref[...] += dq
 
     @pl.when((flags & _LAST) != 0)
     def _store():
@@ -270,14 +351,15 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       mask, mask_all: bool, scale: float, block_q: int,
                       block_k: int, half: int):
-    """dk/dv for one K/V head's k tile: the grid walks the tile's q
-    tiles and, innermost, the query heads that share the K/V head,
-    accumulating into VMEM scratch (TPU pallas runs the grid in order
-    on a core) and writing the tile once.  The logits are held
+    """dk/dv for one k tile of one lane tile's K/V heads: the grid walks
+    the tile's q tiles and, innermost, the query heads that share the
+    K/V head, accumulating into VMEM scratch (TPU pallas runs the grid
+    in order on a core) and writing the tile once.  The logits are held
     TRANSPOSED, (block_k, block_q), so lse and delta enter as lane-dense
     rows and every product is a plain or an ``a @ b.T`` one."""
-    p_id, g = pl.program_id(1), pl.program_id(2)
+    p_id, g = pl.program_id(2), pl.program_id(3)
     flags = fl_ref[p_id]
+    per = lse_ref.shape[0]
 
     @pl.when(((flags & _FIRST) != 0) & (g == 0))
     def _init():
@@ -287,24 +369,31 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     k_blk, v_blk, do = k_ref[...], v_ref[...], do_ref[...]
     # same-dtype q*scale as the forward (see dq kernel note)
     q = q_ref[...] * scale
-    s_t = jax.lax.dot_general(k_blk, q, _NT,
-                              preferred_element_type=jnp.float32)
     k_start = ki_ref[p_id] * block_k
-    s_t = _masked(s_t, mask, mask_all, flags,
-                  _positions(qi_ref[p_id] * block_q, block_q, 1),
-                  _positions(k_start, block_k, 0), k_start >= half)
-    p_t = jnp.exp(s_t - lse_ref[...])                   # (bk, bq)
-    dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
-                           preferred_element_type=jnp.float32)
-    dp_t = jax.lax.dot_general(v_blk, do, _NT,
-                               preferred_element_type=jnp.float32)
-    ds_t = p_t * (dp_t - delta_ref[...])
-    # dk = Σ ds_ijᵀ (scale·q_i): q enters pre-scaled, so the scale is
-    # already in the accumulation
-    dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
-                           preferred_element_type=jnp.float32)
+    q_pos = _positions(qi_ref[p_id] * block_q, block_q, 1)
+    k_pos = _positions(k_start, block_k, 0)
+    dk = dv = None
+    for j in range(per):
+        q_j, do_j = _head_lanes(j, per, q, do)
+        s_t = jax.lax.dot_general(k_blk, q_j, _NT,
+                                  preferred_element_type=jnp.float32)
+        s_t = _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
+                      k_start >= half)
+        p_t = jnp.exp(s_t - lse_ref[j])                     # (bk, bq)
+        dv_j = jnp.dot(p_t.astype(do.dtype), do_j,
+                       preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v_blk, do_j, _NT,
+                                   preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta_ref[j])
+        # dk = Σ ds_ijᵀ (scale·q_i): q enters pre-scaled, so the scale
+        # is already in the accumulation
+        dk_j = jnp.dot(ds_t.astype(q.dtype), q_j,
+                       preferred_element_type=jnp.float32)
+        dk, dv = (dk_j, dv_j) if dk is None else (dk + dk_j, dv + dv_j)
+    dk_acc[...] += dk
+    dv_acc[...] += dv
 
-    @pl.when(((flags & _LAST) != 0) & (g == pl.num_programs(2) - 1))
+    @pl.when(((flags & _LAST) != 0) & (g == pl.num_programs(3) - 1))
     def _store():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -338,151 +427,261 @@ def _compiler_params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
-def _by_q_specs(block_q: int, block_k: int, d: int, group: int):
-    """Block specs of a walk by q tile (grid: heads, pairs): a q-sized
-    tile, a K/V tile of the head's K/V head, a lane-dense row."""
-    return (pl.BlockSpec((None, block_q, d),
-                         lambda i, p, qi, ki, fl: (i, qi[p], 0)),
-            pl.BlockSpec((None, block_k, d),
-                         lambda i, p, qi, ki, fl: (i // group, ki[p], 0)),
-            pl.BlockSpec((None, 1, block_q),
-                         lambda i, p, qi, ki, fl: (i, 0, qi[p])))
+class _Heads(NamedTuple):
+    """How the kernels find a head in the token-major operands: ``h``
+    query heads on ``h_kv`` K/V heads of ``d``, ``per`` of them to a
+    block of ``width`` lanes, and where q, k and v start in the last
+    dimension of their operand, in such blocks (all 0 unless the three
+    are ONE array, the fused projection's result)."""
+    h: int
+    h_kv: int
+    d: int
+    per: int
+    width: int
+    offsets: tuple
+
+    @property
+    def group(self):
+        return self.h // self.h_kv
+
+    @property
+    def tiles(self):
+        return self.h // self.per
 
 
-def _flash_fwd_impl(q, k, v, cfg):
-    mask, scale, block_q, block_k, interpret = cfg
-    b, h, t, d = q.shape
-    group = h // k.shape[1]
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(-1, t, d)
-    vf = v.reshape(-1, t, d)
+def _heads(ops, h: int, h_kv: int) -> _Heads:
+    fused = len(ops) == 1
+    d = ops[0].shape[-1] // (h + 2 * h_kv if fused else h)
+    per = _heads_per_tile(h, h_kv, d) or 1
+    offsets = (0, h // per, (h + h_kv) // per) if fused else (0, 0, 0)
+    return _Heads(h, h_kv, d, per, per * d, offsets)
+
+
+def _statics(cfg, t: int):
+    mask, scale, block_q, block_k = cfg[:4]
+    return dict(mask=mask, scale=scale, block_q=block_q, block_k=block_k,
+                half=_half(mask, t),
+                mask_all=_mostly_partial(mask, t, block_q, block_k))
+
+
+def _by_q_specs(block_q: int, block_k: int, hd: _Heads):
+    """Block specs of a walk by q tile (grid: batch, lane tiles, pairs):
+    a q-sized tile of the heads' lanes, a K/V tile of their K/V head's,
+    the heads' lane-dense rows."""
+    def q_tile(off=0):
+        return pl.BlockSpec(
+            (None, block_q, hd.width),
+            lambda b, i, p, qi, ki, fl: (b, qi[p], off + i))
+
+    def kv_tile(off):
+        return pl.BlockSpec(
+            (None, block_k, hd.width),
+            lambda b, i, p, qi, ki, fl: (b, ki[p], off + i // hd.group))
+
+    rows = pl.BlockSpec(
+        (hd.per, 1, block_q),
+        lambda b, i, p, qi, ki, fl: (b * hd.tiles + i, 0, qi[p]))
+    return q_tile, kv_tile, rows
+
+
+def _flash_fwd_impl(ops, cfg):
+    mask, scale, block_q, block_k, interpret, h, h_kv = cfg
+    hd = _heads(ops, h, h_kv)
+    q, k, v = ops if len(ops) == 3 else ops * 3
+    b, t = q.shape[:2]
     by_q, _ = _tile_pairs(mask, t, block_q, block_k)
-    kernel = functools.partial(
-        _flash_kernel, mask=mask, scale=scale, block_q=block_q,
-        block_k=block_k, half=_half(mask, t),
-        mask_all=_mostly_partial(mask, t, block_q, block_k))
-    q_spec, kv_spec, row_spec = _by_q_specs(block_q, block_k, d, group)
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+    q_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
+    q_off, k_off, v_off = hd.offsets
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, **_statics(cfg, t)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, h * hd.d), q.dtype),
                    jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b * h, len(by_q[0])),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=(q_spec, row_spec),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32)]),
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+            grid=(b, hd.tiles, len(by_q[0])),
+            in_specs=[q_tile(q_off), kv_tile(k_off), kv_tile(v_off)],
+            out_specs=(q_tile(), rows),
+            scratch_shapes=[pltpu.VMEM((block_q, hd.width), jnp.float32),
+                            pltpu.VMEM((hd.per, block_q, 1), jnp.float32),
+                            pltpu.VMEM((hd.per, block_q, 1), jnp.float32)]),
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(*_tables(by_q), qf, kf, vf)
-    return out.reshape(b, h, t, d), lse
+    )(*_tables(by_q), q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash(q, k, v, cfg):
-    out, _ = _flash_fwd_impl(q, k, v, cfg)
+# The forward and the backward pass are programs of their own: a model
+# of twelve blocks traces each kernel body once, not twelve times, and
+# not again each time the train step is traced (three times a process:
+# 11 of the GPT cell's 45 s of set-up; my chip runs, PR 30).  XLA
+# inlines the calls, so nothing changes in what it compiles.
+_forward = engine_jit(_flash_fwd_impl, static_argnums=(1,),
+                      key_hint="flash_attention_forward")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _flash(ops, cfg):
+    """The token-major core.  ``ops``: ``(q, k, v)`` as (B, T, H·D) and
+    (B, T, H_kv·D) arrays, or ``(qkv,)``, the three side by side in the
+    last dimension of one; -> (B, T, H·D)."""
+    out, _ = _forward(ops, cfg)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, cfg):
-    out, lse = _flash_fwd_impl(q, k, v, cfg)
-    return out, (q, k, v, out, lse)
+def _flash_vjp_fwd(ops, cfg):
+    out, lse = _forward(ops, cfg)
+    return out, (ops, out, lse)
 
 
-def _flash_vjp_bwd(cfg, res, dout):
-    mask, scale, block_q, block_k, interpret = cfg
-    q, k, v, out, lse = res
-    b, h, t, d = q.shape
-    h_kv = k.shape[1]
-    group = h // h_kv
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h_kv, t, d)
-    vf = v.reshape(b * h_kv, t, d)
-    dof = dout.reshape(b * h, t, d)
-    of = out.reshape(b * h, t, d)
-    # D_i = rowsum(dO_i ∘ O_i) — cheap elementwise, computed by XLA
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1)[:, None, :]                  # (bh, 1, t)
+def _side_by_side(parts):
+    """``concatenate`` along the last axis, written as a sum of pads:
+    that XLA fuses into whatever reads the result (the projection's
+    three gradient products), where a concatenate of three kernels'
+    results is built in place, one copy a part (compiled for the v5e,
+    PR 30)."""
+    widths = [a.shape[-1] for a in parts]
+    zero = jnp.zeros((), parts[0].dtype)
+    lead = [(0, 0, 0)] * (parts[0].ndim - 1)
+    return functools.reduce(jnp.add, (
+        jax.lax.pad(a, zero, lead + [(sum(widths[:i]),
+                                      sum(widths[i + 1:]), 0)])
+        for i, a in enumerate(parts)))
+
+
+def _flash_bwd_impl(res, dout, cfg):
+    mask, scale, block_q, block_k, interpret, h, h_kv = cfg
+    ops, out, lse = res
+    hd = _heads(ops, h, h_kv)
+    q, k, v = ops if len(ops) == 3 else ops * 3
+    b, t = q.shape[:2]
     by_q, by_k = _tile_pairs(mask, t, block_q, block_k)
-    static = dict(mask=mask, scale=scale, block_q=block_q, block_k=block_k,
-                  half=_half(mask, t),
-                  mask_all=_mostly_partial(mask, t, block_q, block_k))
+    static = _statics(cfg, t)
+    q_off, k_off, v_off = hd.offsets
 
-    q_spec, kv_spec, row_spec = _by_q_specs(block_q, block_k, d, group)
-    dq = pl.pallas_call(
+    q_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
+    # the dq kernel also forms delta, (b·h, 1, t) rows as lse, for the
+    # dkv kernel: each reads dO once, and O is read here alone
+    dq, delta = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **static),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b, t, h * hd.d), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b * h, len(by_q[0])),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
-                      row_spec],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32)]),
-        compiler_params=_compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-        name="flash_attention_dq",
-    )(*_tables(by_q), qf, kf, vf, dof, lse, delta)
-
-    # grid (K/V heads, pairs by k tile, query heads of the group): the
-    # dk/dv tile of one K/V head stays in scratch while its q tiles and
-    # the group's query heads go by
-    qg_spec = pl.BlockSpec(
-        (None, block_q, d),
-        lambda i, p, g, qi, ki, fl: (i * group + g, qi[p], 0))
-    kg_spec = pl.BlockSpec((None, block_k, d),
-                           lambda i, p, g, qi, ki, fl: (i, ki[p], 0))
-    rowg_spec = pl.BlockSpec(
-        (None, 1, block_q),
-        lambda i, p, g, qi, ki, fl: (i * group + g, 0, qi[p]))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **static),
-        out_shape=(jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b * h_kv, len(by_k[0]), group),
-            in_specs=[qg_spec, kg_spec, kg_spec, qg_spec, rowg_spec,
-                      rowg_spec],
-            out_specs=(kg_spec, kg_spec),
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)]),
-        compiler_params=_compiler_params("parallel", "arbitrary",
+            grid=(b, hd.tiles, len(by_q[0])),
+            in_specs=[q_tile(q_off), kv_tile(k_off), kv_tile(v_off),
+                      q_tile(), q_tile(), rows],
+            out_specs=(q_tile(), rows),
+            scratch_shapes=[pltpu.VMEM((block_q, hd.width), jnp.float32),
+                            pltpu.VMEM((hd.per, block_q, 1), jnp.float32),
+                            pltpu.VMEM((hd.per, block_q, 1), jnp.float32)]),
+        compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=interpret,
+        name="flash_attention_dq",
+    )(*_tables(by_q), q, k, v, dout, out, lse)
+
+    # grid (batch, K/V lane tiles, pairs by k tile, query heads of the
+    # group): the dk/dv tile of one K/V head stays in scratch while its
+    # q tiles and the group's query heads go by
+    def qg_tile(off=0):
+        return pl.BlockSpec(
+            (None, block_q, hd.width),
+            lambda b, i, p, g, qi, ki, fl: (b, qi[p],
+                                            off + i * hd.group + g))
+
+    def kg_tile(off=0):
+        return pl.BlockSpec(
+            (None, block_k, hd.width),
+            lambda b, i, p, g, qi, ki, fl: (b, ki[p], off + i))
+
+    rowg = pl.BlockSpec(
+        (hd.per, 1, block_q),
+        lambda b, i, p, g, qi, ki, fl: (b * hd.tiles + i * hd.group + g,
+                                        0, qi[p]))
+    kv_shape = (b, t, h_kv * hd.d)
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, **static),
+        out_shape=(jax.ShapeDtypeStruct(kv_shape, k.dtype),
+                   jax.ShapeDtypeStruct(kv_shape, v.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, hd.tiles // hd.group, len(by_k[0]), hd.group),
+            in_specs=[qg_tile(q_off), kg_tile(k_off), kg_tile(v_off),
+                      qg_tile(), rowg, rowg],
+            out_specs=(kg_tile(), kg_tile()),
+            scratch_shapes=[pltpu.VMEM((block_k, hd.width), jnp.float32),
+                            pltpu.VMEM((block_k, hd.width), jnp.float32)]),
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary", "arbitrary"),
+        interpret=interpret,
         name="flash_attention_dkv",
-    )(*_tables(by_k), qf, kf, vf, dof, lse, delta)
+    )(*_tables(by_k), q, k, v, dout, lse, delta)
 
-    return (dq.reshape(b, h, t, d), dk.reshape(b, h_kv, t, d),
-            dv.reshape(b, h_kv, t, d))
-
-
-_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+    if len(ops) == 1:
+        return (_side_by_side([dq, dk, dv]),),
+    return (dq, dk, dv),
 
 
-def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = False,
-                    mask: Optional[BlockDiffusionMask] = None):
-    """q: (B, H, T, D); k, v: (B, H_kv, T, D) with ``H_kv`` dividing
-    ``H`` (query head ``h`` reads K/V head ``h // (H / H_kv)``)
-    -> (B, H, T, D).  ``causal`` or ``mask=block_diffusion(L, B)``
-    (``T = 2 L``) restrict what a query reads.  Differentiable (flash
-    backward kernels)."""
-    b, h, t, d = q.shape
-    if k.shape != v.shape or k.shape[0] != b or k.shape[2:] != (t, d) \
-            or h % k.shape[1]:
+_backward = engine_jit(_flash_bwd_impl, static_argnums=(2,),
+                       key_hint="flash_attention_backward")
+_flash.defvjp(_flash_vjp_fwd,
+              lambda cfg, res, dout: _backward(res, dout, cfg))
+
+
+def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
+                                n_kv_head: Optional[int] = None,
+                                causal: bool = False,
+                                scale: Optional[float] = None,
+                                block_q: int = 256, block_k: int = 256,
+                                interpret: bool = False,
+                                mask: Optional[BlockDiffusionMask] = None):
+    """Attention over heads where a projection wrote them.  q:
+    (B, T, H·D); k, v: (B, T, H_kv·D) with ``H_kv`` dividing ``H``
+    (query head ``h`` reads K/V head ``h // (H / H_kv)``) -> (B, T, H·D).
+    Or ``q`` alone, the fused projection's (B, T, (H + 2 H_kv)·D) result
+    with q, k and v side by side (``n_kv_head``: ``H_kv``, ``H`` if not
+    given): the kernels read the three out of the one array.  A head is
+    a block of the last dimension: heads narrower than the 128 lanes
+    share a block (``_heads_per_tile``).  ``causal`` or
+    ``mask=block_diffusion(L, B)`` (``T = 2 L``) restrict what a query
+    reads.  Differentiable (flash backward kernels)."""
+    b, t, last = q.shape
+    if k is None:
+        h_kv = n_kv_head or n_head
+        ops, d = (q,), last // (n_head + 2 * h_kv)
+        fits = last == (n_head + 2 * h_kv) * d
+    else:
+        ops, d = (q, k, v), last // n_head
+        h_kv = k.shape[-1] // max(d, 1)
+        fits = last == n_head * d and k.shape == v.shape == (b, t, h_kv * d)
+    if not (fits and h_kv and n_head % h_kv == 0):
         raise ValueError(
-            f"k/v {k.shape}/{v.shape} do not fit q {q.shape}")
+            f"{n_head} heads on operands "
+            f"{[tuple(a.shape) for a in ops]} do not fit")
     if causal and mask is not None:
         raise ValueError("causal and mask exclude each other")
     if scale is None:
         scale = d ** -0.5
     what = "causal" if causal else mask
     block_q, block_k = _resolve_blocks(t, block_q, block_k, what)
-    return _flash(q, k, v, (what, scale, block_q, block_k, interpret))
+    return _flash(ops, (what, scale, block_q, block_k, interpret,
+                        n_head, h_kv))
+
+
+def flash_attention(q, k, v, causal: bool = False,
+                    scale: Optional[float] = None, block_q: int = 256,
+                    block_k: int = 256, interpret: bool = False,
+                    mask: Optional[BlockDiffusionMask] = None):
+    """``flash_attention_token_major`` for callers that hold head-major
+    arrays.  q: (B, H, T, D); k, v: (B, H_kv, T, D) -> (B, H, T, D)."""
+    b, h, t, d = q.shape
+    if k.shape != v.shape or k.shape[0] != b or k.shape[2:] != (t, d) \
+            or h % k.shape[1]:
+        raise ValueError(
+            f"k/v {k.shape}/{v.shape} do not fit q {q.shape}")
+    out = flash_attention_token_major(
+        *(jnp.moveaxis(a, 1, 2).reshape(b, t, -1) for a in (q, k, v)),
+        n_head=h, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, mask=mask)
+    return jnp.moveaxis(out.reshape(b, t, h, d), 2, 1)

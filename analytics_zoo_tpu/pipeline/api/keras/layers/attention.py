@@ -5,7 +5,9 @@ N transformer blocks + pooler) and pyzoo
 zoo/pipeline/api/keras/layers/self_attention.py (TransformerLayer).
 
 TPU design: QKV is one fused matmul; heads live in a reshaped axis (no
-per-head loops).  With a populated ``seq`` mesh axis the layer routes
+per-head loops), and on the flash path they are not split at all: the
+kernels take the projection's (B, T, 3·H·D) result and pick a head by
+a block of its last dimension.  With a populated ``seq`` mesh axis the layer routes
 through ring attention (sequence parallelism over ICI, ppermute ring) —
 the long-context capability the reference lacks (SURVEY.md §5).  With a
 populated ``model`` axis, QKV/out projections shard Megatron-style
@@ -51,18 +53,34 @@ def _mesh():
     return get_zoo_context().mesh
 
 
-def _flash_route(t: int, head_dim: int) -> bool:
-    """Whether self-attention over ``t`` positions goes to the flash
-    kernels: ``pallas_call`` is not GSPMD-partitionable, so only on a
-    trivial (single-device) mesh; the tiles are 256 wide and a head is
-    a lane multiple.  Every operand enters the kernels tile by tile, so
-    no length is too long.  Availability comes from the kernel suite's
-    ONE capability probe (ops/fused.pallas_supported — does this
-    backend compile Pallas?), not from a backend-name string match."""
+def _flash_route(t: int, n_head: int, n_kv_head: int, head_dim: int):
+    """How many heads share a lane tile if self-attention over ``t``
+    positions goes to the flash kernels, 0 if it does not:
+    ``pallas_call`` is not GSPMD-partitionable, so only on a trivial
+    (single-device) mesh; the tiles are 256 positions long; and a head
+    has to be a block of the projection's last dimension, a lane
+    multiple or one of ``128 // head_dim`` that fill 128 lanes
+    (``ops/pallas_attention._heads_per_tile``).  Every operand enters
+    the kernels tile by tile, so no length is too long.  Availability
+    comes from the kernel suite's ONE capability probe
+    (ops/fused.pallas_supported — does this backend compile Pallas?),
+    not from a backend-name string match."""
     from analytics_zoo_tpu.ops import fused
-    return (fused.pallas_supported()
-            and math.prod(_mesh().shape.values()) == 1
-            and t % 256 == 0 and head_dim % 64 == 0)
+    from analytics_zoo_tpu.ops.pallas_attention import _heads_per_tile
+    if not (fused.pallas_supported()
+            and math.prod(_mesh().shape.values()) == 1 and t % 256 == 0):
+        return 0
+    return _heads_per_tile(n_head, n_kv_head, head_dim)
+
+
+def _count_flash(heads_per_tile: int) -> None:
+    """Which form of attention a traced program got: the kernels
+    (and, beside it, whether heads share a tile) or the lax path."""
+    from analytics_zoo_tpu.ops import fused
+    fused.count_build("flash_attention",
+                      "pallas" if heads_per_tile else "lax")
+    if heads_per_tile > 1:
+        fused.count_build("flash_attention_packed", "pallas")
 
 
 class MultiHeadSelfAttention(Layer):
@@ -120,35 +138,40 @@ class MultiHeadSelfAttention(Layer):
             x, mask = inputs, None
         b, t, _ = x.shape
         qkv = _mm(x, params["qkv_kernel"]) + params["qkv_bias"]
-        qkv = qkv.reshape(b, t, 3, self.n_head, self.head_dim)
-        q, k, v = (jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3))
 
         use_sp = self._use_sp() and mask is None
-        use_flash = (not use_sp and mask is None
-                     and _flash_route(t, self.head_dim))
-        from analytics_zoo_tpu.ops import fused
-        if use_flash:
+        per_tile = 0 if use_sp or mask is not None else _flash_route(
+            t, self.n_head, self.n_head, self.head_dim)
+        if per_tile:
             from analytics_zoo_tpu.ops.pallas_attention import (
-                flash_attention)
-            fused.count_build("flash_attention", "pallas")
-            ctx = flash_attention(q, k, v, causal=self.causal)
-        elif use_sp:
-            from analytics_zoo_tpu.parallel.ring_attention import (
-                ring_attention)
-            mesh = _mesh()
-            spec = NamedSharding(
-                mesh, P((DATA_AXIS, FSDP_AXIS), None, SEQ_AXIS, None))
-            q = jax.lax.with_sharding_constraint(q, spec)
-            k = jax.lax.with_sharding_constraint(k, spec)
-            v = jax.lax.with_sharding_constraint(v, spec)
-            ctx = ring_attention(q, k, v, mesh, causal=self.causal)
+                flash_attention_token_major)
+            _count_flash(per_tile)
+            # the kernels read q, k and v out of the projection's
+            # result where it lies, and write ctx as the output
+            # projection reads it
+            ctx = flash_attention_token_major(
+                qkv, n_head=self.n_head, causal=self.causal)
         else:
-            attn_mask = None
-            if mask is not None:
-                attn_mask = mask[:, None, None, :]   # (B,1,1,Tk)
-            fused.count_build("flash_attention", "lax")
-            ctx = scaled_dot_product_attention(
-                q, k, v, mask=attn_mask, causal=self.causal)
+            qkv = qkv.reshape(b, t, 3, self.n_head, self.head_dim)
+            q, k, v = (jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3))
+            if use_sp:
+                from analytics_zoo_tpu.parallel.ring_attention import (
+                    ring_attention)
+                mesh = _mesh()
+                spec = NamedSharding(
+                    mesh, P((DATA_AXIS, FSDP_AXIS), None, SEQ_AXIS, None))
+                q = jax.lax.with_sharding_constraint(q, spec)
+                k = jax.lax.with_sharding_constraint(k, spec)
+                v = jax.lax.with_sharding_constraint(v, spec)
+                ctx = ring_attention(q, k, v, mesh, causal=self.causal)
+            else:
+                attn_mask = None
+                if mask is not None:
+                    attn_mask = mask[:, None, None, :]   # (B,1,1,Tk)
+                _count_flash(0)
+                ctx = scaled_dot_product_attention(
+                    q, k, v, mask=attn_mask, causal=self.causal)
+            ctx = jnp.moveaxis(ctx, 1, 2).reshape(b, t, self.hidden_size)
 
         if training and self.attn_dropout > 0:
             if rng is None:
@@ -157,7 +180,6 @@ class MultiHeadSelfAttention(Layer):
             ctx = ctx * jax.random.bernoulli(
                 rng, keep, ctx.shape) / keep
 
-        ctx = jnp.moveaxis(ctx, 1, 2).reshape(b, t, self.hidden_size)
         return (_mm(ctx, params["out_kernel"]) +
                 params["out_bias"]).astype(x.dtype)
 
@@ -180,9 +202,9 @@ class GroupedQueryAttention(Layer):
     over ``T = 2 L`` positions (the noisy copy, then the clean one).
 
     Routing is ``MultiHeadSelfAttention``'s: the flash kernels on one
-    device (K/V heads are indexed, never repeated, and tiles the mask
-    rules out are skipped), dense attention under an explicit mask
-    elsewhere."""
+    device (heads are blocks of the projections' last dimension, K/V
+    heads are indexed, never repeated, and tiles the mask rules out are
+    skipped), dense attention under an explicit mask elsewhere."""
 
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
                  rope_theta: float = 10000.0, qk_norm: bool = True,
@@ -217,10 +239,9 @@ class GroupedQueryAttention(Layer):
         return params
 
     def call(self, params, inputs, training=False, rng=None):
-        from analytics_zoo_tpu.ops import fused
         from analytics_zoo_tpu.ops.attention import rotary_embedding
         from analytics_zoo_tpu.ops.pallas_attention import (
-            allowed_pairs, flash_attention)
+            allowed_pairs, flash_attention_token_major)
         x, positions = inputs
         b, t, _ = x.shape
         compute = get_policy().compute_dtype
@@ -240,25 +261,27 @@ class GroupedQueryAttention(Layer):
         k = rotary_embedding(heads("k_kernel", self.n_kv_head, "k_norm"),
                              positions, self.rope_theta)
         v = heads("v_kernel", self.n_kv_head, None)
-        q, k, v = (jnp.moveaxis(a, 1, 2) for a in (q, k, v))
 
         causal = self.mask == "causal"
-        if _flash_route(t, self.head_dim):
-            fused.count_build("flash_attention", "pallas")
+        per_tile = _flash_route(t, self.n_head, self.n_kv_head,
+                                self.head_dim)
+        _count_flash(per_tile)
+        if per_tile:
             block = 512 if t % 1024 == 0 else 256
-            ctx = flash_attention(
-                q, k, v, causal=causal, block_q=block, block_k=block,
-                mask=None if causal else self.mask)
+            ctx = flash_attention_token_major(
+                *(a.reshape(b, t, -1) for a in (q, k, v)),
+                n_head=self.n_head, causal=causal, block_q=block,
+                block_k=block, mask=None if causal else self.mask)
         else:
-            fused.count_build("flash_attention", "lax")
             group = self.n_head // self.n_kv_head
+            q, k, v = (jnp.moveaxis(a, 1, 2) for a in (q, k, v))
             ctx = scaled_dot_product_attention(
                 q, jnp.repeat(k, group, axis=1),
                 jnp.repeat(v, group, axis=1),
                 mask=None if self.mask is None else jnp.asarray(
                     allowed_pairs(self.mask, t)))
-        ctx = jnp.moveaxis(ctx, 1, 2).reshape(
-            b, t, self.n_head * self.head_dim)
+            ctx = jnp.moveaxis(ctx, 1, 2).reshape(
+                b, t, self.n_head * self.head_dim)
         return _mm(ctx, params["o_kernel"]).astype(x.dtype)
 
     def compute_output_shape(self, input_shape):

@@ -86,3 +86,21 @@ def f32_policy():
     dtypes.set_policy(param_dtype="float32", compute_dtype="float32")
     yield
     dtypes.restore_policy(old)
+
+
+@pytest.fixture
+def one_chip_routing(monkeypatch):
+    """The layers' routing as on one TPU chip: a one-device context mesh
+    (the suite's default is 8-way data parallel) and the kernel suite's
+    capability probe answering as a TPU does.  Nothing runs a kernel by
+    it: a test traces (and counts builds) or compiles for a described
+    chip."""
+    import jax
+    from analytics_zoo_tpu.common import zoo_context
+    from analytics_zoo_tpu.common.config import get_config
+    from analytics_zoo_tpu.ops import fused
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.create_mesh({"data": 1}, devices=jax.devices()[:1])
+    monkeypatch.setattr(zoo_context, "_context",
+                        zoo_context.ZooContext(get_config(), mesh))
+    monkeypatch.setattr(fused, "pallas_supported", lambda: True)

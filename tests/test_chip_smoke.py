@@ -90,9 +90,11 @@ def test_transformer(one_chip, capsys):
                          vocab=100, fit_steps=2)
     assert ok, line
     assert set(line["flash_vs_dense"]) == {"bfloat16", "float32"}
-    # the reference run really was the lax forms, and only it
+    # the reference run really was the lax forms, and only it (two
+    # heads of 64 share a lane tile: the kernels' packed form)
     assert line["kernel_builds_reference"] == {
         '{kernel="flash_attention",path="pallas"}': 1.0,
+        '{kernel="flash_attention_packed",path="pallas"}': 1.0,
         '{kernel="bias_gelu",path="lax"}': 1.0,
         '{kernel="layernorm_act",path="lax"}': 1.0}
     assert get_config().get("ops.fused") == "auto"

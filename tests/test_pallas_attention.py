@@ -200,3 +200,162 @@ def test_bad_mask_arguments_are_refused():
                         interpret=True)
     with pytest.raises(ValueError, match="must divide"):
         block_diffusion(64, 5)
+
+
+# ------------------------- the token-major core, heads sharing a tile
+from analytics_zoo_tpu.ops.pallas_attention import (  # noqa: E402
+    _heads_per_tile, flash_attention_token_major)
+
+# (query heads, K/V heads, head width, q k v as ONE array)
+HEADS = {"two-to-a-tile": (4, 4, 64, False),
+         "two-to-a-tile-fused": (4, 4, 64, True),
+         "one-to-a-tile": (2, 2, 128, False),
+         "grouped-d128": (4, 2, 128, False),
+         "grouped-d128-fused": (4, 2, 128, True)}
+CORE_MASKS = {"none": None, "causal": "causal",
+              "block_diffusion": block_diffusion(128, 4)}
+
+
+def _head_major(a, n, d):
+    return jnp.moveaxis(a.reshape(a.shape[0], a.shape[1], n, d), 1, 2)
+
+
+@pytest.mark.parametrize("mask", sorted(CORE_MASKS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_token_major_core_matches_dense(heads, dtype, mask):
+    """The kernels on (B, T, H·D) operands, a head a block of the last
+    dimension (two 64-wide heads to a 128-lane block, or one of 128;
+    q, k and v apart or side by side in one array), against dense
+    attention over the same values: forward and all three gradients."""
+    h, h_kv, d, fused = HEADS[heads]
+    assert _heads_per_tile(h, h_kv, d) == 128 // d
+    mask, b, t = CORE_MASKS[mask], 1, 256
+    rs = np.random.RandomState(6)
+    qkv = jnp.asarray(rs.randn(b, t, (h + 2 * h_kv) * d) * 0.5, dtype)
+    w = jnp.asarray(rs.randn(b, t, h * d), jnp.float32)
+    cuts = (h * d, (h + h_kv) * d)
+    kw = dict(causal=True) if mask == "causal" else dict(mask=mask)
+
+    def flash(qkv):
+        ops = (qkv,) if fused else jnp.split(qkv, cuts, axis=-1)
+        out = flash_attention_token_major(
+            *ops, n_head=h, n_kv_head=h_kv if fused else None,
+            block_q=128, block_k=128, interpret=True, **kw)
+        assert out.shape == (b, t, h * d) and out.dtype == qkv.dtype
+        return jnp.sum(w * out.astype(jnp.float32))
+
+    def dense(qkv):
+        q, k, v = jnp.split(qkv.astype(jnp.float32), cuts, axis=-1)
+        out = _dense(_head_major(q, h, d), _head_major(k, h_kv, d),
+                     _head_major(v, h_kv, d), allowed_pairs(mask, t))
+        return jnp.sum(w * jnp.moveaxis(out, 1, 2).reshape(b, t, h * d))
+
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=0.05, atol=0.05)
+    np.testing.assert_allclose(flash(qkv), dense(qkv),
+                               rtol=tol["rtol"] * 10)
+    got, want = jax.grad(flash)(qkv), jax.grad(dense)(qkv)
+    assert got.dtype == qkv.dtype
+    # dq, dk and dv, side by side as q, k and v are
+    for g, r in zip(jnp.split(got.astype(jnp.float32), cuts, axis=-1),
+                    jnp.split(want, cuts, axis=-1)):
+        np.testing.assert_allclose(g, r, **tol)
+
+
+@pytest.mark.parametrize("heads", ["two-to-a-tile", "grouped-d128"])
+def test_head_major_wrapper_is_the_core(heads):
+    """``flash_attention`` over (B, H, T, D) is the core round two
+    ``moveaxis``: the same values, forward and backward, bit for bit."""
+    h, h_kv, d, _ = HEADS[heads]
+    b, t = 1, 256
+    rs = np.random.RandomState(7)
+    q = jnp.asarray(rs.randn(b, t, h * d), jnp.float32)
+    k, v = (jnp.asarray(rs.randn(b, t, h_kv * d), jnp.float32)
+            for _ in "kv")
+
+    def core(q, k, v):
+        return flash_attention_token_major(
+            q, k, v, n_head=h, causal=True, block_q=128, block_k=128,
+            interpret=True)
+
+    def wrapped(q, k, v):
+        out = flash_attention(
+            _head_major(q, h, d), _head_major(k, h_kv, d),
+            _head_major(v, h_kv, d), causal=True, block_q=128,
+            block_k=128, interpret=True)
+        assert out.shape == (b, h, t, d)
+        return jnp.moveaxis(out, 1, 2).reshape(b, t, h * d)
+
+    np.testing.assert_array_equal(core(q, k, v), wrapped(q, k, v))
+    for got, want in zip(
+            jax.grad(lambda *a: jnp.sum(jnp.square(wrapped(*a))),
+                     (0, 1, 2))(q, k, v),
+            jax.grad(lambda *a: jnp.sum(jnp.square(core(*a))),
+                     (0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_heads_that_fill_no_lane_tile():
+    """64-wide heads on FEWER K/V heads, or an odd number of them, have
+    no lane-aligned block: the layers send them to the lax path on the
+    chip; interpret mode runs them one head a block (the grouped test
+    above, at 16 wide)."""
+    assert _heads_per_tile(12, 12, 64) == 2
+    assert _heads_per_tile(8, 8, 32) == 4
+    assert _heads_per_tile(32, 4, 128) == 1
+    assert _heads_per_tile(8, 8, 256) == 1
+    assert _heads_per_tile(8, 2, 64) == 0
+    assert _heads_per_tile(3, 3, 64) == 0
+    assert _heads_per_tile(4, 4, 96) == 0
+    with pytest.raises(ValueError, match="do not fit"):
+        flash_attention_token_major(jnp.zeros((1, 128, 100)), n_head=3,
+                                    interpret=True)
+
+
+# ------------------------------- which form a traced layer was given
+def _builds_while_tracing(layer, input_shapes, *inputs):
+    from analytics_zoo_tpu.observability import get_registry
+    params = jax.eval_shape(
+        lambda: layer.build(jax.random.PRNGKey(0), input_shapes))
+    prefix = "fused_kernel_builds_total"
+
+    def flash_series():
+        return {k[len(prefix):]: v for k, v in
+                get_registry().snapshot()["counters"].items()
+                if k.startswith(prefix) and "flash_attention" in k}
+
+    before = flash_series()
+    jax.eval_shape(lambda p, *x: layer.call(
+        p, list(x) if len(x) > 1 else x[0]), params, *inputs)
+    return {k: v - before.get(k, 0.0) for k, v in flash_series().items()
+            if v != before.get(k, 0.0)}
+
+
+def test_build_counter_says_which_form_a_traced_layer_got(one_chip_routing):
+    """``fused_kernel_builds_total``: the GPT block's 12 heads of 64
+    share lane tiles (the packed series beside the kernels' own), the
+    sparse cell's 32 on 4 of 128 do not, and 64-wide heads on fewer K/V
+    heads have no lane-aligned block and take the lax path, counted as
+    any other fallback."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
+        GroupedQueryAttention, MultiHeadSelfAttention)
+    f32 = jax.ShapeDtypeStruct((2, 512, 768), jnp.float32)
+    assert _builds_while_tracing(
+        MultiHeadSelfAttention(768, 12, causal=True), (None, 512, 768),
+        f32) == {'{kernel="flash_attention",path="pallas"}': 1.0,
+                 '{kernel="flash_attention_packed",path="pallas"}': 1.0}
+    x = jax.ShapeDtypeStruct((1, 512, 256), jnp.float32)
+    pos = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    shapes = [(None, 512, 256), (None, 512)]
+    assert _builds_while_tracing(
+        GroupedQueryAttention(32, 4, 128, mask=block_diffusion(256, 4)),
+        shapes, x, pos) == {'{kernel="flash_attention",path="pallas"}': 1.0}
+    assert _builds_while_tracing(
+        GroupedQueryAttention(8, 2, 64, mask="causal"),
+        shapes, x, pos) == {'{kernel="flash_attention",path="lax"}': 1.0}
+    # a padding-mask INPUT still sends the dense layer to the lax path
+    assert _builds_while_tracing(
+        MultiHeadSelfAttention(768, 12), [(None, 512, 768), (None, 512)],
+        f32, jax.ShapeDtypeStruct((2, 512), jnp.float32)) == {
+            '{kernel="flash_attention",path="lax"}': 1.0}

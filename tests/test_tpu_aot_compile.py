@@ -24,7 +24,7 @@ from analytics_zoo_tpu.ops import fused
 from analytics_zoo_tpu.ops.grouped_matmul import (
     buffer_rows, group_layout, grouped_matmul)
 from analytics_zoo_tpu.ops.pallas_attention import (
-    block_diffusion, flash_attention)
+    block_diffusion, flash_attention, flash_attention_token_major)
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +75,16 @@ def _flash_block_diffusion(q, k, v):
                            block_q=512, block_k=512)
 
 
+def _flash_gpt_cell(qkv):
+    return flash_attention_token_major(qkv, n_head=12, causal=True)
+
+
+def _flash_sdar_cell(q, k, v):
+    return flash_attention_token_major(
+        q, k, v, n_head=32, mask=block_diffusion(4096, 4), block_q=512,
+        block_k=512)
+
+
 def _grad(fn, n_args):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
                     argnums=tuple(range(n_args)))
@@ -108,6 +118,17 @@ CASES = [
     # heads on 4 K/V heads, the block-diffusion mask's tile tables
     ("flash-blockdiff-grad-h32kv4t8192d128", _grad(_flash_block_diffusion, 3),
      [(1, 32, 8192, 128), (1, 4, 8192, 128), (1, 4, 8192, 128)], BF16, 3),
+    # the cells' own operands, where the projections wrote them: GPT's
+    # fused float32 qkv at 12 heads of 64, two to a lane tile; the
+    # sparse cell's bfloat16 q and k/v at 32 heads on 4 of 128
+    ("flash-gpt-cell-b32t512x2304", _flash_gpt_cell, [(32, 512, 2304)],
+     F32, 1),
+    ("flash-gpt-cell-grad-b32t512x2304", _grad(_flash_gpt_cell, 1),
+     [(32, 512, 2304)], F32, 3),
+    ("flash-sdar-cell-t8192x4096", _flash_sdar_cell,
+     [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 1),
+    ("flash-sdar-cell-grad-t8192x4096", _grad(_flash_sdar_cell, 3),
+     [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 3),
 ]
 # every activation the LayerNorm epilogue claims to run in-kernel
 CASES += [
@@ -211,6 +232,77 @@ def test_optimizer_update_is_one_fusion_in_the_leafs_own_layout(
     alias = alias[:alias.index("entry_computation_layout")]
     pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", alias)
     assert [(int(o), int(i)) for o, i in pairs] == list(enumerate(state))
+
+
+def _relayouts(text, t, least):
+    """The ``copy`` and ``transpose`` instructions of a compiled module
+    (fused computations included) over arrays of ``t`` positions and at
+    least ``least`` elements: activations, not weights."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r" = (\w+\[([\d,]+)\]\S*) (copy|transpose)\(", line)
+        if not m:
+            continue
+        dims = [int(n) for n in m.group(2).split(",")]
+        size = 1
+        for n in dims:
+            size *= n
+        if t in dims and size >= least:
+            found.append(f"{m.group(3)} {m.group(1)}")
+    return found
+
+
+def _layer_grad_text(layer, input_shapes, args):
+    params = jax.eval_shape(
+        lambda: layer.build(jax.random.PRNGKey(0), input_shapes))
+
+    def loss(params, *inputs):
+        x = list(inputs) if len(inputs) > 1 else inputs[0]
+        return jnp.sum(jnp.square(layer.call(params, x).astype(F32)))
+
+    sharding = args[0].sharding
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        params)
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, *args).compile().as_text()
+
+
+def test_attention_layers_hand_the_kernels_what_the_projections_wrote(
+        v5e, one_chip_routing):
+    """The two attention layers at their cells' shapes, forward and
+    backward, compiled for the v5e: between the projections and the
+    three flash kernels no head array is copied or transposed.
+
+    GPT's block (12 heads of 64, float32): NOTHING is — q, k and v are
+    read out of the fused projection's result, ctx goes to the output
+    projection as written, and the three gradients are read side by
+    side by the projection's gradient products (no concatenate built).
+    The sparse cell's block (32 heads on 4 of 128, bfloat16, per-head
+    RMS norm and rotary): v, ctx and its cotangent are not; q and k,
+    dq and dk still are, once each, because XLA works the rotary
+    embedding's half-head slices with the positions in the lanes
+    (ROADMAP S9) — four, where the head-major entry had five."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
+        GroupedQueryAttention, MultiHeadSelfAttention)
+    gpt = MultiHeadSelfAttention(768, 12, causal=True)
+    text = _layer_grad_text(
+        gpt, (None, 512, 768),
+        [jax.ShapeDtypeStruct((32, 512, 768), F32, sharding=v5e)])
+    assert text.count("tpu_custom_call") == 3
+    assert _relayouts(text, 512, 32 * 512 * 768) == []
+    assert "dynamic-update-slice" not in text and " concatenate(" not in text
+
+    sdar = GroupedQueryAttention(32, 4, 128, rope_theta=1e6,
+                                 mask=block_diffusion(4096, 4))
+    text = _layer_grad_text(
+        sdar, [(None, 8192, 2048), (None, 8192)],
+        [jax.ShapeDtypeStruct((1, 8192, 2048), F32, sharding=v5e),
+         jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=v5e)])
+    assert text.count("tpu_custom_call") == 3
+    moved = _relayouts(text, 8192, 8192 * 4 * 128)
+    assert len(moved) <= 4, moved
+    assert not [m for m in moved if m.startswith("transpose")], moved
 
 
 def test_capability_probe_compiles_for_v5e(v5e):
