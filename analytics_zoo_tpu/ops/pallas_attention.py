@@ -39,12 +39,20 @@ grid step nor a DMA, in the forward, dq and dkv kernels alike; a tile
 every pair of which is allowed skips the mask's arithmetic.  The mask
 itself is evaluated from iotas inside the kernel (``_key_interval``:
 each query row may read one interval of key positions in a key tile),
-never materialised.
+never materialised: ``causal``, ``sliding_window(W)`` (causal inside a
+band of ``W`` keys) and ``block_diffusion(L, B)``.
 
 Grouped-query attention: ``k``/``v`` may have fewer heads than ``q``;
 query head ``h`` reads K/V head ``h // group`` through the index map,
 and the dkv kernel walks the group's query heads itself, so no repeated
-K/V and no per-query-head dk/dv exists in HBM.
+K/V and no per-query-head dk/dv exists in HBM.  K and V are operands
+like any other: they may be another layer's.
+
+The differential pair (``differential=True``): 64-wide heads in pairs, a
+pair a lane tile, fewer K/V pairs than query pairs.  Each head's logits
+come from its own half of the q and K tiles as above, and its map is
+applied to the pair's WHOLE V tile, so a tile's output is two tiles wide
+(one a head) and V is neither copied nor cut.
 """
 
 from __future__ import annotations
@@ -82,6 +90,18 @@ def block_diffusion(seq_len: int, block: int) -> BlockDiffusionMask:
     return BlockDiffusionMask(int(seq_len), int(block))
 
 
+class SlidingWindowMask(NamedTuple):
+    """Causal within a window: query ``i`` reads the ``window`` keys
+    ``i - window + 1 .. i``."""
+    window: int
+
+
+def sliding_window(window: int) -> SlidingWindowMask:
+    if window < 1:
+        raise ValueError(f"a window of {window} keys")
+    return SlidingWindowMask(int(window))
+
+
 def _key_interval(mask, q_pos, k_clean, xp):
     """``(lo, hi)``: query position ``q_pos`` may read the keys at
     positions ``lo <= k < hi`` of a key tile (``k_clean``: whether that
@@ -91,6 +111,8 @@ def _key_interval(mask, q_pos, k_clean, xp):
     call it, so P is recomputed under the identical mask."""
     if mask == "causal":
         return xp.zeros_like(q_pos), q_pos + 1
+    if isinstance(mask, SlidingWindowMask):
+        return xp.maximum(q_pos - (mask.window - 1), 0), q_pos + 1
     L, B = mask
     noisy = q_pos < L
     rel = xp.where(noisy, q_pos, q_pos - L)
@@ -138,6 +160,7 @@ def _tile_pairs(mask, t: int, block_q: int, block_k: int):
             all_[:, j] = whole.reshape(nq, block_q).all(axis=1)
     if not any_.any(axis=1).all():
         raise ValueError("a query tile with no key to read")
+    _gauge_tiles(mask, int(any_.sum()), _causal_tiles(t, block_q, block_k))
 
     def walk(by_q: bool):
         grid = any_ if by_q else any_.T
@@ -150,6 +173,33 @@ def _tile_pairs(mask, t: int, block_q: int, block_k: int):
         return (qi.astype(np.int32), ki.astype(np.int32), flags)
 
     return walk(True), walk(False)
+
+
+def _causal_tiles(t: int, block_q: int, block_k: int) -> int:
+    """Tiles the plain causal mask walks at these tile sizes."""
+    q_last = np.arange(t // block_q) * block_q + block_q - 1
+    return int(np.sum(q_last // block_k + 1))
+
+
+def _mask_name(mask) -> str:
+    if mask is None or isinstance(mask, str):
+        return mask or "none"
+    return {BlockDiffusionMask: "block_diffusion",
+            SlidingWindowMask: "sliding_window"}[type(mask)]
+
+
+def _gauge_tiles(mask, walked: int, causal: int) -> None:
+    """How many (q tile, k tile) pairs a batch element and head walk
+    under the mask whose tables were just built, beside what the causal
+    mask walks over the same tiles."""
+    from analytics_zoo_tpu.observability import get_registry
+    gauge = get_registry().gauge(
+        "flash_attention_tiles",
+        "tile pairs of the newest tile tables built for a mask",
+        labels=("mask", "which"))
+    name = _mask_name(mask)
+    gauge.labels(name, "walked").set(walked)
+    gauge.labels(name, "causal").set(causal)
 
 
 def _masked(s, mask, mask_all, flags, q_pos, k_pos, k_clean):
@@ -204,13 +254,22 @@ def _row_to_col(row):
     return jnp.broadcast_to(row, (_LANES, n)).T[:, 0:1]
 
 
-def _heads_per_tile(h: int, h_kv: int, d: int) -> int:
+def _heads_per_tile(h: int, h_kv: int, d: int,
+                    differential: bool = False) -> int:
     """How many heads one lane tile of the token-major operands holds:
     1 where a head is a lane multiple; ``128 // d`` consecutive heads
     where a narrower head divides the 128 lanes, every query head has
     its own K/V head and the tiles come out whole; 0 where neither
     holds (no lane-aligned block picks such a head: Mosaic refuses it,
-    and interpret mode runs it at one head a block)."""
+    and interpret mode runs it at one head a block).
+
+    ``differential``: the heads come in pairs, a pair of query heads
+    reads a PAIR of K/V heads, first on first and second on second, and
+    a pair is what a tile holds: 2 where two heads fill the 128 lanes
+    (fewer K/V pairs than query pairs are then fewer K/V TILES, found by
+    the index map as a wider head's K/V head is), else 0."""
+    if differential:
+        return 2 if 2 * d == _LANES and h % 2 == 0 and h_kv % 2 == 0 else 0
     if d % _LANES == 0:
         return 1
     per = _LANES // d
@@ -230,6 +289,11 @@ def _head_lanes(j: int, per: int, *xs):
     lane = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
     own = (lane >= j * d) & (lane < (j + 1) * d)
     return tuple(jnp.where(own, x, jnp.zeros_like(x)) for x in xs)
+
+
+def _own(j: int, width: int):
+    """The lanes of head ``j``'s map in a differential tile's output."""
+    return slice(j * width, (j + 1) * width)
 
 
 def _spread(cols, width: int):
@@ -253,7 +317,10 @@ def _flash_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
     ``l``; the tile's accumulator is updated and written whole."""
     p_id = pl.program_id(2)
     flags = fl_ref[p_id]
-    per, width = m_ref.shape[0], acc_ref.shape[1]
+    per, width = m_ref.shape[0], q_ref.shape[1]
+    # the differential pair: each head's map over the tile's WHOLE V,
+    # side by side in an accumulator two tiles wide
+    differential = acc_ref.shape[1] != width
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
@@ -278,16 +345,28 @@ def _flash_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         l_ref[j] = l_ref[j] * corr[j] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[j] = m_new
-        pv_j = jnp.dot(p.astype(v_blk.dtype), v_j,
+        pv_j = jnp.dot(p.astype(v_blk.dtype),
+                       v_blk if differential else v_j,
                        preferred_element_type=jnp.float32)
-        pv = pv_j if pv is None else pv + pv_j
-    acc_ref[...] = acc_ref[...] * _spread(corr, width) + pv
+        if differential:
+            own = _own(j, width)
+            acc_ref[:, own] = acc_ref[:, own] * corr[j] + pv_j
+        else:
+            pv = pv_j if pv is None else pv + pv_j
+    if not differential:
+        acc_ref[...] = acc_ref[...] * _spread(corr, width) + pv
 
     @pl.when((flags & _LAST) != 0)
     def _store():
         l_safe = [jnp.maximum(l_ref[j], 1e-30) for j in range(per)]
-        o_ref[...] = (acc_ref[...] / _spread(l_safe, width)
-                      ).astype(o_ref.dtype)
+        if differential:
+            for j in range(per):
+                own = _own(j, width)
+                o_ref[:, own] = (acc_ref[:, own] / l_safe[j]
+                                 ).astype(o_ref.dtype)
+        else:
+            o_ref[...] = (acc_ref[...] / _spread(l_safe, width)
+                          ).astype(o_ref.dtype)
         # lse leaves as lane-dense (1, block_q) rows: a (t, 1) float32
         # array is tiled to 128 lanes in HBM, 128 times its size
         for j in range(per):
@@ -305,7 +384,8 @@ def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     lane-dense row for the dkv kernel."""
     p_id = pl.program_id(2)
     flags = fl_ref[p_id]
-    per = lse_col.shape[0]
+    per, width = lse_col.shape[0], q_ref.shape[1]
+    differential = do_ref.shape[1] != width
 
     @pl.when((flags & _FIRST) != 0)
     def _init():
@@ -314,8 +394,9 @@ def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                 * o_ref[...].astype(jnp.float32))
         for j in range(per):
             lse_col[j] = _row_to_col(lse_ref[j])
-            delta_col[j] = jnp.sum(*_head_lanes(j, per, do_o), axis=1,
-                                   keepdims=True)
+            own = do_o[:, _own(j, width)] if differential \
+                else _head_lanes(j, per, do_o)[0]
+            delta_col[j] = jnp.sum(own, axis=1, keepdims=True)
             delta_ref[j] = _col_to_row(delta_col[j])
 
     # recompute logits EXACTLY as the forward did (same dtype for the
@@ -334,8 +415,12 @@ def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                                 preferred_element_type=jnp.float32)
         s = _masked(s, mask, mask_all, flags, q_pos, k_pos, k_start >= half)
         p = jnp.exp(s - lse_col[j])                         # (bq, bk)
-        dp = jax.lax.dot_general(do, v_j, _NT,
-                                 preferred_element_type=jnp.float32)
+        if differential:
+            dp = jax.lax.dot_general(do[:, _own(j, width)], v_blk, _NT,
+                                     preferred_element_type=jnp.float32)
+        else:
+            dp = jax.lax.dot_general(do, v_j, _NT,
+                                     preferred_element_type=jnp.float32)
         ds = p * (dp - delta_col[j])
         dq_j = jnp.dot(ds.astype(k_blk.dtype), k_j,
                        preferred_element_type=jnp.float32)
@@ -359,7 +444,8 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     rows and every product is a plain or an ``a @ b.T`` one."""
     p_id, g = pl.program_id(2), pl.program_id(3)
     flags = fl_ref[p_id]
-    per = lse_ref.shape[0]
+    per, width = lse_ref.shape[0], q_ref.shape[1]
+    differential = do_ref.shape[1] != width
 
     @pl.when(((flags & _FIRST) != 0) & (g == 0))
     def _init():
@@ -374,7 +460,10 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     k_pos = _positions(k_start, block_k, 0)
     dk = dv = None
     for j in range(per):
-        q_j, do_j = _head_lanes(j, per, q, do)
+        if differential:
+            q_j, do_j = _head_lanes(j, per, q)[0], do[:, _own(j, width)]
+        else:
+            q_j, do_j = _head_lanes(j, per, q, do)
         s_t = jax.lax.dot_general(k_blk, q_j, _NT,
                                   preferred_element_type=jnp.float32)
         s_t = _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
@@ -439,6 +528,9 @@ class _Heads(NamedTuple):
     per: int
     width: int
     offsets: tuple
+    # lanes of a tile's OUTPUT (and of its cotangent): the tile's own,
+    # or one tile a head under the differential pair
+    out_width: int
 
     @property
     def group(self):
@@ -449,12 +541,14 @@ class _Heads(NamedTuple):
         return self.h // self.per
 
 
-def _heads(ops, h: int, h_kv: int) -> _Heads:
+def _heads(ops, h: int, h_kv: int, differential: bool = False) -> _Heads:
     fused = len(ops) == 1
     d = ops[0].shape[-1] // (h + 2 * h_kv if fused else h)
-    per = _heads_per_tile(h, h_kv, d) or 1
+    per = _heads_per_tile(h, h_kv, d, differential) or 1
     offsets = (0, h // per, (h + h_kv) // per) if fused else (0, 0, 0)
-    return _Heads(h, h_kv, d, per, per * d, offsets)
+    width = per * d
+    return _Heads(h, h_kv, d, per, width, offsets,
+                  per * width if differential else width)
 
 
 def _statics(cfg, t: int):
@@ -466,12 +560,15 @@ def _statics(cfg, t: int):
 
 def _by_q_specs(block_q: int, block_k: int, hd: _Heads):
     """Block specs of a walk by q tile (grid: batch, lane tiles, pairs):
-    a q-sized tile of the heads' lanes, a K/V tile of their K/V head's,
-    the heads' lane-dense rows."""
-    def q_tile(off=0):
+    a q-sized tile of the heads' lanes, one of their output's (or its
+    cotangent's) lanes, a K/V tile of their K/V head's, the heads'
+    lane-dense rows."""
+    def q_tile(off=0, width=hd.width):
         return pl.BlockSpec(
-            (None, block_q, hd.width),
+            (None, block_q, width),
             lambda b, i, p, qi, ki, fl: (b, qi[p], off + i))
+
+    o_tile = functools.partial(q_tile, 0, hd.out_width)
 
     def kv_tile(off):
         return pl.BlockSpec(
@@ -481,27 +578,29 @@ def _by_q_specs(block_q: int, block_k: int, hd: _Heads):
     rows = pl.BlockSpec(
         (hd.per, 1, block_q),
         lambda b, i, p, qi, ki, fl: (b * hd.tiles + i, 0, qi[p]))
-    return q_tile, kv_tile, rows
+    return q_tile, o_tile, kv_tile, rows
 
 
 def _flash_fwd_impl(ops, cfg):
-    mask, scale, block_q, block_k, interpret, h, h_kv = cfg
-    hd = _heads(ops, h, h_kv)
+    mask, scale, block_q, block_k, interpret, h, h_kv, differential = cfg
+    hd = _heads(ops, h, h_kv, differential)
     q, k, v = ops if len(ops) == 3 else ops * 3
     b, t = q.shape[:2]
     by_q, _ = _tile_pairs(mask, t, block_q, block_k)
-    q_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
+    q_tile, o_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
     q_off, k_off, v_off = hd.offsets
     return pl.pallas_call(
         functools.partial(_flash_kernel, **_statics(cfg, t)),
-        out_shape=(jax.ShapeDtypeStruct((b, t, h * hd.d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b, t, hd.tiles * hd.out_width),
+                                        q.dtype),
                    jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, hd.tiles, len(by_q[0])),
             in_specs=[q_tile(q_off), kv_tile(k_off), kv_tile(v_off)],
-            out_specs=(q_tile(), rows),
-            scratch_shapes=[pltpu.VMEM((block_q, hd.width), jnp.float32),
+            out_specs=(o_tile(), rows),
+            scratch_shapes=[pltpu.VMEM((block_q, hd.out_width),
+                                       jnp.float32),
                             pltpu.VMEM((hd.per, block_q, 1), jnp.float32),
                             pltpu.VMEM((hd.per, block_q, 1), jnp.float32)]),
         compiler_params=_compiler_params("parallel", "parallel",
@@ -550,16 +649,16 @@ def _side_by_side(parts):
 
 
 def _flash_bwd_impl(res, dout, cfg):
-    mask, scale, block_q, block_k, interpret, h, h_kv = cfg
+    mask, scale, block_q, block_k, interpret, h, h_kv, differential = cfg
     ops, out, lse = res
-    hd = _heads(ops, h, h_kv)
+    hd = _heads(ops, h, h_kv, differential)
     q, k, v = ops if len(ops) == 3 else ops * 3
     b, t = q.shape[:2]
     by_q, by_k = _tile_pairs(mask, t, block_q, block_k)
     static = _statics(cfg, t)
     q_off, k_off, v_off = hd.offsets
 
-    q_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
+    q_tile, o_tile, kv_tile, rows = _by_q_specs(block_q, block_k, hd)
     # the dq kernel also forms delta, (b·h, 1, t) rows as lse, for the
     # dkv kernel: each reads dO once, and O is read here alone
     dq, delta = pl.pallas_call(
@@ -570,7 +669,7 @@ def _flash_bwd_impl(res, dout, cfg):
             num_scalar_prefetch=3,
             grid=(b, hd.tiles, len(by_q[0])),
             in_specs=[q_tile(q_off), kv_tile(k_off), kv_tile(v_off),
-                      q_tile(), q_tile(), rows],
+                      o_tile(), o_tile(), rows],
             out_specs=(q_tile(), rows),
             scratch_shapes=[pltpu.VMEM((block_q, hd.width), jnp.float32),
                             pltpu.VMEM((hd.per, block_q, 1), jnp.float32),
@@ -584,9 +683,9 @@ def _flash_bwd_impl(res, dout, cfg):
     # grid (batch, K/V lane tiles, pairs by k tile, query heads of the
     # group): the dk/dv tile of one K/V head stays in scratch while its
     # q tiles and the group's query heads go by
-    def qg_tile(off=0):
+    def qg_tile(off=0, width=hd.width):
         return pl.BlockSpec(
-            (None, block_q, hd.width),
+            (None, block_q, width),
             lambda b, i, p, g, qi, ki, fl: (b, qi[p],
                                             off + i * hd.group + g))
 
@@ -608,7 +707,7 @@ def _flash_bwd_impl(res, dout, cfg):
             num_scalar_prefetch=3,
             grid=(b, hd.tiles // hd.group, len(by_k[0]), hd.group),
             in_specs=[qg_tile(q_off), kg_tile(k_off), kg_tile(v_off),
-                      qg_tile(), rowg, rowg],
+                      qg_tile(0, hd.out_width), rowg, rowg],
             out_specs=(kg_tile(), kg_tile()),
             scratch_shapes=[pltpu.VMEM((block_k, hd.width), jnp.float32),
                             pltpu.VMEM((block_k, hd.width), jnp.float32)]),
@@ -634,8 +733,8 @@ def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
                                 causal: bool = False,
                                 scale: Optional[float] = None,
                                 block_q: int = 256, block_k: int = 256,
-                                interpret: bool = False,
-                                mask: Optional[BlockDiffusionMask] = None):
+                                interpret: bool = False, mask=None,
+                                differential: bool = False):
     """Attention over heads where a projection wrote them.  q:
     (B, T, H·D); k, v: (B, T, H_kv·D) with ``H_kv`` dividing ``H``
     (query head ``h`` reads K/V head ``h // (H / H_kv)``) -> (B, T, H·D).
@@ -643,9 +742,19 @@ def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
     with q, k and v side by side (``n_kv_head``: ``H_kv``, ``H`` if not
     given): the kernels read the three out of the one array.  A head is
     a block of the last dimension: heads narrower than the 128 lanes
-    share a block (``_heads_per_tile``).  ``causal`` or
-    ``mask=block_diffusion(L, B)`` (``T = 2 L``) restrict what a query
-    reads.  Differentiable (flash backward kernels)."""
+    share a block (``_heads_per_tile``).  ``causal``,
+    ``mask=sliding_window(W)`` or ``mask=block_diffusion(L, B)``
+    (``T = 2 L``) restrict what a query reads.  k and v may be another
+    layer's: their cotangents are this call's share of the sum over
+    their readers.  Differentiable (flash backward kernels).
+
+    ``differential``: the heads come in pairs, query heads ``2j`` and
+    ``2j + 1`` on K/V heads ``2i`` and ``2i + 1`` with ``i = j // (H /
+    H_kv)``, and each query head's map (over its own K head) is applied
+    to the pair's two V heads side by side, ``V_i = [v_2i, v_2i+1]``:
+    -> (B, T, H·2D), head ``h``'s ``softmax(q_h k^T) V_i`` at lanes
+    ``[2 D h, 2 D (h + 1))``.  No second copy of V and no repeated K/V
+    is built; what is done with the two maps of a pair is the caller's."""
     b, t, last = q.shape
     if k is None:
         h_kv = n_kv_head or n_head
@@ -661,18 +770,21 @@ def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
             f"{[tuple(a.shape) for a in ops]} do not fit")
     if causal and mask is not None:
         raise ValueError("causal and mask exclude each other")
+    if differential and not _heads_per_tile(n_head, h_kv, d, True):
+        raise ValueError(
+            f"the differential pair on the kernels takes heads of "
+            f"{_LANES // 2}, in pairs; got {n_head} on {h_kv} of {d}")
     if scale is None:
         scale = d ** -0.5
     what = "causal" if causal else mask
     block_q, block_k = _resolve_blocks(t, block_q, block_k, what)
     return _flash(ops, (what, scale, block_q, block_k, interpret,
-                        n_head, h_kv))
+                        n_head, h_kv, bool(differential)))
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = False,
-                    mask: Optional[BlockDiffusionMask] = None):
+                    block_k: int = 256, interpret: bool = False, mask=None):
     """``flash_attention_token_major`` for callers that hold head-major
     arrays.  q: (B, H, T, D); k, v: (B, H_kv, T, D) -> (B, H, T, D)."""
     b, h, t, d = q.shape

@@ -61,6 +61,10 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.diffusion import (
     BlockDiffusionLoss, BlockDiffusionNoise,
 )
+from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
+    DifferentialAttention, GatedFeedForward, GatedMemoryUnit,
+    HybridDecoderLayer, Mamba, NextTokenLoss,
+)
 
 # Keras-2 style aliases
 Conv1D = Convolution1D
@@ -94,6 +98,8 @@ __all__ = [
     "TransformerLayer", "transformer_block",
     "SparseEmbedding", "AtrousConvolution1D", "ShareConvolution2D",
     "SpaceToDepth2D", "MoE", "DroplessMoE",
+    "DifferentialAttention", "GatedFeedForward", "GatedMemoryUnit",
+    "HybridDecoderLayer", "Mamba", "NextTokenLoss",
     "AddConstant", "BinaryThreshold", "CAdd", "CMul", "Exp",
     "GaussianSampler", "HardShrink", "HardTanh", "Identity", "Log",
     "LRN2D", "Mul", "MulConstant", "Negative", "Power",

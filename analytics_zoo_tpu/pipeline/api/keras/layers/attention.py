@@ -53,7 +53,8 @@ def _mesh():
     return get_zoo_context().mesh
 
 
-def _flash_route(t: int, n_head: int, n_kv_head: int, head_dim: int):
+def _flash_route(t: int, n_head: int, n_kv_head: int, head_dim: int,
+                 differential: bool = False):
     """How many heads share a lane tile if self-attention over ``t``
     positions goes to the flash kernels, 0 if it does not:
     ``pallas_call`` is not GSPMD-partitionable, so only on a trivial
@@ -70,7 +71,7 @@ def _flash_route(t: int, n_head: int, n_kv_head: int, head_dim: int):
     if not (fused.pallas_supported()
             and math.prod(_mesh().shape.values()) == 1 and t % 256 == 0):
         return 0
-    return _heads_per_tile(n_head, n_kv_head, head_dim)
+    return _heads_per_tile(n_head, n_kv_head, head_dim, differential)
 
 
 def _count_flash(heads_per_tile: int) -> None:

@@ -20,7 +20,8 @@ class Embedding(Layer):
 
     def __init__(self, input_dim: int, output_dim: int, init="uniform",
                  W_regularizer=None, mask_zero: bool = False,
-                 parallel_mode: str = None, vocab_held=None, **kwargs):
+                 parallel_mode: str = None, vocab_held=None,
+                 tie_head: bool = False, **kwargs):
         """parallel_mode: None | "dim" — "dim" shards the embedding dim
         over the ``model`` axis (the gather stays local; downstream TP
         layers consume the sharded activations directly).
@@ -30,7 +31,12 @@ class Embedding(Layer):
         holds rows ``first .. first + count - 1`` only and gives zeros
         for every other id (what the other ranks would add is theirs to
         compute; no exchange is built here).  Default: the whole
-        table."""
+        table.
+
+        tie_head: the layer has a second output, the table itself
+        (count, D), for a head that forms its logits from it
+        (``layers.ssm.NextTokenLoss``): one leaf with two uses, whose
+        gradient is the sum of both."""
         super().__init__(**kwargs)
         self.vocab_first, self.vocab_count = (
             (0, int(input_dim)) if vocab_held is None
@@ -47,6 +53,7 @@ class Embedding(Layer):
         if parallel_mode not in (None, "dim"):
             raise ValueError("parallel_mode must be None|dim")
         self.parallel_mode = parallel_mode
+        self.tie_head = bool(tie_head)
 
     def build(self, rng, input_shape) -> Params:
         from jax.sharding import PartitionSpec as P
@@ -72,10 +79,13 @@ class Embedding(Layer):
             out = jnp.take(params["embeddings"], ids, axis=0)
         if self.mask_zero:
             out = out * (ids != 0)[..., None].astype(out.dtype)
-        return out
+        return [out, params["embeddings"]] if self.tie_head else out
 
     def compute_output_shape(self, input_shape):
-        return tuple(input_shape) + (self.output_dim,)
+        out = tuple(input_shape) + (self.output_dim,)
+        if self.tie_head:
+            return [out, (self.vocab_count, self.output_dim)]
+        return out
 
 
 class WordEmbedding(Embedding):

@@ -21,7 +21,17 @@ from ``--seed``):
                   batch 32, 2 blocks): the one listed model whose default
                   path meets flash attention, ``bias_gelu`` and
                   ``layernorm_act`` together, compared with the suite's
-                  lax forms and with dense attention.
+                  lax forms and with dense attention;
+* ``hybrid``      the kernels of a decoder-hybrid-decoder at its widths
+                  (5,120 scan channels of 16 states; 40 heads of 64 in
+                  differential pairs on 20 K/V heads, under a 512-key
+                  window and the causal mask; 2,048 positions): the
+                  selective scan's two kernels against the sequential
+                  ``lax.scan``, the flash kernels' two maps a pair
+                  against dense attention, outputs and gradients, each
+                  side timed.
+
+``--phases a,b`` runs only the phases named.
 
 ``--chips 4`` runs ONLY the data-parallel ResNet-50 train path on a
 ``{"data": 4}`` mesh and the same steps on one of the four chips as its
@@ -90,6 +100,9 @@ WARMUP_ITERATIONS = 1000
 # pair to 5e-2 too).
 EPILOGUE_TOL = 2e-2
 ATTENTION_TOL = 5e-2
+# the scan's kernels and the sequential lax.scan are both float32 and
+# differ in the order of their sums over channels and positions
+SCAN_TOL = 1e-3
 # 4 chips and 1 chip run the same math in another reduction order
 # (bf16 convolutions): 1e-2 while the parameters are still the same,
 # 5e-2 after the 8 SGD steps that amplify it
@@ -592,6 +605,113 @@ def data_parallel(devices, *, depth: int = 50, classes: int = 1000,
     return rec
 
 
+# ----------------------------------------------------------------- hybrid
+def _timed(fn, *args):
+    """(result, seconds of the second call: the first compiles)."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args))
+    return out, time.perf_counter() - t0
+
+
+def hybrid(*, seq: int = 2048, channels: int = 5120, states: int = 16,
+           heads: int = 40, kv_heads: int = 20, head_dim: int = 64,
+           window: int = 512, seed: int = 0):
+    """The selective scan and differential flash attention on their
+    Pallas paths, forward and backward, against the lax scan and dense
+    attention at the same shapes."""
+    from analytics_zoo_tpu.ops.pallas_attention import (
+        allowed_pairs, flash_attention_token_major, sliding_window)
+    from analytics_zoo_tpu.ops.selective_scan import (
+        selective_scan, selective_scan_lax)
+    before = _counters()
+    keys = jax.random.split(jax.random.PRNGKey(seed), 12)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    x = jax.random.normal(keys[0], (1, seq, channels), f32)
+    dt = jax.nn.softplus(jax.random.normal(keys[1], x.shape, f32) - 3.0)
+    a = -jnp.broadcast_to(jnp.arange(1, states + 1, dtype=f32),
+                          (channels, states))
+    b, c = (jax.random.normal(k, (1, seq, states), f32) for k in keys[2:4])
+    state = jax.random.normal(keys[4], (1, channels, states), f32)
+    w_y = jax.random.normal(keys[5], x.shape, f32)
+
+    def scan_grads(scan):
+        def loss(x, dt, a, b, c, state, w_y):
+            y, last = scan(x, dt, a, b, c, state)
+            return jnp.sum(y * w_y) + jnp.sum(last), (y, last)
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)),
+                                          has_aux=True))
+
+    args = (x, dt, a, b, c, state, w_y)
+    ((_, got_out), got_g), scan_s = _timed(scan_grads(selective_scan), *args)
+    ((_, ref_out), ref_g), lax_s = _timed(scan_grads(selective_scan_lax),
+                                          *args)
+    names = ("y", "last", "dx", "ddt", "da", "db", "dc", "dstate")
+    scan_err = {n: _rel_err(g, r) for n, g, r in
+                zip(names, got_out + got_g, ref_out + ref_g)}
+
+    q = jax.random.normal(keys[6], (1, seq, heads * head_dim), f32)
+    k, v = (jax.random.normal(kk, (1, seq, kv_heads * head_dim), f32)
+            for kk in keys[7:9])
+    w_o = jax.random.normal(keys[9], (1, seq, 2 * heads * head_dim), f32)
+    q, k, v, w_o = (t.astype(bf16) for t in (q, k, v, w_o))
+    group = heads // kv_heads
+
+    def dense(mask):
+        ok = jnp.asarray(allowed_pairs(mask, seq))
+
+        def maps(q, k, v):
+            qp = q.reshape(1, seq, heads // 2, 2, head_dim)
+            kp = jnp.repeat(k.reshape(1, seq, kv_heads // 2, 2, head_dim),
+                            group, axis=2)
+            vp = jnp.repeat(v.reshape(1, seq, kv_heads // 2, 2 * head_dim),
+                            group, axis=2)
+            s = jnp.einsum("bqjrd,bkjrd->bjrqk", qp, kp,
+                           preferred_element_type=f32) * head_dim ** -0.5
+            p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1)
+            out = jnp.einsum("bjrqk,bkje->bqjre", p.astype(bf16), vp,
+                             preferred_element_type=f32)
+            return out.reshape(1, seq, -1).astype(bf16)
+        return maps
+
+    def flash(mask):
+        return lambda q, k, v: flash_attention_token_major(
+            q, k, v, n_head=heads, differential=True,
+            causal=mask == "causal", mask=None if mask == "causal" else mask)
+
+    def attn_grads(maps):
+        def loss(q, k, v, w_o):
+            out = maps(q, k, v)
+            return jnp.sum(out.astype(f32) * w_o.astype(f32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    attention = {}
+    seconds = {"selective_scan": scan_s, "selective_scan_lax": lax_s}
+    for name, mask in (("window", sliding_window(window)),
+                       ("causal", "causal")):
+        ((_, got_o), got_g), seconds["flash_" + name] = _timed(
+            attn_grads(flash(mask)), q, k, v, w_o)
+        ((_, ref_o), ref_g), seconds["dense_" + name] = _timed(
+            attn_grads(dense(mask)), q, k, v, w_o)
+        attention[name] = {n: _rel_err(g, r) for n, g, r in zip(
+            ("out", "dq", "dk", "dv"), (got_o,) + got_g, (ref_o,) + ref_g)}
+
+    builds = _delta(_counters(), before, "fused_kernel_builds_total")
+    rec = {"scan_vs_lax": scan_err, "differential_flash_vs_dense": attention,
+           "forward_backward_s": seconds, "kernel_builds": builds}
+    rec["checks"] = {
+        "selective_scan_pallas":
+            builds.get('{kernel="selective_scan",path="pallas"}', 0) > 0,
+        "scan_agrees_with_lax": all(e <= SCAN_TOL
+                                    for e in scan_err.values()),
+        "flash_agrees_with_dense": all(
+            e <= ATTENTION_TOL for d in attention.values()
+            for e in d.values()),
+    }
+    return rec
+
+
 # ------------------------------------------------------------------- main
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -599,7 +719,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="4 = only the data-parallel train path and "
                          "its one-chip comparison")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phases", default="train,serve,transformer,hybrid",
+                    help="one-chip phases to run (serve needs train)")
     args = ap.parse_args(argv)
+    phases = args.phases.split(",")
 
     devices = jax.devices()
     device = {"platform": devices[0].platform,
@@ -622,18 +745,22 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             oks.append(run_phase("data_parallel", data_parallel, devices,
                                  seed=args.seed)[0])
         else:
-            ok, model = run_phase("train", train, seed=args.seed,
-                                  workdir=workdir)
-            oks.append(ok)
-            if model is not None:
+            model = None
+            if "train" in phases:
+                ok, model = run_phase("train", train, seed=args.seed,
+                                      workdir=workdir)
+                oks.append(ok)
+            if "serve" in phases and model is not None:
                 oks.append(run_phase("serve", serve, model,
                                      seed=args.seed)[0])
-            else:
+            elif "serve" in phases:
                 _emit({"phase": "serve", "ok": False,
                        "error": "no trained model: train failed"})
                 oks.append(False)
-            oks.append(run_phase("transformer", transformer,
-                                 seed=args.seed)[0])
+            for name, fn in (("transformer", transformer),
+                             ("hybrid", hybrid)):
+                if name in phases:
+                    oks.append(run_phase(name, fn, seed=args.seed)[0])
     ok = all(oks)
     _emit({"ok": ok, "device": device})
     return 0 if ok else 1

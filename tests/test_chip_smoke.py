@@ -100,6 +100,19 @@ def test_transformer(one_chip, capsys):
     assert get_config().get("ops.fused") == "auto"
 
 
+def test_hybrid(one_chip, capsys):
+    ok, _, line = _phase(capsys, "hybrid", chip_smoke.hybrid, seq=256,
+                         channels=1024, states=4, heads=4, kv_heads=2,
+                         window=100)
+    assert ok, line
+    assert line["kernel_builds"] == {
+        '{kernel="selective_scan",path="pallas"}': 1.0}
+    assert set(line["differential_flash_vs_dense"]) == {"window", "causal"}
+    assert set(line["forward_backward_s"]) == {
+        "selective_scan", "selective_scan_lax", "flash_window",
+        "dense_window", "flash_causal", "dense_causal"}
+
+
 def test_data_parallel_on_four_virtual_devices(capsys):
     ok, _, line = _phase(capsys, "data_parallel", chip_smoke.data_parallel,
                          jax.devices()[:4], **TOY_RESNET)

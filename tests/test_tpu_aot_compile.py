@@ -24,7 +24,9 @@ from analytics_zoo_tpu.ops import fused
 from analytics_zoo_tpu.ops.grouped_matmul import (
     buffer_rows, group_layout, grouped_matmul)
 from analytics_zoo_tpu.ops.pallas_attention import (
-    block_diffusion, flash_attention, flash_attention_token_major)
+    block_diffusion, flash_attention, flash_attention_token_major,
+    sliding_window)
+from analytics_zoo_tpu.ops.selective_scan import selective_scan
 
 
 @pytest.fixture(scope="module")
@@ -85,6 +87,21 @@ def _flash_sdar_cell(q, k, v):
         block_k=512)
 
 
+def _flash_pair_window(q, k, v):
+    return flash_attention_token_major(
+        q, k, v, n_head=40, differential=True, mask=sliding_window(512))
+
+
+def _flash_pair_causal(qkv):
+    return flash_attention_token_major(
+        qkv, n_head=40, n_kv_head=20, differential=True, causal=True,
+        block_q=512, block_k=512)
+
+
+def _scan(x, dt, a, b, c):
+    return selective_scan(x, dt, a, b, c)[0]
+
+
 def _grad(fn, n_args):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
                     argnums=tuple(range(n_args)))
@@ -129,6 +146,21 @@ CASES = [
      [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 1),
     ("flash-sdar-cell-grad-t8192x4096", _grad(_flash_sdar_cell, 3),
      [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 3),
+    # the hybrid cell's: 40 heads of 64 in differential pairs on 20 K/V
+    # heads (two maps a pair over its 128-wide V), inside a 512-key
+    # window with K/V as operands of their own, and causal with q, k and
+    # v read out of the projection's result; the scan's two kernels over
+    # 5,120 channels of 16 states (float32)
+    ("flash-pair-window-grad-t8192x2560", _grad(_flash_pair_window, 3),
+     [(1, 8192, 2560), (1, 8192, 1280), (1, 8192, 1280)], BF16, 3),
+    ("flash-pair-causal-grad-t8192x5120", _grad(_flash_pair_causal, 1),
+     [(1, 8192, 5120)], BF16, 3),
+    ("selective-scan-t8192c5120n16", _scan,
+     [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16),
+      (1, 8192, 16)], F32, 1),
+    ("selective-scan-grad-t8192c5120n16", _grad(_scan, 5),
+     [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16),
+      (1, 8192, 16)], F32, 2),
 ]
 # every activation the LayerNorm epilogue claims to run in-kernel
 CASES += [
@@ -176,6 +208,8 @@ NAMED = {
     "layernorm_act-16384x768": ["layernorm_act"],
     "flash-grad-b2h12t512d64": ["flash_attention_fwd", "flash_attention_dq",
                                 "flash_attention_dkv"],
+    "selective-scan-grad-t8192c5120n16": ["selective_scan_fwd",
+                                          "selective_scan_bwd"],
 }
 
 
@@ -303,6 +337,60 @@ def test_attention_layers_hand_the_kernels_what_the_projections_wrote(
     moved = _relayouts(text, 8192, 8192 * 4 * 128)
     assert len(moved) <= 4, moved
     assert not [m for m in moved if m.startswith("transpose")], moved
+
+
+# (mixer, what the layer reads besides the stream, kernels in its
+# gradient: each forward kernel twice, the layer being recomputed)
+def _hybrid_blocks():
+    from analytics_zoo_tpu.pipeline.api.keras.layers import ssm
+    attention = dict(n_head=40, n_kv_head=20, head_dim=64)
+    kv, memory = (1, 8192, 1280), (1, 8192, 5120)
+    return {
+        "mamba": (ssm.Mamba(5120, 16, 4, 160, emit_memory=True), [], 3),
+        "window_attention": (ssm.DifferentialAttention(
+            layer_index=1, mask=sliding_window(512), **attention), [], 4),
+        "full_attention": (ssm.DifferentialAttention(
+            layer_index=17, emit_kv=True, **attention), [], 4),
+        "memory_unit": (ssm.GatedMemoryUnit(), [memory], 0),
+        "cross_attention": (ssm.DifferentialAttention(
+            layer_index=19, cross=True, **attention), [kv, kv], 4),
+    }
+
+
+@pytest.mark.parametrize("kind", ["mamba", "window_attention",
+                                  "full_attention", "memory_unit",
+                                  "cross_attention"])
+def test_hybrid_decoder_layers_compile_for_v5e(v5e, one_chip_routing, on_tpu,
+                                               kind):
+    """One recomputed decoder layer of each of the hybrid cell's five
+    kinds at its published widths and 8,192 positions, forward and
+    backward: Mosaic takes the scan's kernels and the differential pair
+    under the window and the causal mask, K/V its own or another
+    layer's, and the layers reach them (no lax form in the program)."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import ssm
+    mixer, reads, kernels = _hybrid_blocks()[kind]
+    layer = ssm.HybridDecoderLayer(mixer, ssm.GatedFeedForward(10240),
+                                   recompute=True)
+    stream = (1, 8192, 2560)
+    shapes = [stream, *reads] if reads else stream
+    params = jax.eval_shape(
+        lambda: layer.build(jax.random.PRNGKey(0), shapes))
+
+    def loss(params, h, *extra):
+        out = layer.call(params, [h, *extra] if extra else h)
+        outs = out if isinstance(out, list) else [out]
+        return sum(jnp.sum(jnp.square(o.astype(F32))) for o in outs)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    # the gradients of what the layer reads too: the writer's share
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(2 + len(reads))))
+                   ).lower(
+        jax.tree.map(lambda a: shaped(a.shape, a.dtype), params),
+        shaped(stream, F32), *(shaped(r, BF16) for r in reads)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
 
 
 def test_capability_probe_compiles_for_v5e(v5e):
