@@ -46,12 +46,16 @@ cached with it (docs/aot-compile.md).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -146,6 +150,32 @@ def count_build(kernel: str, path: str) -> None:
         "fused_kernel_builds_total",
         "fused-suite kernels built into traced programs",
         labels=("kernel", "path")).labels(kernel, path).inc()
+
+
+# The record of the recomputed layer being traced, if any.
+_kept_results = contextvars.ContextVar("kept_results", default=None)
+
+
+@contextlib.contextmanager
+def recording_kept_results():
+    """While the body of a recomputed layer is traced: -> the dict that
+    ``keep_result`` fills with the bytes under each name."""
+    kept: dict = {}
+    token = _kept_results.set(kept)
+    try:
+        yield kept
+    finally:
+        _kept_results.reset(token)
+
+
+def keep_result(value, name: str):
+    """A kernel's result under the name a recomputed layer's policy
+    keeps it by (``jax.checkpoint(..., policy=save_only_these_names)``);
+    the identity under no such policy."""
+    kept = _kept_results.get()
+    if kept is not None:
+        kept[name] = kept.get(name, 0) + value.size * value.dtype.itemsize
+    return checkpoint_name(value, name)
 
 
 # Half of the 16 MiB scoped-VMEM limit the v5e compiler enforces on one

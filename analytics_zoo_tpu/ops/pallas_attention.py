@@ -68,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.compile.engine import engine_jit
+from analytics_zoo_tpu.ops.fused import keep_result
 
 NEG = -1e30
 # flags of one (q tile, k tile) pair in a kernel's walk
@@ -619,18 +620,44 @@ _forward = engine_jit(_flash_fwd_impl, static_argnums=(1,),
                       key_hint="flash_attention_forward")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+# What a recomputed layer keeps of a call (``fused.keep_result``): the
+# forward kernel's two results, all its backward kernels read of it.
+KEPT_RESULTS = ("flash_attention_out", "flash_attention_lse")
+
+
 def _flash(ops, cfg):
     """The token-major core.  ``ops``: ``(q, k, v)`` as (B, T, H·D) and
     (B, T, H_kv·D) arrays, or ``(qkv,)``, the three side by side in the
-    last dimension of one; -> (B, T, H·D)."""
-    out, _ = _forward(ops, cfg)
+    last dimension of one; -> (B, T, H·D).
+
+    The forward kernel is an ordinary call, outside the ``custom_vjp``
+    and on operands cut off from differentiation, and its results carry
+    ``KEPT_RESULTS``' names: a ``jax.checkpoint`` whose policy saves
+    those names keeps them and runs the forward kernel once, where a
+    forward rule inside the ``custom_vjp`` is run again with everything
+    else (a name given inside a forward rule is not kept: JAX 0.9).
+    Under no such policy a name is the identity.  ``_attach`` hangs the
+    backward kernels on the result."""
+    out, lse = map(keep_result, _forward(jax.lax.stop_gradient(ops), cfg),
+                   KEPT_RESULTS)
+    return _attach(ops, out, lse, cfg)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attach(ops, out, lse, cfg):
+    """``out``, the forward kernel's result on ``ops``, as a function of
+    ``ops``: the backward kernels on ``(ops, out, lse)`` give ``ops``
+    its cotangent; ``out`` and ``lse`` themselves get none (they were
+    formed from stopped operands, so none could flow on)."""
     return out
 
 
-def _flash_vjp_fwd(ops, cfg):
-    out, lse = _forward(ops, cfg)
+def _attach_fwd(ops, out, lse, cfg):
     return out, (ops, out, lse)
+
+
+def _attach_bwd(cfg, res, dout):
+    return (*_backward(res, dout, cfg), None, None)
 
 
 def _side_by_side(parts):
@@ -724,8 +751,7 @@ def _flash_bwd_impl(res, dout, cfg):
 
 _backward = engine_jit(_flash_bwd_impl, static_argnums=(2,),
                        key_hint="flash_attention_backward")
-_flash.defvjp(_flash_vjp_fwd,
-              lambda cfg, res, dout: _backward(res, dout, cfg))
+_attach.defvjp(_attach_fwd, _attach_bwd)
 
 
 def flash_attention_token_major(q, k=None, v=None, *, n_head: int,

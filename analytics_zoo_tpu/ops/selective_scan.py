@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.compile.engine import engine_jit
+from analytics_zoo_tpu.ops import fused
 
 _LANES, _SUBLANES = 128, 8
 _GROUP = _LANES * _SUBLANES
@@ -298,24 +299,46 @@ _backward = engine_jit(_bwd_impl, static_argnums=(8,),
                        key_hint="selective_scan_backward")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+# What a recomputed layer keeps of a call (``fused.keep_result``): ``y``
+# for whatever reads the scan, the chunk-start states for the backward
+# kernel, and the last state (``N`` registers a channel group), without
+# which the layer's second pass would run the forward kernel for it.
+KEPT_RESULTS = ("selective_scan_y", "selective_scan_starts",
+                "selective_scan_last")
+
+
 def _scan(x, dt, a, b, c, state, interpret):
-    y, _, last = _forward(x, dt, a, b, c, state, interpret)
+    """The kernels' form of the scan.  As flash attention's ``_flash``:
+    the forward kernel is an ordinary call on operands cut off from
+    differentiation and its results carry ``KEPT_RESULTS``' names, so
+    that a recomputed layer whose policy saves them runs the forward
+    kernel once; ``_attach`` hangs the backward kernel on the results."""
+    results = _forward(
+        *jax.lax.stop_gradient((x, dt, a, b, c, state)), interpret)
+    return _attach(x, dt, a, b, c, state,
+                   *map(fused.keep_result, results, KEPT_RESULTS), interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _attach(x, dt, a, b, c, state, y, starts, last, interpret):
+    """``(y, the last state)``, the forward kernel's results in its own
+    layout, as functions of the scan's six operands: the backward kernel
+    on ``(x, dt, a, b, c, starts)`` gives those their cotangents, from
+    ``y``'s AND the last state's; the forward's results get none."""
     return y.reshape(x.shape), _state_from_kernel(last)
 
 
-def _scan_fwd(x, dt, a, b, c, state, interpret):
-    y, starts, last = _forward(x, dt, a, b, c, state, interpret)
-    return ((y.reshape(x.shape), _state_from_kernel(last)),
+def _attach_fwd(x, dt, a, b, c, state, y, starts, last, interpret):
+    return (_attach(x, dt, a, b, c, state, y, starts, last, interpret),
             (x, dt, a, b, c, starts))
 
 
-def _scan_bwd(interpret, res, cot):
+def _attach_bwd(interpret, res, cot):
     dy, dlast = cot
-    return _backward(*res, dy, dlast, interpret)
+    return (*_backward(*res, dy, dlast, interpret), None, None, None)
 
 
-_scan.defvjp(_scan_fwd, _scan_bwd)
+_attach.defvjp(_attach_fwd, _attach_bwd)
 
 
 def pallas_fits(t: int, channels: int) -> bool:
@@ -331,7 +354,6 @@ def selective_scan(x, dt, a, b, c, state: Optional[jax.Array] = None,
     ``state`` (B, C, N) or None (zeros) -> (``y`` (B, T, C), the last
     state (B, C, N)), float32 (see the module's docstring for the
     recurrence).  Differentiable in everything it takes."""
-    from analytics_zoo_tpu.ops import fused
     bsz, t, ch = x.shape
     if (interpret or fused._use_pallas()) and pallas_fits(t, ch):
         fused.count_build("selective_scan", "pallas")

@@ -319,13 +319,22 @@ class HybridDecoderLayer(Layer):
     ``recompute``: the layer's internals are not kept for the backward
     pass but computed again in it (``jax.checkpoint`` round the layer),
     so that a deep model holds one layer's internals at a time beside
-    every layer's input."""
+    every layer's input.  EXCEPT what its Pallas kernels wrote, which
+    the checkpoint's policy keeps by name (``KEPT_RESULTS`` of
+    ``ops/pallas_attention.py`` and ``ops/selective_scan.py``): a kept
+    byte of attention's output saves five times the device time a kept
+    byte of the MLP's would (docs/hybrid-decoder-layers.md), and the
+    forward kernels then run once a step.  The gauge
+    ``train_recompute_kept_bytes{name}`` holds the bytes so kept, over
+    every recomputed layer traced."""
 
     def __init__(self, mixer: Layer, ffn: Layer, epsilon: float = 1e-5,
                  recompute: bool = False, **kwargs):
         super().__init__(**kwargs)
         self.mixer, self.ffn = mixer, ffn
         self.epsilon, self.recompute = float(epsilon), bool(recompute)
+        # this layer's share of train_recompute_kept_bytes, by name
+        self._kept_bytes: dict = {}
 
     @staticmethod
     def _stream(input_shape):
@@ -370,9 +379,30 @@ class HybridDecoderLayer(Layer):
 
     def call(self, params, inputs, training=False, rng=None):
         args = inputs if isinstance(inputs, (list, tuple)) else [inputs]
-        body = jax.checkpoint(self._body) if self.recompute else self._body
-        out = body(params, *args)
+        if self.recompute:
+            from analytics_zoo_tpu.ops import (
+                fused, pallas_attention, selective_scan)
+            keep = jax.checkpoint_policies.save_only_these_names(
+                *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS)
+            with fused.recording_kept_results() as kept:
+                out = jax.checkpoint(self._body, policy=keep)(params, *args)
+            self._gauge_kept(kept)
+        else:
+            out = self._body(params, *args)
         return list(out) if len(out) > 1 else out[0]
+
+    def _gauge_kept(self, kept: dict) -> None:
+        """Moves the gauge by what this trace of the layer keeps more
+        (or less) than its last: tracing a layer again adds nothing."""
+        from analytics_zoo_tpu.observability import get_registry
+        gauge = get_registry().gauge(
+            "train_recompute_kept_bytes",
+            "bytes of kernel results that recomputed layers keep for the "
+            "backward pass", labels=("name",))
+        for name in kept.keys() | self._kept_bytes.keys():
+            gauge.labels(name).inc(
+                kept.get(name, 0) - self._kept_bytes.get(name, 0))
+        self._kept_bytes = kept
 
 
 class NextTokenLoss(Layer):

@@ -614,16 +614,17 @@ def _timed(fn, *args):
     return out, time.perf_counter() - t0
 
 
-def hybrid(*, seq: int = 2048, channels: int = 5120, states: int = 16,
-           heads: int = 40, kv_heads: int = 20, head_dim: int = 64,
-           window: int = 512, seed: int = 0):
+def hybrid(*, seq: int = 2048, hidden: int = 2560, channels: int = 5120,
+           states: int = 16, heads: int = 40, kv_heads: int = 20,
+           head_dim: int = 64, window: int = 512, seed: int = 0):
     """The selective scan and differential flash attention on their
     Pallas paths, forward and backward, against the lax scan and dense
-    attention at the same shapes."""
+    attention at the same shapes; then a recomputed decoder layer round
+    each, which keeps the kernels' results."""
+    from analytics_zoo_tpu.ops import pallas_attention, selective_scan
     from analytics_zoo_tpu.ops.pallas_attention import (
         allowed_pairs, flash_attention_token_major, sliding_window)
-    from analytics_zoo_tpu.ops.selective_scan import (
-        selective_scan, selective_scan_lax)
+    from analytics_zoo_tpu.ops.selective_scan import selective_scan_lax
     before = _counters()
     keys = jax.random.split(jax.random.PRNGKey(seed), 12)
     f32, bf16 = jnp.float32, jnp.bfloat16
@@ -643,7 +644,8 @@ def hybrid(*, seq: int = 2048, channels: int = 5120, states: int = 16,
                                           has_aux=True))
 
     args = (x, dt, a, b, c, state, w_y)
-    ((_, got_out), got_g), scan_s = _timed(scan_grads(selective_scan), *args)
+    ((_, got_out), got_g), scan_s = _timed(
+        scan_grads(selective_scan.selective_scan), *args)
     ((_, ref_out), ref_g), lax_s = _timed(scan_grads(selective_scan_lax),
                                           *args)
     names = ("y", "last", "dx", "ddt", "da", "db", "dc", "dstate")
@@ -698,9 +700,20 @@ def hybrid(*, seq: int = 2048, channels: int = 5120, states: int = 16,
             ("out", "dq", "dk", "dv"), (got_o,) + got_g, (ref_o,) + ref_g)}
 
     builds = _delta(_counters(), before, "fused_kernel_builds_total")
+    kept, forward_calls = _recomputed_layers(
+        seq, hidden, channels, states, heads, kv_heads, head_dim, window,
+        seconds)
     rec = {"scan_vs_lax": scan_err, "differential_flash_vs_dense": attention,
-           "forward_backward_s": seconds, "kernel_builds": builds}
+           "forward_backward_s": seconds, "kernel_builds": builds,
+           "train_recompute_kept_bytes": kept,
+           "forward_kernels_in_recomputed_gradient": forward_calls}
     rec["checks"] = {
+        # a recomputed layer keeps what its kernels wrote and runs each
+        # forward kernel once
+        "recomputed_layers_keep_kernel_results":
+            set(forward_calls.values()) == {1} and all(
+                kept.get('{name="%s"}' % n, 0) > 0 for n in
+                pallas_attention.KEPT_RESULTS + selective_scan.KEPT_RESULTS),
         "selective_scan_pallas":
             builds.get('{kernel="selective_scan",path="pallas"}', 0) > 0,
         "scan_agrees_with_lax": all(e <= SCAN_TOL
@@ -710,6 +723,50 @@ def hybrid(*, seq: int = 2048, channels: int = 5120, states: int = 16,
             for e in d.values()),
     }
     return rec
+
+
+def _pallas_calls(jaxpr, name: str) -> int:
+    """``pallas_call`` equations named ``name`` in ``jaxpr``, the
+    jaxprs its equations hold included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call" \
+            and eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub, name)
+    return n
+
+
+def _recomputed_layers(seq, hidden, channels, states, heads, kv_heads,
+                       head_dim, window, seconds):
+    """One recomputed decoder layer round the scan and one round the
+    windowed pair, forward and backward (timed into ``seconds``): -> (the
+    gauge ``train_recompute_kept_bytes`` by name, how often each layer's
+    forward kernel stands in its gradient)."""
+    from analytics_zoo_tpu.ops.pallas_attention import sliding_window
+    from analytics_zoo_tpu.pipeline.api.keras.layers import ssm
+    mixers = {
+        "selective_scan_fwd": ssm.Mamba(channels, states),
+        "flash_attention_fwd": ssm.DifferentialAttention(
+            heads, kv_heads, head_dim, 1, mask=sliding_window(window))}
+    forward_calls = {}
+    for kernel, mixer in mixers.items():
+        layer = ssm.HybridDecoderLayer(mixer, ssm.GatedFeedForward(4 * hidden),
+                                       recompute=True)
+        shape = (1, seq, hidden)
+        params = layer.build(jax.random.PRNGKey(0), shape)
+        h = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+
+        def loss(params, h, layer=layer):
+            return jnp.sum(jnp.square(layer.call(params, h)))
+
+        grads = jax.grad(loss, argnums=(0, 1))
+        forward_calls[kernel] = _pallas_calls(
+            jax.make_jaxpr(grads)(params, h).jaxpr, kernel)
+        _, seconds["recomputed_layer_" + kernel] = _timed(
+            jax.jit(grads), params, h)
+    return (_delta(get_registry().snapshot()["gauges"], {},
+                   "train_recompute_kept_bytes"), forward_calls)
 
 
 # ------------------------------------------------------------------- main

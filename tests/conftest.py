@@ -89,6 +89,28 @@ def f32_policy():
 
 
 @pytest.fixture
+def pallas_calls():
+    """-> ``count(fn, *args)``: how often each ``pallas_call``, by its
+    ``name=``, stands in the jaxpr of ``fn(*args)``, the jaxprs its
+    equations hold included."""
+    import collections
+    import jax
+
+    def count(fn, *args):
+        found = collections.Counter()
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found[eqn.params["name"]] += 1
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    walk(inner)
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return dict(found)
+    return count
+
+
+@pytest.fixture
 def one_chip_routing(monkeypatch):
     """The layers' routing as on one TPU chip: a one-device context mesh
     (the suite's default is 8-way data parallel) and the kernel suite's

@@ -102,15 +102,24 @@ def test_transformer(one_chip, capsys):
 
 def test_hybrid(one_chip, capsys):
     ok, _, line = _phase(capsys, "hybrid", chip_smoke.hybrid, seq=256,
-                         channels=1024, states=4, heads=4, kv_heads=2,
-                         window=100)
+                         hidden=64, channels=1024, states=4, heads=4,
+                         kv_heads=2, window=100)
     assert ok, line
     assert line["kernel_builds"] == {
         '{kernel="selective_scan",path="pallas"}': 1.0}
     assert set(line["differential_flash_vs_dense"]) == {"window", "causal"}
     assert set(line["forward_backward_s"]) == {
         "selective_scan", "selective_scan_lax", "flash_window",
-        "dense_window", "flash_causal", "dense_causal"}
+        "dense_window", "flash_causal", "dense_causal",
+        "recomputed_layer_selective_scan_fwd",
+        "recomputed_layer_flash_attention_fwd"}
+    # the recomputed layers keep what their kernels wrote: the maps'
+    # (1, 256, 4 * 128) bfloat16 output, the scan's float32 y (1, 256, 1024)
+    assert line["forward_kernels_in_recomputed_gradient"] == {
+        "selective_scan_fwd": 1, "flash_attention_fwd": 1}
+    kept = line["train_recompute_kept_bytes"]
+    assert kept['{name="flash_attention_out"}'] >= 256 * 512 * 2
+    assert kept['{name="selective_scan_y"}'] >= 256 * 1024 * 4
 
 
 def test_data_parallel_on_four_virtual_devices(capsys):

@@ -21,6 +21,8 @@ if REPO_ROOT not in sys.path:
 
 from benchmark import harness  # noqa: E402
 
+from analytics_zoo_tpu.ops import (  # noqa: E402
+    fused, pallas_attention, selective_scan)
 from analytics_zoo_tpu.ops.pallas_attention import (  # noqa: E402
     _PARTIAL, _tile_pairs, allowed_pairs, flash_attention_token_major,
     sliding_window)
@@ -34,6 +36,9 @@ TOY = dict(seq_len=96, hidden_size=64, intermediate_size=96,
            sliding_window=24, vocab_size=97, vocab_held=[0, 97],
            vocab_size_published=776, initializer_range=0.2,
            recompute=dict(decoder_layers=True, loss_chunk_rows=32))
+# the policy of a recomputed layer
+KEEP_KERNEL_RESULTS = jax.checkpoint_policies.save_only_these_names(
+    *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS)
 # a layer of each kind, with the layer that writes what it reads
 LAYERS = {"mamba": [0], "window_attention": [1],
           "mamba_memory+memory_unit": [16, 18],
@@ -223,18 +228,24 @@ def test_the_pair_needs_heads_that_fill_a_tile():
             interpret=True)
 
 
-def test_shared_kv_cotangent_is_the_sum_over_its_readers():
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["kept", "recomputed"])
+def test_shared_kv_cotangent_is_the_sum_over_its_readers(recompute):
     """Two cross-attention readers of one K/V (each with its own
     queries): the cotangent that reaches K and V is the sum of what each
-    reader alone sends back."""
+    reader alone sends back, also when each reader is recomputed under
+    the policy that keeps the kernels' results."""
     h, h_kv, d, t = 4, 2, 64, 256
     q1, k, v, w1 = pair_inputs(1, t, h, h_kv, d, seed=2)
     q2, _, _, w2 = pair_inputs(1, t, h, h_kv, d, seed=3)
 
     def reader(q, w):
-        return lambda k, v: jnp.sum(w * flash_attention_token_major(
-            q, k, v, n_head=h, differential=True, causal=True,
-            interpret=True))
+        def read(k, v):
+            return jnp.sum(w * flash_attention_token_major(
+                q, k, v, n_head=h, differential=True, causal=True,
+                interpret=True))
+        return jax.checkpoint(read, policy=KEEP_KERNEL_RESULTS) \
+            if recompute else read
 
     both = jax.grad(lambda k, v: reader(q1, w1)(k, v) + reader(q2, w2)(k, v),
                     (0, 1))(k, v)
@@ -345,3 +356,136 @@ def test_recomputed_layers_give_the_same_gradients(f32_policy, reference):
     for name in kept[1]:
         np.testing.assert_allclose(kept[1][name], again[1][name], rtol=1e-4,
                                    atol=1e-7, err_msg=name)
+
+
+# ------------------------- a recomputed layer keeps what its kernels wrote
+@pytest.fixture
+def interpreted_kernels(one_chip_routing, monkeypatch):
+    """The layers on their kernels as on one TPU chip, every kernel
+    interpreted."""
+    from jax.experimental import pallas as pl
+    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
+    compiled_call = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **kw: compiled_call(*a, **{**kw, "interpret": True}))
+
+
+# (mixer, what the layer reads besides the stream, its forward kernel,
+# bytes kept by name at the toy's sizes: 256 positions, 4 heads in pairs
+# on 2 K/V heads of 64, 1,024 channels of 4 states, float32)
+def kernel_layers():
+    attention = dict(n_head=4, n_kv_head=2, head_dim=64)
+    maps = {"flash_attention_out": 256 * 4 * 128 * 4,
+            "flash_attention_lse": 4 * 256 * 4}
+    return {
+        "mamba": (ssm.Mamba(1024, 4, 4, 4), [], "selective_scan_fwd",
+                  {"selective_scan_y": 256 * 1024 * 4,
+                   "selective_scan_starts": 4 * 4 * 1024 * 4,
+                   "selective_scan_last": 4 * 1024 * 4}),
+        "window_attention": (ssm.DifferentialAttention(
+            layer_index=1, mask=sliding_window(100), **attention), [],
+            "flash_attention_fwd", maps),
+        "cross_attention": (ssm.DifferentialAttention(
+            layer_index=19, cross=True, **attention),
+            [(1, 256, 128)] * 2, "flash_attention_fwd", maps),
+    }
+
+
+def layer_gradients(kind, recompute):
+    """-> (the gradient function of a toy layer of ``kind``, its
+    arguments, its forward kernel, the bytes it keeps by name)."""
+    Layer.reset_name_counters()
+    mixer, reads, kernel, kept = kernel_layers()[kind]
+    layer = ssm.HybridDecoderLayer(mixer, ssm.GatedFeedForward(96),
+                                   recompute=recompute)
+    stream = (1, 256, 64)
+    params = layer.build(jax.random.PRNGKey(0),
+                         [stream, *reads] if reads else stream)
+    keys = jax.random.split(jax.random.PRNGKey(1), 1 + len(reads))
+    inputs = [jax.random.normal(k, shape)
+              for k, shape in zip(keys, [stream, *reads])]
+
+    def loss(params, h, *extra):
+        return jnp.sum(jnp.square(
+            layer.call(params, [h, *extra] if extra else h)))
+
+    # what the layer reads besides the stream gets its gradient too
+    grads = jax.grad(loss, argnums=tuple(range(1 + len(inputs))))
+    return grads, (params, *inputs), kernel, kept
+
+
+def kept_bytes():
+    from analytics_zoo_tpu.observability import get_registry
+    prefix = "train_recompute_kept_bytes"
+    return {k[len(prefix):]: v
+            for k, v in get_registry().snapshot()["gauges"].items()
+            if k.startswith(prefix)}
+
+
+@pytest.mark.parametrize("kind", sorted(kernel_layers()))
+def test_a_recomputed_layer_runs_its_forward_kernel_once(
+        f32_policy, interpreted_kernels, pallas_calls, kind):
+    """The gradient of a recomputed layer holds its forward kernel once,
+    as an unrecomputed layer's does (a bare ``jax.checkpoint`` would run
+    a ``custom_vjp``'s forward rule again with everything else); the
+    gauge moves by the bytes of the named values, once however often the
+    layer is traced, and not at all for a layer that keeps everything."""
+    before = kept_bytes()
+    kept_all, args, kernel, _ = layer_gradients(kind, recompute=False)
+    once = pallas_calls(kept_all, *args)
+    assert once[kernel] == 1 and kept_bytes() == before
+
+    grads, args, kernel, kept = layer_gradients(kind, recompute=True)
+    assert pallas_calls(grads, *args) == once
+    pallas_calls(grads, *args)
+    moved = {k: v - before.get(k, 0) for k, v in kept_bytes().items()}
+    assert {k: v for k, v in moved.items() if v} == {
+        '{name="%s"}' % name: n for name, n in kept.items()}
+
+
+@pytest.mark.parametrize("kind", sorted(kernel_layers()))
+def test_a_recomputed_layers_gradients_are_the_kept_layers(
+        f32_policy, interpreted_kernels, kind):
+    """Exactly, in float32: the kept values are what a second run of the
+    kernels would have formed again."""
+    grads, args, _, _ = layer_gradients(kind, recompute=True)
+    again = grads(*args)
+    grads, args, _, _ = layer_gradients(kind, recompute=False)
+    kept = grads(*args)
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(kept)):
+        assert float(jnp.max(jnp.abs(b))) > 0
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_last_states_cotangent_survives_recomputation(pallas_calls):
+    """A loss on the carried-out state alone, inside a checkpoint that
+    keeps the kernels' results: its cotangent reaches x, dt, A, B, C and
+    the carried-in state as the sequential scan's does, and the forward
+    kernel stands once in the gradient."""
+    k = jax.random.split(jax.random.PRNGKey(4), 7)
+    x = jax.random.normal(k[0], (1, 128, 1024))
+    dt = jax.nn.softplus(jax.random.normal(k[1], x.shape) - 2.0)
+    a = -jnp.exp(0.5 * jax.random.normal(k[2], (1024, 4)))
+    b, c = (jax.random.normal(kk, (1, 128, 4)) for kk in k[3:5])
+    state = jax.random.normal(k[5], (1, 1024, 4))
+    w = jax.random.normal(k[6], state.shape)
+
+    def on_last(scan):
+        return lambda *args: jnp.sum(scan(*args)[1] * w)
+
+    kernels = jax.checkpoint(
+        on_last(lambda *args: selective_scan.selective_scan(
+            *args, interpret=True)), policy=KEEP_KERNEL_RESULTS)
+    every = tuple(range(6))
+    args = (x, dt, a, b, c, state)
+    assert pallas_calls(jax.grad(kernels, every), *args) == {
+        "selective_scan_fwd": 1, "selective_scan_bwd": 1}
+    got = jax.grad(kernels, every)(*args)
+    want = jax.grad(on_last(selective_scan.selective_scan_lax), every)(*args)
+    for name, g, r in zip("x dt a b c state".split(), got, want):
+        # C does not reach the last state
+        assert (float(jnp.max(jnp.abs(r))) > 0) == (name != "c"), name
+        np.testing.assert_allclose(
+            g, r, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(r))),
+            err_msg=name)

@@ -263,6 +263,41 @@ def test_token_major_core_matches_dense(heads, dtype, mask):
         np.testing.assert_allclose(g, r, **tol)
 
 
+@pytest.mark.parametrize("heads", ["grouped-d128", "grouped-d128-fused"])
+def test_forward_call_outside_the_vjp_cuts_no_cotangent(heads, pallas_calls):
+    """The forward kernel is an ordinary call on stopped operands and
+    the ``custom_vjp`` only attaches the backward kernels: outside any
+    ``jax.checkpoint`` the gradient's jaxpr holds each kernel once, and
+    every operand's gradient is dense attention's, the K/V of a grouped
+    head included (q, k and v apart, or side by side in one array)."""
+    h, h_kv, d, fused = HEADS[heads]
+    b, t = 1, 256
+    rs = np.random.RandomState(9)
+    ops = [jnp.asarray(rs.randn(b, t, n * d) * 0.5, jnp.float32)
+           for n in (h, h_kv, h_kv)]
+    w = jnp.asarray(rs.randn(b, t, h * d), jnp.float32)
+
+    def flash(q, k, v):
+        ops = (jnp.concatenate([q, k, v], -1),) if fused else (q, k, v)
+        return jnp.sum(w * flash_attention_token_major(
+            *ops, n_head=h, n_kv_head=h_kv, causal=True, block_q=128,
+            block_k=128, interpret=True))
+
+    def dense(q, k, v):
+        out = _dense(_head_major(q, h, d), _head_major(k, h_kv, d),
+                     _head_major(v, h_kv, d), allowed_pairs("causal", t))
+        return jnp.sum(w * jnp.moveaxis(out, 1, 2).reshape(b, t, h * d))
+
+    assert pallas_calls(jax.grad(flash, (0, 1, 2)), *ops) == {
+        "flash_attention_fwd": 1, "flash_attention_dq": 1,
+        "flash_attention_dkv": 1}
+    for name, got, want in zip("qkv", jax.grad(flash, (0, 1, 2))(*ops),
+                               jax.grad(dense, (0, 1, 2))(*ops)):
+        assert float(jnp.max(jnp.abs(want))) > 0
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-5,
+                                   err_msg=name)
+
+
 @pytest.mark.parametrize("heads", ["two-to-a-tile", "grouped-d128"])
 def test_head_major_wrapper_is_the_core(heads):
     """``flash_attention`` over (B, H, T, D) is the core round two

@@ -69,6 +69,25 @@ def test_kernel_gradients_match_the_sequential_scan(name):
                                atol=2e-5 * float(jnp.max(jnp.abs(want))))
 
 
+def test_forward_call_outside_the_vjp_cuts_no_cotangent(pallas_calls):
+    """The forward kernel is an ordinary call on stopped operands and
+    the ``custom_vjp`` only attaches the backward kernel: outside any
+    ``jax.checkpoint`` the gradient's jaxpr holds each kernel once, and
+    all six operands' gradients, the carried-in state's included, are
+    the sequential scan's, from ``y`` and from the last state alike."""
+    args, weights = inputs(batch=1, t=128, channels=1024)
+    every = tuple(range(6))
+    kernels = jax.grad(weighted(FORMS["pallas"], weights), every)
+    assert pallas_calls(kernels, *args) == {"selective_scan_fwd": 1,
+                                            "selective_scan_bwd": 1}
+    want = jax.grad(weighted(FORMS["lax"], weights), every)(*args)
+    for name, g, w in zip(ARGS, kernels(*args), want):
+        assert float(jnp.max(jnp.abs(w))) > 0
+        np.testing.assert_allclose(
+            g, w, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(w))),
+            err_msg=name)
+
+
 def test_lax_gradient_matches_the_recurrence():
     args, weights = inputs(batch=1, t=128, channels=128, states=3)
     got = jax.grad(weighted(ss.selective_scan_lax, weights),

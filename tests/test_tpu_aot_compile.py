@@ -340,20 +340,21 @@ def test_attention_layers_hand_the_kernels_what_the_projections_wrote(
 
 
 # (mixer, what the layer reads besides the stream, kernels in its
-# gradient: each forward kernel twice, the layer being recomputed)
+# gradient: each forward kernel ONCE though the layer is recomputed,
+# because the layer's policy keeps the kernels' results by name)
 def _hybrid_blocks():
     from analytics_zoo_tpu.pipeline.api.keras.layers import ssm
     attention = dict(n_head=40, n_kv_head=20, head_dim=64)
     kv, memory = (1, 8192, 1280), (1, 8192, 5120)
     return {
-        "mamba": (ssm.Mamba(5120, 16, 4, 160, emit_memory=True), [], 3),
+        "mamba": (ssm.Mamba(5120, 16, 4, 160, emit_memory=True), [], 2),
         "window_attention": (ssm.DifferentialAttention(
-            layer_index=1, mask=sliding_window(512), **attention), [], 4),
+            layer_index=1, mask=sliding_window(512), **attention), [], 3),
         "full_attention": (ssm.DifferentialAttention(
-            layer_index=17, emit_kv=True, **attention), [], 4),
+            layer_index=17, emit_kv=True, **attention), [], 3),
         "memory_unit": (ssm.GatedMemoryUnit(), [memory], 0),
         "cross_attention": (ssm.DifferentialAttention(
-            layer_index=19, cross=True, **attention), [kv, kv], 4),
+            layer_index=19, cross=True, **attention), [kv, kv], 3),
     }
 
 
