@@ -33,10 +33,10 @@ class MoeStatsReader:
     """The expert layers of one model and the counts last read."""
 
     def __init__(self, model, state):
-        from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
-            DroplessMoE)
+        # the layers whose state holds the counts: a ``DroplessMoE``, or
+        # a decoder layer that holds one and carries its state
         self.layers = [l.name for l in getattr(model, "layers", ())
-                       if isinstance(l, DroplessMoE)]
+                       if STATE_KEY in (state.get(l.name) or {})]
         self._last: Dict[str, np.ndarray] = {}
         if not self.layers:
             return

@@ -65,6 +65,9 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
     DifferentialAttention, GatedFeedForward, GatedMemoryUnit,
     HybridDecoderLayer, Mamba, NextTokenLoss,
 )
+from analytics_zoo_tpu.pipeline.api.keras.layers.latent import (
+    LatentAttention, LatentDecoderLayer,
+)
 
 # Keras-2 style aliases
 Conv1D = Convolution1D
@@ -100,6 +103,7 @@ __all__ = [
     "SpaceToDepth2D", "MoE", "DroplessMoE",
     "DifferentialAttention", "GatedFeedForward", "GatedMemoryUnit",
     "HybridDecoderLayer", "Mamba", "NextTokenLoss",
+    "LatentAttention", "LatentDecoderLayer",
     "AddConstant", "BinaryThreshold", "CAdd", "CMul", "Exp",
     "GaussianSampler", "HardShrink", "HardTanh", "Identity", "Log",
     "LRN2D", "Mul", "MulConstant", "Negative", "Power",

@@ -22,7 +22,11 @@ by ``aux_loss()`` after a forward — add it to the objective via
 (SiLU) experts, no capacity and no dropped token, rows sorted by expert
 and multiplied by ``ops.grouped_matmul``; told which experts it holds
 (``experts_held``), it computes their part of the sum only — what one
-rank of an expert-parallel group runs.
+rank of an expert-parallel group runs.  Its router has two forms: the
+softmax over all experts with the ``top_k`` largest probabilities, and
+(``scoring="sigmoid"``) independent sigmoid scores selected under a
+bias the weights do not see, normalised and scaled, beside shared
+experts that every token passes through.
 """
 
 from __future__ import annotations
@@ -263,6 +267,24 @@ class DroplessMoE(Layer):
     or their exchange: under an ``expert`` mesh axis this is what each
     rank runs between the two all-to-alls, which are not built here.
 
+    ``scoring="sigmoid"`` (the auxiliary-loss-free router): the scores
+    are ``s = sigmoid(float32(x) Wr)``, each expert's own; the ``top_k``
+    experts with the largest ``s + b`` are selected, ``b`` the state
+    leaf ``selection_bias`` (zeros until a checkpoint or a balancing
+    rule sets it: it is no parameter and gets no gradient); the weights
+    are the UNBIASED scores of the selected, normalised to sum to one
+    under ``norm_topk_prob``, times ``routed_scaling_factor``.  The
+    product is float32 at the highest precision (a rounding that swaps
+    the sixth and seventh expert of a token changes which rows exist).
+    ``aux`` is then zero: this router is balanced through ``b``, not
+    through the loss.
+
+    ``shared_hidden``: one more gated expert of that width which every
+    token passes through, ``y += (silu(x Wg) * (x Wu)) Ws`` (several
+    shared experts of a published model are one of their summed width).
+    It is whole on every rank: the ranks' shares of ``y`` add up to the
+    uncut layer's once the shared part is counted once.
+
     State (not trained, carried like BatchNorm's moving statistics):
     ``rows_routed`` (count + 1,) int32 — assignments so far to each
     held expert and, last, to all the others; wraps at 2**32.
@@ -270,8 +292,16 @@ class DroplessMoE(Layer):
 
     def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
                  norm_topk_prob: bool = True, experts_held=None,
-                 block_rows: int = 256, init="glorot_uniform", **kwargs):
+                 block_rows: int = 256, init="glorot_uniform",
+                 scoring: str = "softmax",
+                 routed_scaling_factor: float = 1.0,
+                 shared_hidden: int = 0, **kwargs):
         super().__init__(**kwargs)
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+        self.scoring = scoring
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.shared_hidden = int(shared_hidden)
         self.num_experts = int(num_experts)
         self.hidden_dim = int(hidden_dim)
         self.top_k = int(top_k)
@@ -300,19 +330,42 @@ class DroplessMoE(Layer):
                         init=self.kernel_init)
         self.add_weight(params, rng, "down", (e, h, d),
                         init=self.kernel_init)
+        if self.shared_hidden:
+            self.add_weight(params, rng, "shared_gate_up",
+                            (d, 2 * self.shared_hidden),
+                            init=self.kernel_init)
+            self.add_weight(params, rng, "shared_down",
+                            (self.shared_hidden, d), init=self.kernel_init)
         return params
 
     def init_state(self, input_shape) -> State:
-        return {"rows_routed": jnp.zeros((self.count + 1,), jnp.int32)}
+        state = {"rows_routed": jnp.zeros((self.count + 1,), jnp.int32)}
+        if self.scoring == "sigmoid":
+            state["selection_bias"] = jnp.zeros((self.num_experts,),
+                                                jnp.float32)
+        return state
 
     def compute_output_shape(self, input_shape):
         return [tuple(input_shape), (input_shape[0],)]
 
-    def route(self, router, x):
+    def route(self, router, x, bias=None):
         """``(gates (N, k) float32, experts (N, k) int32, aux (B,))``
-        for ``x`` (B, T, d): the router in float32 over all experts."""
+        for ``x`` (B, T, d): the router in float32 over all experts;
+        ``bias`` (num_experts,): the sigmoid scores' selection bias."""
         policy = get_policy()
         b, t, d = x.shape
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(jnp.matmul(
+                x.reshape(b * t, d).astype(jnp.float32),
+                router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, experts = jax.lax.top_k(
+                jax.lax.stop_gradient(scores + bias), self.top_k)
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
+            if self.norm_topk_prob:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            return (gates * self.routed_scaling_factor, experts,
+                    jnp.zeros((b,), jnp.float32))
         logits = jax.lax.dot_general(
             policy.cast_compute(x.reshape(b * t, d)),
             policy.cast_compute(router), (((1,), (0,)), ((), ())),
@@ -365,7 +418,9 @@ class DroplessMoE(Layer):
         from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
         compute = get_policy().compute_dtype
         b, t, d = x.shape
-        gates, experts, aux = self.route(params["router"], x)
+        gates, experts, aux = self.route(
+            params["router"], x,
+            state["selection_bias"] if self.scoring == "sigmoid" else None)
         route, layout, rows = self.layout(experts)
 
         def held_experts(xt, gates, gate, up, down):
@@ -382,7 +437,14 @@ class DroplessMoE(Layer):
         y = jax.checkpoint(held_experts)(
             x.reshape(b * t, d).astype(compute), gates.T, params["gate"],
             params["up"], params["down"])
+        y = y.reshape(x.shape)
+        if self.shared_hidden:
+            from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
+                gated_feed_forward)
+            y = y + gated_feed_forward(x, params["shared_gate_up"],
+                                       params["shared_down"])
         new_state = state
         if state is not None:
-            new_state = {"rows_routed": state["rows_routed"] + rows}
-        return [y.reshape(x.shape).astype(x.dtype), aux], new_state
+            new_state = {**state,
+                         "rows_routed": state["rows_routed"] + rows}
+        return [y.astype(x.dtype), aux], new_state

@@ -52,6 +52,16 @@ def layer_norm(x, gamma, beta, epsilon: float):
     return y.astype(get_policy().compute_dtype)
 
 
+def gated_feed_forward(x, gate_up_kernel, down_kernel):
+    """``[g, u] = x W1``; ``(u * silu(g)) W2`` in float32: the products
+    in the compute dtype, the gate in float32."""
+    compute = get_policy().compute_dtype
+    gu = _mm(x, gate_up_kernel).astype(compute)
+    g, u = jnp.split(gu, 2, axis=-1)
+    act = (u.astype(F32) * jax.nn.silu(g.astype(F32))).astype(compute)
+    return _mm(act, down_kernel)
+
+
 class GatedFeedForward(Layer):
     """``[g, u] = x W1``; ``y = (u * silu(g)) W2``; no bias."""
 
@@ -68,11 +78,8 @@ class GatedFeedForward(Layer):
         return params
 
     def call(self, params, x, training=False, rng=None):
-        compute = get_policy().compute_dtype
-        gu = _mm(x, params["gate_up_kernel"]).astype(compute)
-        g, u = jnp.split(gu, 2, axis=-1)
-        act = (u.astype(F32) * jax.nn.silu(g.astype(F32))).astype(compute)
-        return _mm(act, params["down_kernel"]).astype(x.dtype)
+        return gated_feed_forward(x, params["gate_up_kernel"],
+                                  params["down_kernel"]).astype(x.dtype)
 
 
 class Mamba(Layer):
@@ -309,7 +316,44 @@ class DifferentialAttention(Layer):
         return [out, k, v] if self.emit_kv else out
 
 
-class HybridDecoderLayer(Layer):
+class KeepsKernelResults(Layer):
+    """A layer that may run its body under ``jax.checkpoint`` with the
+    policy that keeps what the body's Pallas kernels wrote, by name
+    (``KEPT_RESULTS`` of ``ops/pallas_attention.py`` and
+    ``ops/selective_scan.py``), and publishes the bytes so kept in the
+    gauge ``train_recompute_kept_bytes{name}``, over every recomputed
+    layer traced."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        # this layer's share of train_recompute_kept_bytes, by name
+        self._kept_bytes: dict = {}
+
+    def _recomputed(self, body, *args):
+        from analytics_zoo_tpu.ops import (
+            fused, pallas_attention, selective_scan)
+        keep = jax.checkpoint_policies.save_only_these_names(
+            *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS)
+        with fused.recording_kept_results() as kept:
+            out = jax.checkpoint(body, policy=keep)(*args)
+        self._gauge_kept(kept)
+        return out
+
+    def _gauge_kept(self, kept: dict) -> None:
+        """Moves the gauge by what this trace of the layer keeps more
+        (or less) than its last: tracing a layer again adds nothing."""
+        from analytics_zoo_tpu.observability import get_registry
+        gauge = get_registry().gauge(
+            "train_recompute_kept_bytes",
+            "bytes of kernel results that recomputed layers keep for the "
+            "backward pass", labels=("name",))
+        for name in kept.keys() | self._kept_bytes.keys():
+            gauge.labels(name).inc(
+                kept.get(name, 0) - self._kept_bytes.get(name, 0))
+        self._kept_bytes = kept
+
+
+class HybridDecoderLayer(KeepsKernelResults):
     """One decoder layer: ``h = h + mixer(LN1(h))``, then ``h = h +
     ffn(LN2(h))``, LayerNorm with weight and bias.  Inputs ``h`` or ``[h,
     *what the mixer reads besides]``; outputs ``h`` or ``[h, *what the
@@ -333,8 +377,6 @@ class HybridDecoderLayer(Layer):
         super().__init__(**kwargs)
         self.mixer, self.ffn = mixer, ffn
         self.epsilon, self.recompute = float(epsilon), bool(recompute)
-        # this layer's share of train_recompute_kept_bytes, by name
-        self._kept_bytes: dict = {}
 
     @staticmethod
     def _stream(input_shape):
@@ -379,30 +421,9 @@ class HybridDecoderLayer(Layer):
 
     def call(self, params, inputs, training=False, rng=None):
         args = inputs if isinstance(inputs, (list, tuple)) else [inputs]
-        if self.recompute:
-            from analytics_zoo_tpu.ops import (
-                fused, pallas_attention, selective_scan)
-            keep = jax.checkpoint_policies.save_only_these_names(
-                *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS)
-            with fused.recording_kept_results() as kept:
-                out = jax.checkpoint(self._body, policy=keep)(params, *args)
-            self._gauge_kept(kept)
-        else:
-            out = self._body(params, *args)
+        out = self._recomputed(self._body, params, *args) \
+            if self.recompute else self._body(params, *args)
         return list(out) if len(out) > 1 else out[0]
-
-    def _gauge_kept(self, kept: dict) -> None:
-        """Moves the gauge by what this trace of the layer keeps more
-        (or less) than its last: tracing a layer again adds nothing."""
-        from analytics_zoo_tpu.observability import get_registry
-        gauge = get_registry().gauge(
-            "train_recompute_kept_bytes",
-            "bytes of kernel results that recomputed layers keep for the "
-            "backward pass", labels=("name",))
-        for name in kept.keys() | self._kept_bytes.keys():
-            gauge.labels(name).inc(
-                kept.get(name, 0) - self._kept_bytes.get(name, 0))
-        self._kept_bytes = kept
 
 
 class NextTokenLoss(Layer):
@@ -414,19 +435,36 @@ class NextTokenLoss(Layer):
     vocabulary that starts at id ``vocab_first``, logit ``j`` is id
     ``vocab_first + j``.
 
+    ``head_units``: an UNTIED head: the layer holds its own ``kernel``
+    (D, head_units), takes ``[h, ids]`` and forms the logits as ``h_t
+    kernel``.
+
     ``chunk_rows``: the logits are formed that many positions at a time
     and formed again in the backward pass, so that one chunk's (rows, V)
     float32 logits exist at a time, not the sequence's."""
 
-    def __init__(self, vocab_first: int = 0, chunk_rows: int = 0, **kwargs):
+    def __init__(self, vocab_first: int = 0, chunk_rows: int = 0,
+                 head_units: int = 0, **kwargs):
         super().__init__(**kwargs)
         self.vocab_first, self.chunk_rows = int(vocab_first), int(chunk_rows)
+        self.head_units = int(head_units)
+
+    def build(self, rng, input_shape) -> Params:
+        params: Params = {}
+        if self.head_units:
+            self.add_weight(params, rng, "kernel",
+                            (input_shape[0][-1], self.head_units),
+                            init="normal")
+        return params
 
     def compute_output_shape(self, input_shape):
         return (input_shape[0][0],)
 
     def call(self, params, inputs, training=False, rng=None):
-        h, ids, table = inputs
+        if self.head_units:
+            (h, ids), table, over = inputs, params["kernel"], 0
+        else:
+            (h, ids, table), over = inputs, 1
         b, t, d = h.shape
         targets = jnp.roll(ids.astype(jnp.int32), -1, axis=1) \
             - self.vocab_first
@@ -439,7 +477,7 @@ class NextTokenLoss(Layer):
             logits = jax.lax.dot_general(
                 get_policy().cast_compute(h_c),
                 get_policy().cast_compute(table),
-                (((2,), (1,)), ((), ())), preferred_element_type=F32)
+                (((2,), (over,)), ((), ())), preferred_element_type=F32)
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, targets_c[..., None],
                                          axis=-1)[..., 0]

@@ -31,6 +31,14 @@ from ``--seed``):
                   against dense attention, outputs and gradients, each
                   side timed.
 
+* ``latent``      the latent-attention cell's mechanisms at its widths
+                  (32 heads of 128 | 64 | 128 and ONE shared rotary key,
+                  2,048 positions): the three latent flash kernels
+                  against dense attention over 192-wide keys written out
+                  plainly, output and the four gradients, each side
+                  timed; the sigmoid router of 128 experts under a
+                  selection bias against its formula.
+
 ``--phases a,b`` runs only the phases named.
 
 ``--chips 4`` runs ONLY the data-parallel ResNet-50 train path on a
@@ -769,6 +777,106 @@ def _recomputed_layers(seq, hidden, channels, states, heads, kv_heads,
                    "train_recompute_kept_bytes"), forward_calls)
 
 
+# ----------------------------------------------------------------- latent
+def latent(*, seq: int = 2048, heads: int = 32, hidden: int = 2048,
+           experts: int = 128, top_k: int = 6, scale: float = 2.448,
+           seed: int = 0):
+    """The latent flash kernels (heads of 128 | 64 | 128, one shared
+    rotary key) on their Pallas path, forward and backward, against
+    dense attention over keys written out 192 wide; then the sigmoid
+    router under a selection bias against its formula."""
+    from analytics_zoo_tpu.ops import pallas_latent_attention as kernels
+    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import DroplessMoE
+    before = _counters()
+    n, r = kernels.NOPE, kernels.ROPE
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    shapes = [(1, seq, heads * (n + r)), (1, seq, heads * r),
+              (1, seq, heads * 2 * n), (1, seq, r), (1, seq, heads * n)]
+    q, q_pe, kv, k_pe, w_o = (jax.random.normal(k, s, f32).astype(bf16)
+                              for k, s in zip(keys, shapes))
+
+    def flash(q, q_pe, kv, k_pe):
+        return kernels.latent_flash_attention(
+            q, q_pe, kv, k_pe, n_head=heads, causal=True,
+            block_q=512 if seq % 1024 == 0 else 256,
+            block_k=512 if seq % 1024 == 0 else 256)
+
+    def dense(q, q_pe, kv, k_pe):
+        # the keys written out: each head's own 128 beside the shared 64
+        q_h = jnp.concatenate([q[..., :heads * n].reshape(1, seq, heads, n),
+                               q_pe.reshape(1, seq, heads, r)], axis=-1)
+        k_h = jnp.concatenate(
+            [kv[..., :heads * n].reshape(1, seq, heads, n),
+             jnp.broadcast_to(k_pe[:, :, None], (1, seq, heads, r))], axis=-1)
+        v_h = kv[..., heads * n:].reshape(1, seq, heads, n)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_h, k_h,
+                       preferred_element_type=f32) * (n + r) ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -1e30)
+        out = jnp.einsum("bhqk,bkhd->bqhd",
+                         jax.nn.softmax(s, axis=-1).astype(bf16), v_h,
+                         preferred_element_type=f32)
+        return out.reshape(1, seq, heads * n).astype(bf16)
+
+    def grads(attend):
+        def loss(q, q_pe, kv, k_pe, w_o):
+            out = attend(q, q_pe, kv, k_pe)
+            return jnp.sum(out.astype(f32) * w_o.astype(f32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                          has_aux=True))
+
+    seconds = {}
+    ((_, got_o), got_g), seconds["flash_latent"] = _timed(
+        grads(flash), q, q_pe, kv, k_pe, w_o)
+    ((_, ref_o), ref_g), seconds["dense_latent"] = _timed(
+        grads(dense), q, q_pe, kv, k_pe, w_o)
+    # the rotary columns of q are not read: their cotangent is zero
+    unread = float(jnp.max(jnp.abs(got_g[0][..., heads * n:].astype(f32))))
+    got_g = (got_g[0][..., :heads * n],) + got_g[1:]
+    ref_g = (ref_g[0][..., :heads * n],) + ref_g[1:]
+    attention = {name: _rel_err(g, ref) for name, g, ref in zip(
+        ("out", "dq_nope", "dq_pe", "dk_nope_dv", "dk_pe"),
+        (got_o,) + got_g, (ref_o,) + ref_g)}
+
+    layer = DroplessMoE(experts, 8, top_k=top_k, scoring="sigmoid",
+                        routed_scaling_factor=scale)
+    router = 0.02 * jax.random.normal(keys[5], (hidden, experts), f32)
+    x = jax.random.normal(keys[6], (1, seq, hidden), f32)
+    bias = 0.01 * jax.random.normal(keys[7], (experts,), f32)
+    gates, picked, _ = jax.jit(layer.route)(router, x, bias)
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x[0], router, precision=jax.lax.Precision.HIGHEST))
+    _, want = jax.lax.top_k(scores + bias, top_k)
+    want_gates = jnp.take_along_axis(scores, want, axis=-1)
+    want_gates = want_gates / jnp.sum(want_gates, -1, keepdims=True) * scale
+    _, unbiased = jax.lax.top_k(scores, top_k)
+    router_rec = {
+        "tokens_picking_other_experts": int(jnp.sum(jnp.any(
+            jnp.sort(picked, -1) != jnp.sort(want, -1), axis=-1))),
+        "gates_rel_err": _rel_err(jnp.sort(gates, -1),
+                                  jnp.sort(want_gates, -1)),
+        "tokens_the_bias_moved": int(jnp.sum(jnp.any(
+            jnp.sort(unbiased, -1) != jnp.sort(want, -1), axis=-1)))}
+
+    builds = _delta(_counters(), before, "fused_kernel_builds_total")
+    rec = {"latent_flash_vs_dense": attention,
+           "unread_q_columns_cotangent_max": unread,
+           "forward_backward_s": seconds, "router": router_rec,
+           "kernel_builds": builds}
+    rec["checks"] = {
+        "flash_agrees_with_dense": all(e <= ATTENTION_TOL
+                                       for e in attention.values()),
+        "unread_columns_get_no_gradient": unread == 0.0,
+        # a float32 product at the highest precision picks the same
+        # experts as the formula (a token in a thousand may sit on a tie)
+        "router_picks_the_formulas_experts":
+            router_rec["tokens_picking_other_experts"] <= seq // 1000 + 1
+            and router_rec["gates_rel_err"] <= 1e-3,
+        "bias_changes_selections": router_rec["tokens_the_bias_moved"] > 0,
+    }
+    return rec
+
+
 # ------------------------------------------------------------------- main
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -776,7 +884,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="4 = only the data-parallel train path and "
                          "its one-chip comparison")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phases", default="train,serve,transformer,hybrid",
+    ap.add_argument("--phases",
+                    default="train,serve,transformer,hybrid,latent",
                     help="one-chip phases to run (serve needs train)")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
@@ -815,7 +924,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                        "error": "no trained model: train failed"})
                 oks.append(False)
             for name, fn in (("transformer", transformer),
-                             ("hybrid", hybrid)):
+                             ("hybrid", hybrid), ("latent", latent)):
                 if name in phases:
                     oks.append(run_phase(name, fn, seed=args.seed)[0])
     ok = all(oks)

@@ -126,3 +126,35 @@ def one_chip_routing(monkeypatch):
     monkeypatch.setattr(zoo_context, "_context",
                         zoo_context.ZooContext(get_config(), mesh))
     monkeypatch.setattr(fused, "pallas_supported", lambda: True)
+
+
+@pytest.fixture
+def interpreted_kernels(one_chip_routing, monkeypatch):
+    """The layers on their kernels as on one TPU chip, every kernel
+    interpreted."""
+    from jax.experimental import pallas as pl
+    from analytics_zoo_tpu.ops import fused
+    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
+    compiled_call = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **kw: compiled_call(*a, **{**kw, "interpret": True}))
+
+
+@pytest.fixture(autouse=True)
+def own_benchmark_root(request, monkeypatch):
+    """A test that drives ``benchmark.harness.run_cell`` in this process
+    (it asks for ``one_chip``) gets a checkout root of its own: links to
+    ``BENCHMARK.json`` and ``benchmark/`` in a temporary directory.  A
+    traced run removes ``<root>/.bench_trace`` before and after it
+    writes there, and under ``-n`` the traced runs of the cells' test
+    files sit in different workers: with one root, one worker's removal
+    takes another's trace ("no .xplane.pb under .bench_trace")."""
+    if "one_chip" not in request.fixturenames:
+        return
+    from benchmark import harness
+    root = request.getfixturevalue("tmp_path")
+    for name in ("BENCHMARK.json", "benchmark"):
+        os.symlink(os.path.join(harness.ROOT, name), root / name)
+    monkeypatch.syspath_prepend(str(root))
+    monkeypatch.setattr(harness, "ROOT", str(root))

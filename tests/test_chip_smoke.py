@@ -122,6 +122,20 @@ def test_hybrid(one_chip, capsys):
     assert kept['{name="selective_scan_y"}'] >= 256 * 1024 * 4
 
 
+def test_latent(one_chip, capsys):
+    ok, _, line = _phase(capsys, "latent", chip_smoke.latent, seq=256,
+                         heads=2, hidden=64, experts=16, top_k=3)
+    assert ok, line
+    assert set(line["latent_flash_vs_dense"]) == {
+        "out", "dq_nope", "dq_pe", "dk_nope_dv", "dk_pe"}
+    assert set(line["forward_backward_s"]) == {"flash_latent",
+                                               "dense_latent"}
+    assert line["unread_q_columns_cotangent_max"] == 0.0
+    assert line["router"]["tokens_picking_other_experts"] == 0
+    assert line["router"]["tokens_the_bias_moved"] > 0
+    assert all(line["checks"].values())
+
+
 def test_data_parallel_on_four_virtual_devices(capsys):
     ok, _, line = _phase(capsys, "data_parallel", chip_smoke.data_parallel,
                          jax.devices()[:4], **TOY_RESNET)

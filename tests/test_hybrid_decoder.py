@@ -359,18 +359,6 @@ def test_recomputed_layers_give_the_same_gradients(f32_policy, reference):
 
 
 # ------------------------- a recomputed layer keeps what its kernels wrote
-@pytest.fixture
-def interpreted_kernels(one_chip_routing, monkeypatch):
-    """The layers on their kernels as on one TPU chip, every kernel
-    interpreted."""
-    from jax.experimental import pallas as pl
-    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
-    compiled_call = pl.pallas_call
-    monkeypatch.setattr(
-        pl, "pallas_call",
-        lambda *a, **kw: compiled_call(*a, **{**kw, "interpret": True}))
-
-
 # (mixer, what the layer reads besides the stream, its forward kernel,
 # bytes kept by name at the toy's sizes: 256 positions, 4 heads in pairs
 # on 2 K/V heads of 64, 1,024 channels of 4 states, float32)

@@ -26,6 +26,8 @@ from analytics_zoo_tpu.ops.grouped_matmul import (
 from analytics_zoo_tpu.ops.pallas_attention import (
     block_diffusion, flash_attention, flash_attention_token_major,
     sliding_window)
+from analytics_zoo_tpu.ops.pallas_latent_attention import (
+    latent_flash_attention)
 from analytics_zoo_tpu.ops.selective_scan import selective_scan
 
 
@@ -102,6 +104,18 @@ def _scan(x, dt, a, b, c):
     return selective_scan(x, dt, a, b, c)[0]
 
 
+def _flash_latent(q, q_pe, kv, k_pe):
+    return latent_flash_attention(q, q_pe, kv, k_pe, n_head=32, causal=True,
+                                  block_q=512, block_k=512)
+
+
+# the latent cell's operands at 8,192 positions: the query projection's
+# result (32 heads of 128 | 64, the nope heads first), the rotated rotary
+# parts, the up-projection's result (k_nope | v) and the ONE rotary key
+LATENT = [(1, 8192, 32 * 192), (1, 8192, 32 * 64), (1, 8192, 32 * 256),
+          (1, 8192, 64)]
+
+
 def _grad(fn, n_args):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
                     argnums=tuple(range(n_args)))
@@ -155,6 +169,9 @@ CASES = [
      [(1, 8192, 2560), (1, 8192, 1280), (1, 8192, 1280)], BF16, 3),
     ("flash-pair-causal-grad-t8192x5120", _grad(_flash_pair_causal, 1),
      [(1, 8192, 5120)], BF16, 3),
+    ("flash-latent-t8192h32-128-64-128", _flash_latent, LATENT, BF16, 1),
+    ("flash-latent-grad-t8192h32-128-64-128", _grad(_flash_latent, 4),
+     LATENT, BF16, 3),
     ("selective-scan-t8192c5120n16", _scan,
      [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16),
       (1, 8192, 16)], F32, 1),
@@ -210,6 +227,9 @@ NAMED = {
                                 "flash_attention_dkv"],
     "selective-scan-grad-t8192c5120n16": ["selective_scan_fwd",
                                           "selective_scan_bwd"],
+    "flash-latent-grad-t8192h32-128-64-128": [
+        "flash_attention_latent_fwd", "flash_attention_latent_dq",
+        "flash_attention_latent_dkv"],
 }
 
 
@@ -392,6 +412,56 @@ def test_hybrid_decoder_layers_compile_for_v5e(v5e, one_chip_routing, on_tpu,
         shaped(stream, F32), *(shaped(r, BF16) for r in reads)
     ).compile().as_text()
     assert text.count("tpu_custom_call") == kernels
+
+
+def test_latent_decoder_layer_compiles_for_v5e(v5e, one_chip_routing, on_tpu):
+    """One recomputed SPARSE decoder layer of the latent cell at its
+    published widths and 8,192 positions, forward and backward, state
+    and all: Mosaic takes the three latent kernels and the layer reaches
+    them, each ONCE though the layer is recomputed (its policy keeps
+    the forward kernel's results); the grouped products' forward stands
+    twice, the expert layer's own recomputation.  No 192-wide key exists
+    (the logit is formed as a sum in the kernels), and the shared rotary
+    key is never broadcast to the heads: besides its (T, 64) self only
+    the (T, 128) tile holding it twice."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import latent, moe
+    layer = latent.LatentDecoderLayer(
+        latent.LatentAttention(32, 512, 128, 64, 128, rope_theta=1e6),
+        moe.DroplessMoE(128, 768, top_k=6, experts_held=(0, 16),
+                        init="normal", scoring="sigmoid",
+                        routed_scaling_factor=2.448,
+                        shared_hidden=1536),
+        recompute=True)
+    stream = (1, 8192, 2048)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: layer.build(jax.random.PRNGKey(0), stream)))
+    state = shaped(jax.eval_shape(lambda: layer.init_state(stream)))
+
+    def loss(params, h, state):
+        out, new = layer.apply(params, h, state=state)
+        return jnp.sum(jnp.square(out)), new
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, jax.ShapeDtypeStruct(stream, F32, sharding=v5e), state
+    ).compile().as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == sorted(
+        ["flash_attention_latent_fwd", "flash_attention_latent_dq",
+         "flash_attention_latent_dkv"] + 2 * 3 * ["grouped_matmul_fwd"]
+        + 3 * ["grouped_matmul_dlhs", "grouped_matmul_drhs"])
+    # no (T, 32, 192) array: 192 is no dimension of anything
+    assert not re.search(r"\[[\d,]*\b192\b[\d,]*\]", text)
+    for line in text.splitlines():
+        m = re.search(r" = \w+\[([\d,]+)\]\S* broadcast\(", line)
+        if m and {"8192", "32", "64"} <= set(m.group(1).split(",")):
+            raise AssertionError(f"k_pe broadcast to the heads: {line}")
 
 
 def test_capability_probe_compiles_for_v5e(v5e):
