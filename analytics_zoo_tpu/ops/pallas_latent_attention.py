@@ -25,10 +25,30 @@ step works TWO heads: their 64-wide rotary parts fill one 128-lane tile
 taken with the other head's lanes zeroed; ``k_pe`` enters as a 128-lane
 tile holding it twice, so the masked product is head ``j``'s own (the
 wrapper lays the two copies side by side and autodiff adds the halves of
-the cotangent: ``dk_pe`` is the sum over every query head).  The dkv
-kernel walks, for each key tile, every pair of heads over the key
-tile's query tiles: ``dk_nope`` and ``dv`` leave once a (key tile, head
-pair), ``dk_pe`` once a key tile, summed in VMEM over all heads.
+the cotangent: ``dk_pe`` is the sum over every query head).
+
+The backward pass has two forms, chosen by the sequence length alone
+(``_fits_resident``; ``fused_kernel_builds_total{kernel=
+"flash_attention_latent_backward",path="one_pass"|"two_pass"}`` says
+which a program got):
+
+* **one pass** (``flash_attention_latent_bwd``), wherever one head
+  pair's WHOLE ``dq_nope`` and ``dq_pe`` fit ``_RESIDENT_VMEM`` (8,192
+  positions in bfloat16 do).  The grid is (batch, head pair, walk), the
+  walk ``_tile_pairs``' by-k-tile list: for each key tile its query
+  tiles.  P, dP and dS are formed ONCE a (tile pair, head), transposed;
+  ``dv``, ``dk_nope`` and the pair's share of ``dk_pe`` accumulate over
+  the key tile's query tiles and leave on its last, while ``dq``
+  accumulates in float32 scratch addressed by the query tile's rows and
+  leaves once, after the pair's last key tile.  ``delta`` = rowsum(dO O)
+  is formed before the kernel, and the head pairs' shares of ``dk_pe``
+  are summed after it, both by XLA.
+* **two passes** (``flash_attention_latent_dq`` + ``_dkv``) past that
+  length: the dq kernel walks by query tile (and forms ``delta``), the
+  dkv kernel walks, for each key tile, every pair of heads over the key
+  tile's query tiles: ``dk_nope`` and ``dv`` leave once a (key tile,
+  head pair), ``dk_pe`` once a key tile, summed in VMEM over all heads.
+  Each forms P, dP and dS for itself.
 
 Heads of (128 | 64 | 128) only; other sizes take
 ``latent_attention_dense``, the lax form, which is also what runs off
@@ -48,7 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.compile.engine import engine_jit
-from analytics_zoo_tpu.ops.fused import keep_result
+from analytics_zoo_tpu.ops.fused import count_build, keep_result
 from analytics_zoo_tpu.ops.pallas_attention import (
     _FIRST, _LAST, _NT, KEPT_RESULTS, NEG, _col_to_row, _compiler_params,
     _head_lanes, _masked, _positions, _resolve_blocks, _row_to_col,
@@ -60,6 +80,13 @@ NOPE, ROPE, PER = 128, 64, 2
 # flags of the dkv walk, beside the pair's own: the first and the last
 # entry of a key tile (over all its head pairs)
 _KFIRST, _KLAST = 8, 16
+# VMEM the one-pass backward may hold for a head pair's WHOLE dq_nope
+# and dq_pe while the pair's key tiles go by: their float32 accumulators
+# and the two buffers of each output block (24 MiB of it at 8,192
+# positions in bfloat16).  The kernel's own limit is twice this: the
+# tiles and the intermediates at block 512 take the other half, under
+# the v5e's 128 MiB.  A longer sequence takes the dq and dkv kernels.
+_RESIDENT_VMEM = 32 << 20
 
 
 def kernel_fits(n_head: int, nope_dim: int, rope_dim: int,
@@ -177,6 +204,30 @@ def _dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, qpe_ref, k_ref, v_ref,
         dqpe_ref[...] = (accpe_ref[...] * scale).astype(dqpe_ref.dtype)
 
 
+def _key_side(j: int, q, q_pe, k, v, k_pe, do, lse, delta, masked, dk_acc,
+              dv_acc):
+    """Head ``j`` of one tile pair with the logits held transposed,
+    ``(block_k, block_q)``, so that ``lse`` and ``delta`` enter as
+    lane-dense rows: forms P^T, dP^T and dS^T, adds the head's dv and
+    dk_nope to the accumulators and returns dS^T (cast for its
+    products) and the head's share of dk_pe.  ``q`` and ``q_pe`` enter
+    pre-scaled, so the scale is in dk's accumulation."""
+    s_t = masked(_logits(q, q_pe, k, k_pe, j, transposed=True))
+    p_t = jnp.exp(s_t - lse)                                # (bk, bq)
+    do_j = do[:, _head(j)]
+    dv_acc[:, _head(j)] += jnp.dot(p_t.astype(do.dtype), do_j,
+                                   preferred_element_type=jnp.float32)
+    dp_t = jax.lax.dot_general(v[:, _head(j)], do_j, _NT,
+                               preferred_element_type=jnp.float32)
+    ds_t = (p_t * (dp_t - delta)).astype(q.dtype)
+    dk_acc[:, _head(j)] += jnp.dot(ds_t, q[:, _head(j)],
+                                   preferred_element_type=jnp.float32)
+    # head j's rotary lanes alone: its share lands in half j of the
+    # tile, and the two halves are the two copies' cotangents
+    q_pe_j, = _head_lanes(j, PER, q_pe)
+    return ds_t, jnp.dot(ds_t, q_pe_j, preferred_element_type=jnp.float32)
+
+
 def _dkv_kernel(qi_ref, ki_ref, gi_ref, fl_ref, q_ref, qpe_ref, k_ref, v_ref,
                 kpe_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                 dkpe_ref, dk_acc, dv_acc, dkpe_acc, *, mask, mask_all: bool,
@@ -204,24 +255,15 @@ def _dkv_kernel(qi_ref, ki_ref, gi_ref, fl_ref, q_ref, qpe_ref, k_ref, v_ref,
     k_start = ki_ref[p_id] * block_k
     q_pos = _positions(qi_ref[p_id] * block_q, block_q, 1)
     k_pos = _positions(k_start, block_k, 0)
+
+    def masked(s_t):
+        return _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
+                       k_start >= half)
+
     dk_pe = None
     for j in range(PER):
-        s_t = _masked(_logits(q, q_pe, k, k_pe, j, transposed=True), mask,
-                      mask_all, flags, q_pos, k_pos, k_start >= half)
-        p_t = jnp.exp(s_t - lse_ref[j])                     # (bk, bq)
-        do_j = do[:, _head(j)]
-        dv_acc[:, _head(j)] += jnp.dot(p_t.astype(do.dtype), do_j,
-                                       preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(v[:, _head(j)], do_j, _NT,
-                                   preferred_element_type=jnp.float32)
-        ds_t = (p_t * (dp_t - delta_ref[j])).astype(q.dtype)
-        # q enters pre-scaled, so the scale is in the accumulation
-        dk_acc[:, _head(j)] += jnp.dot(ds_t, q[:, _head(j)],
-                                       preferred_element_type=jnp.float32)
-        # head j's rotary lanes alone: its share lands in half j of the
-        # tile, and the two halves are the two copies' cotangents
-        q_pe_j, = _head_lanes(j, PER, q_pe)
-        dk_pe_j = jnp.dot(ds_t, q_pe_j, preferred_element_type=jnp.float32)
+        _, dk_pe_j = _key_side(j, q, q_pe, k, v, k_pe, do, lse_ref[j],
+                               delta_ref[j], masked, dk_acc, dv_acc)
         dk_pe = dk_pe_j if dk_pe is None else dk_pe + dk_pe_j
     dkpe_acc[...] += dk_pe
 
@@ -233,6 +275,72 @@ def _dkv_kernel(qi_ref, ki_ref, gi_ref, fl_ref, q_ref, qpe_ref, k_ref, v_ref,
     @pl.when((flags & _KLAST) != 0)
     def _store_shared():
         dkpe_ref[...] = dkpe_acc[...].astype(dkpe_ref.dtype)
+
+
+def _bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, qpe_ref, k_ref, v_ref,
+                kpe_ref, do_ref, lse_ref, delta_ref, dq_ref, dqpe_ref,
+                dk_ref, dv_ref, dkpe_ref, dq_acc, dqpe_acc, dk_acc, dv_acc,
+                dkpe_acc, *, mask, mask_all: bool, scale: float,
+                block_q: int, block_k: int, half: int):
+    """All five gradients in one pass over a head pair's walk (key tile
+    by key tile, each key tile's q tiles innermost): P, dP and dS are
+    formed once a (pair, head), transposed (``_key_side``, the dkv
+    kernel's), and feed dv, dk_nope and dk_pe of the key tile and,
+    through dS's transpose, dq_nope and dq_pe of the q tile's rows.  The pair's whole
+    dq stays in ``dq_acc`` / ``dqpe_acc`` over the walk and leaves on
+    its last entry; dk_nope, dv and this PAIR's share of dk_pe leave on
+    a key tile's last."""
+    p_id = pl.program_id(2)
+    flags = fl_ref[p_id]
+
+    @pl.when(p_id == 0)
+    def _init_resident():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dqpe_acc[...] = jnp.zeros_like(dqpe_acc)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dkpe_acc[...] = jnp.zeros_like(dkpe_acc)
+
+    q, q_pe = q_ref[...] * scale, qpe_ref[...] * scale
+    k, v, k_pe, do = k_ref[...], v_ref[...], kpe_ref[...], do_ref[...]
+    k_start = ki_ref[p_id] * block_k
+    q_start = pl.multiple_of(qi_ref[p_id] * block_q, block_q)
+    q_pos = _positions(q_start, block_q, 1)
+    k_pos = _positions(k_start, block_k, 0)
+    rows = pl.ds(q_start, block_q)
+
+    def masked(s_t):
+        return _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
+                       k_start >= half)
+
+    dk_pe = dq_pe = None
+    for j in range(PER):
+        ds_t, dk_pe_j = _key_side(j, q, q_pe, k, v, k_pe, do, lse_ref[j],
+                                  delta_ref[j], masked, dk_acc, dv_acc)
+        dk_pe = dk_pe_j if dk_pe is None else dk_pe + dk_pe_j
+        ds = ds_t.T                                         # (bq, bk)
+        dq_acc[rows, _head(j)] += jnp.dot(
+            ds, k[:, _head(j)], preferred_element_type=jnp.float32)
+        # ds k_pe lands in both halves of the tile: head j keeps its own
+        own, = _head_lanes(j, PER, jnp.dot(
+            ds, k_pe, preferred_element_type=jnp.float32))
+        dq_pe = own if dq_pe is None else dq_pe + own
+    dkpe_acc[...] += dk_pe
+    dqpe_acc[rows, :] += dq_pe
+
+    @pl.when((flags & _LAST) != 0)
+    def _store():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        dkpe_ref[...] = dkpe_acc[...]
+
+    @pl.when(p_id == pl.num_programs(2) - 1)
+    def _store_resident():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+        dqpe_ref[...] = (dqpe_acc[...] * scale).astype(dqpe_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -257,7 +365,10 @@ def _dkv_walk(mask, t: int, block_q: int, block_k: int, tiles: int):
     return tuple(np.concatenate(col).astype(np.int32) for col in out)
 
 
-def _by_q_specs(block_q: int, block_k: int, tiles: int):
+def _pair_specs(block_q: int, block_k: int, tiles: int):
+    """Block specs of grid ``(batch, head pair, walk entry)``: an
+    entry's q tile, k tile (``off`` head pairs on: ``v``), shared
+    rotary key and lane-dense ``lse`` / ``delta`` rows."""
     def q_tile(width):
         return pl.BlockSpec((None, block_q, width),
                             lambda b, i, p, qi, ki, fl: (b, qi[p], i))
@@ -279,7 +390,7 @@ def _fwd_impl(ops, cfg):
     b, t = q.shape[:2]
     tiles = h // PER
     by_q, _ = _tile_pairs(mask, t, block_q, block_k)
-    q_tile, k_tile, kpe_tile, rows = _by_q_specs(block_q, block_k, tiles)
+    q_tile, k_tile, kpe_tile, rows = _pair_specs(block_q, block_k, tiles)
     wide, narrow = PER * NOPE, PER * ROPE
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **_statics(cfg, t)),
@@ -326,11 +437,94 @@ def _attach_fwd(ops, out, lse, cfg):
     return out, (ops, out, lse)
 
 
+def _fits_resident(t: int, itemsize: int) -> bool:
+    """Whether a head pair's whole dq_nope and dq_pe over ``t``
+    positions (float32 accumulators, two buffers of each output block)
+    stay inside ``_RESIDENT_VMEM``: the one-pass backward's condition."""
+    return t * PER * (NOPE + ROPE) * (4 + 2 * itemsize) <= _RESIDENT_VMEM
+
+
 def _attach_bwd(cfg, res, dout):
-    return (_backward(res, dout, cfg), None, None)
+    q = res[0][0]
+    one_pass = _fits_resident(q.shape[1], q.dtype.itemsize)
+    count_build("flash_attention_latent_backward",
+                "one_pass" if one_pass else "two_pass")
+    return (_backward(res, dout, cfg, one_pass), None, None)
 
 
-def _bwd_impl(res, dout, cfg):
+def _bwd_impl(res, dout, cfg, one_pass: bool):
+    """The kernels' five gradients, in either form, as the cotangents
+    of the four operands."""
+    q, h = res[0][0], cfg[-1]
+    dq, dq_pe, dk, dv, dk_pe = (_one_pass if one_pass else _two_pass)(
+        res, dout, cfg)
+    unread = q.shape[-1] - h * NOPE
+    if unread:
+        dq = jax.lax.pad(dq, jnp.zeros((), dq.dtype),
+                         [(0, 0, 0), (0, 0, 0), (0, unread, 0)])
+    return dq, dq_pe, _side_by_side([dk, dv]), dk_pe
+
+
+def _one_pass(res, dout, cfg):
+    mask, scale, block_q, block_k, interpret, h = cfg
+    (q, q_pe, kv, k_pe), out, lse = res
+    b, t = q.shape[:2]
+    tiles = h // PER
+    _, by_k = _tile_pairs(mask, t, block_q, block_k)
+    wide, narrow = PER * NOPE, PER * ROPE
+    # rowsum(dO * O) a head, as lane-dense rows beside lse's: the walk
+    # meets a q tile once a key tile, so no pair is a q tile's first.
+    # Summed as a product with the heads' lanes at float32's precision,
+    # one fusion over dO and O; as a reduction over a head's 128 lanes
+    # XLA writes the float32 product out and relayouts it (1 ms a layer
+    # at 8,192 positions on the v5e)
+    lanes = (jnp.arange(h * NOPE) // NOPE == jnp.arange(h)[:, None])
+    delta = jnp.einsum(
+        "hl,btl->bht", lanes.astype(jnp.float32),
+        dout.astype(jnp.float32) * out.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST).reshape(b * h, 1, t)
+
+    q_tile, k_tile, kpe_tile, rows = _pair_specs(block_q, block_k, tiles)
+
+    def whole(width):
+        return pl.BlockSpec((None, t, width),
+                            lambda b, i, p, qi, ki, fl: (b, 0, i))
+
+    dq, dq_pe, dk, dv, dk_pe = pl.pallas_call(
+        functools.partial(_bwd_kernel, **_statics(cfg, t)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, h * NOPE), q.dtype),
+                   jax.ShapeDtypeStruct(q_pe.shape, q_pe.dtype),
+                   jax.ShapeDtypeStruct((b, t, h * NOPE), kv.dtype),
+                   jax.ShapeDtypeStruct((b, t, h * NOPE), kv.dtype),
+                   jax.ShapeDtypeStruct((b, tiles, t, narrow),
+                                        jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, tiles, len(by_k[0])),
+            in_specs=[q_tile(wide), q_tile(narrow), k_tile(wide),
+                      k_tile(wide, tiles), kpe_tile, q_tile(wide), rows,
+                      rows],
+            out_specs=(whole(wide), whole(narrow), k_tile(wide),
+                       k_tile(wide),
+                       pl.BlockSpec(
+                           (None, None, block_k, narrow),
+                           lambda b, i, p, qi, ki, fl: (b, i, ki[p], 0))),
+            scratch_shapes=[pltpu.VMEM((t, wide), jnp.float32),
+                            pltpu.VMEM((t, narrow), jnp.float32),
+                            pltpu.VMEM((block_k, wide), jnp.float32),
+                            pltpu.VMEM((block_k, wide), jnp.float32),
+                            pltpu.VMEM((block_k, narrow), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=2 * _RESIDENT_VMEM),
+        interpret=interpret,
+        name="flash_attention_latent_bwd",
+    )(*_tables(by_k), q, q_pe, kv, kv, k_pe, dout, lse, delta)
+    # a head pair's share a key tile; the sum over the pairs is XLA's
+    return dq, dq_pe, dk, dv, jnp.sum(dk_pe, axis=1).astype(k_pe.dtype)
+
+
+def _two_pass(res, dout, cfg):
     mask, scale, block_q, block_k, interpret, h = cfg
     (q, q_pe, kv, k_pe), out, lse = res
     b, t = q.shape[:2]
@@ -339,7 +533,7 @@ def _bwd_impl(res, dout, cfg):
     static = _statics(cfg, t)
     wide, narrow = PER * NOPE, PER * ROPE
 
-    q_tile, k_tile, kpe_tile, rows = _by_q_specs(block_q, block_k, tiles)
+    q_tile, k_tile, kpe_tile, rows = _pair_specs(block_q, block_k, tiles)
     dq, dq_pe, delta = pl.pallas_call(
         functools.partial(_dq_kernel, **static),
         out_shape=(jax.ShapeDtypeStruct((b, t, h * NOPE), q.dtype),
@@ -399,14 +593,10 @@ def _bwd_impl(res, dout, cfg):
         name="flash_attention_latent_dkv",
     )(*_tables(walk), q, q_pe, kv, kv, k_pe, dout, lse, delta)
 
-    unread = q.shape[-1] - h * NOPE
-    if unread:
-        dq = jax.lax.pad(dq, jnp.zeros((), dq.dtype),
-                         [(0, 0, 0), (0, 0, 0), (0, unread, 0)])
-    return dq, dq_pe, _side_by_side([dk, dv]), dk_pe
+    return dq, dq_pe, dk, dv, dk_pe
 
 
-_backward = engine_jit(_bwd_impl, static_argnums=(2,),
+_backward = engine_jit(_bwd_impl, static_argnums=(2, 3),
                        key_hint="flash_attention_latent_backward")
 _attach.defvjp(_attach_fwd, _attach_bwd)
 

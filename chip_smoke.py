@@ -33,11 +33,12 @@ from ``--seed``):
 
 * ``latent``      the latent-attention cell's mechanisms at its widths
                   (32 heads of 128 | 64 | 128 and ONE shared rotary key,
-                  2,048 positions): the three latent flash kernels
-                  against dense attention over 192-wide keys written out
-                  plainly, output and the four gradients, each side
-                  timed; the sigmoid router of 128 experts under a
-                  selection bias against its formula.
+                  2,048 positions): the latent flash kernels (the
+                  forward and the one-pass backward, whose build counter
+                  is printed) against dense attention over 192-wide
+                  keys written out plainly, output and the four
+                  gradients, each side timed; the sigmoid router of 128
+                  experts under a selection bias against its formula.
 
 ``--phases a,b`` runs only the phases named.
 
@@ -782,9 +783,11 @@ def latent(*, seq: int = 2048, heads: int = 32, hidden: int = 2048,
            experts: int = 128, top_k: int = 6, scale: float = 2.448,
            seed: int = 0):
     """The latent flash kernels (heads of 128 | 64 | 128, one shared
-    rotary key) on their Pallas path, forward and backward, against
-    dense attention over keys written out 192 wide; then the sigmoid
-    router under a selection bias against its formula."""
+    rotary key) on their Pallas path, forward and backward (in one pass
+    where a head pair's dq fits VMEM, as at every length this phase is
+    run at), against dense attention over keys written out 192 wide;
+    then the sigmoid router under a selection bias against its
+    formula."""
     from analytics_zoo_tpu.ops import pallas_latent_attention as kernels
     from analytics_zoo_tpu.pipeline.api.keras.layers.moe import DroplessMoE
     before = _counters()
@@ -867,6 +870,9 @@ def latent(*, seq: int = 2048, heads: int = 32, hidden: int = 2048,
         "flash_agrees_with_dense": all(e <= ATTENTION_TOL
                                        for e in attention.values()),
         "unread_columns_get_no_gradient": unread == 0.0,
+        "backward_in_one_pass": [
+            k for k in builds if "flash_attention_latent_backward" in k] == [
+            '{kernel="flash_attention_latent_backward",path="one_pass"}'],
         # a float32 product at the highest precision picks the same
         # experts as the formula (a token in a thousand may sit on a tie)
         "router_picks_the_formulas_experts":

@@ -131,6 +131,10 @@ def test_latent(one_chip, capsys):
     assert set(line["forward_backward_s"]) == {"flash_latent",
                                                "dense_latent"}
     assert line["unread_q_columns_cotangent_max"] == 0.0
+    # the backward's form is printed beside the other builds
+    assert line["kernel_builds"][
+        '{kernel="flash_attention_latent_backward",path="one_pass"}'] == 1.0
+    assert line["checks"]["backward_in_one_pass"]
     assert line["router"]["tokens_picking_other_experts"] == 0
     assert line["router"]["tokens_the_bias_moved"] > 0
     assert all(line["checks"].values())

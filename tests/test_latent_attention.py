@@ -2,7 +2,8 @@
 sizes with seeded weights, each against a dense formula or the plain
 reference of the ``kanana-2-30b-a3b-instruct-2601`` configuration
 (``benchmark/reference``): the latent flash kernels (interpreted) and
-their lax form, forward and the four gradients; the layer; the sigmoid
+their lax form, forward and the four gradients, the backward in its
+one-pass and its two-pass form; the layer; the sigmoid
 router under a selection bias; the shared experts; the eight shares of
 an expert-parallel group adding up to the uncut layer; the recomputed
 decoder layer keeping its kernels' results."""
@@ -78,24 +79,126 @@ def close(got, want, tol=2e-5):
 
 
 # ------------------------------------------------------------ the kernels
+FORMS = ["one_pass", "two_pass"]
+
+
+@pytest.fixture
+def backward_form(request, monkeypatch):
+    """The backward in the form named: every shape here fits the
+    one-pass form's budget, so the two-pass form is reached by a budget
+    nothing fits."""
+    if request.param == "two_pass":
+        monkeypatch.setattr(latent, "_RESIDENT_VMEM", 0)
+    return request.param
+
+
+def kernel_grads(ops, w, h, causal, block=256):
+    return loss_and_grads(
+        lambda *a: latent.latent_flash_attention(
+            *a, n_head=h, causal=causal, block_q=block, block_k=block,
+            interpret=True), ops, w)
+
+
+def backward_builds():
+    """{form: how often the counter says the backward was built so}."""
+    builds = fused_builds()
+    return {form: builds.get(
+        '{kernel="flash_attention_latent_backward",path="%s"}' % form, 0)
+        for form in FORMS}
+
+
+def forms_built_since(before):
+    return {form for form, n in backward_builds().items()
+            if n > before[form]}
+
+
+def traced_grads(h, w):
+    """The four gradients on the interpreted causal kernels, a function
+    of the operands to trace."""
+    return jax.grad(lambda *a: jnp.sum(latent.latent_flash_attention(
+        *a, n_head=h, causal=True, interpret=True) * w),
+        argnums=(0, 1, 2, 3))
+
+
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("t,block", [(256, 256), (384, 128)],
                          ids=["one-tile", "t384-tiles-of-128"])
-def test_kernels_match_the_keys_written_out(causal, t, block):
+def test_kernels_match_the_keys_written_out(causal, t, block, backward_form):
     """Forward and the four gradients of the interpreted kernels (two
     heads a step, the shared rotary key twice in one tile) against
-    attention with 192-wide keys built plainly; 384 positions are no
-    multiple of the layers' 256-position tile and walk 128-tiles here.
+    attention with 192-wide keys built plainly, the backward in one
+    pass and as the dq and dkv kernels; 384 positions are no multiple
+    of the layers' 256-position tile and walk 128-tiles here.
     ``dk_pe`` is the sum over all four heads by construction of the
     formula it is held against."""
     *ops, w = operands(4, t)
-    got = loss_and_grads(
-        lambda *a: latent.latent_flash_attention(
-            *a, n_head=4, causal=causal, block_q=block, block_k=block,
-            interpret=True), ops, w)
+    before = backward_builds()
+    got = kernel_grads(ops, w, 4, causal, block)
     want = loss_and_grads(
         lambda *a: keys_written_out(*a, 4, 128, 128, causal), ops, w)
     close(got, want)
+    assert forms_built_since(before) == {backward_form}
+
+
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_dk_pe_is_the_sum_over_all_heads(causal, backward_form):
+    """32 heads are 16 head pairs, each on a grid step of its own: the
+    one-pass kernel writes a pair's share of ``dk_pe`` a key tile and
+    the sum over the pairs is taken outside it; the dkv kernel sums in
+    scratch.  Either is the shared key's whole cotangent."""
+    *ops, w = operands(32, 256, b=1)
+    got = kernel_grads(ops, w, 32, causal, block=128)
+    want = loss_and_grads(
+        lambda *a: keys_written_out(*a, 32, 128, 128, causal), ops, w)
+    close(got, want)
+    # and no one pair's share: the heads' shares do not cancel
+    two_heads = [ops[0][..., :256], ops[1][..., :128],
+                 jnp.concatenate([ops[2][..., :256],
+                                  ops[2][..., 32 * 128:32 * 128 + 256]], -1),
+                 ops[3]]
+    one_pair = kernel_grads(two_heads, w[..., :256], 2, causal, block=128)
+    assert float(jnp.max(jnp.abs(got[1][3] - one_pair[1][3]))) > \
+        0.1 * float(jnp.max(jnp.abs(want[1][3])))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_the_two_forms_agree_to_rounding(monkeypatch, causal):
+    """Same products, same dtypes, same ``lse``: the forms differ in the
+    order float32 sums are taken (dq over key tiles, ``delta`` and the
+    head pairs' ``dk_pe`` outside the kernel)."""
+    *ops, w = operands(4, 512, b=1, seed=3)
+    one = kernel_grads(ops, w, 4, causal, block=128)
+    monkeypatch.setattr(latent, "_RESIDENT_VMEM", 0)
+    two = kernel_grads(ops, w, 4, causal, block=128)
+    assert float(one[0]) == float(two[0])
+    close(one[1], two[1], 2e-6)
+
+
+def test_the_backward_form_follows_the_length(monkeypatch, pallas_calls):
+    """One pass wherever a head pair's whole dq (float32 accumulators,
+    two buffers of each output block) fits ``_RESIDENT_VMEM``; the dq
+    and dkv kernels past it.  The counter says which form a program
+    got."""
+    # the cell's 8,192 positions in bfloat16 fit; twice that, and the
+    # model's longest sequence, do not
+    assert latent._fits_resident(8192, 2)
+    assert not latent._fits_resident(16384, 2)
+    assert not latent._fits_resident(32768, 2)
+    # at float32: 384 lanes of 4 + 2 x 4 bytes a position
+    monkeypatch.setattr(latent, "_RESIDENT_VMEM", 256 * 384 * 12)
+    assert latent._fits_resident(256, 4) and not latent._fits_resident(
+        257, 4)
+    names = {256: {"flash_attention_latent_bwd"},
+             512: {"flash_attention_latent_dq", "flash_attention_latent_dkv"}}
+    for t, form in ((256, "one_pass"), (512, "two_pass")):
+        *ops, w = operands(6, t, b=1)
+        before = backward_builds()
+        calls = pallas_calls(traced_grads(6, w), *ops)
+        assert set(calls) == {"flash_attention_latent_fwd"} | names[t]
+        assert all(n == 1 for n in calls.values())
+        assert forms_built_since(before) == {form}
 
 
 @pytest.mark.parametrize("t,sizes", [(200, (128, 64, 128)),
@@ -141,10 +244,52 @@ def test_the_kernels_take_their_sizes_only():
                                       ops[3], n_head=2, interpret=True)
 
 
-def test_the_dkv_walk_goes_through_each_key_tile_once_a_head_pair():
-    """Per key tile: every head pair's run of q tiles, the run's own
-    first and last flagged (``dk_nope`` and ``dv`` leave there), the key
-    tile's first and last entry flagged once (``dk_pe`` leaves there)."""
+def pallas_grids(fn, *args):
+    """-> the grid of each ``pallas_call`` in ``fn(*args)``'s jaxpr, by
+    its ``name=``."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                walk(inner)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_the_one_pass_walk_goes_through_each_key_tile_once():
+    """The one-pass backward walks ``_tile_pairs``' by-k-tile list as it
+    stands, once a head pair (the pairs are a grid axis outside it):
+    each key tile's run of q tiles is contiguous and met once, its first
+    and last entry flagged (``dk_nope``, ``dv`` and the pair's ``dk_pe``
+    are zeroed and leave there), and the pairs are the by-q walk's."""
+    (aq, ak, _), (qi, ki, fl) = _tile_pairs("causal", 1024, 256, 256)
+    assert len(qi) == 10
+    assert list(ki) == sorted(ki)                    # key tile by key tile
+    assert sorted(zip(qi, ki)) == sorted(zip(aq, ak))
+    for k in range(4):
+        run = np.flatnonzero(ki == k)
+        assert list(run) == list(range(run[0], run[-1] + 1))
+        assert list(qi[run]) == list(range(k, 4))    # its q tiles, in order
+        assert fl[run[0]] & _FIRST and fl[run[-1]] & _LAST
+        assert (fl[run] & _FIRST != 0).sum() == 1
+        assert (fl[run] & _LAST != 0).sum() == 1
+    # the grid: (batch, head pairs, the walk), against the dkv kernel's
+    # (batch, the walk once a head pair)
+    *ops, w = operands(6, 1024, b=2)
+    grids = pallas_grids(traced_grads(6, w), *ops)
+    assert grids["flash_attention_latent_bwd"] == (2, 3, 10)
+
+
+def test_the_dkv_walk_goes_through_each_key_tile_once_a_head_pair(
+        monkeypatch):
+    """The two-pass form's dkv walk.  Per key tile: every head pair's
+    run of q tiles, the run's own first and last flagged (``dk_nope``
+    and ``dv`` leave there), the key tile's first and last entry flagged
+    once (``dk_pe`` leaves there)."""
     tiles = 3
     qi, ki, gi, fl = latent._dkv_walk("causal", 1024, 256, 256, tiles)
     _, (bq, bk, bf) = _tile_pairs("causal", 1024, 256, 256)
@@ -161,6 +306,11 @@ def test_the_dkv_walk_goes_through_each_key_tile_once_a_head_pair():
         assert (fl[here] & latent._KFIRST != 0).sum() == 1
         assert fl[here[0]] & latent._KFIRST and fl[here[-1]] & latent._KLAST
         assert (fl[here] & latent._KLAST != 0).sum() == 1
+    monkeypatch.setattr(latent, "_RESIDENT_VMEM", 0)
+    *ops, w = operands(6, 1024, b=2)
+    grids = pallas_grids(traced_grads(6, w), *ops)
+    assert grids["flash_attention_latent_dq"] == (2, 3, 10)
+    assert grids["flash_attention_latent_dkv"] == (2, 30)
 
 
 def test_the_tile_gauge_is_set_for_the_causal_walk():
@@ -390,8 +540,9 @@ def test_a_recomputed_layer_keeps_its_kernels_results(
     _, plain, args, _ = decoder_layer(False, sparse)
     once = pallas_calls(plain, *args)
     assert once["flash_attention_latent_fwd"] == 1
-    assert once["flash_attention_latent_dq"] == 1
-    assert once["flash_attention_latent_dkv"] == 1
+    assert once["flash_attention_latent_bwd"] == 1
+    assert not {"flash_attention_latent_dq",
+                "flash_attention_latent_dkv"} & set(once)
     assert kept() == before
     _, again, args, _ = decoder_layer(True, sparse)
     calls = pallas_calls(again, *args)

@@ -114,6 +114,9 @@ def _flash_latent(q, q_pe, kv, k_pe):
 # parts, the up-projection's result (k_nope | v) and the ONE rotary key
 LATENT = [(1, 8192, 32 * 192), (1, 8192, 32 * 64), (1, 8192, 32 * 256),
           (1, 8192, 64)]
+# twice as long: a head pair's whole dq no longer fits the one-pass
+# backward's VMEM budget, so the dq and dkv kernels are what compiles
+LATENT_LONG = [(1, 16384) + s[2:] for s in LATENT]
 
 
 def _grad(fn, n_args):
@@ -171,7 +174,9 @@ CASES = [
      [(1, 8192, 5120)], BF16, 3),
     ("flash-latent-t8192h32-128-64-128", _flash_latent, LATENT, BF16, 1),
     ("flash-latent-grad-t8192h32-128-64-128", _grad(_flash_latent, 4),
-     LATENT, BF16, 3),
+     LATENT, BF16, 2),
+    ("flash-latent-grad-t16384h32-128-64-128", _grad(_flash_latent, 4),
+     LATENT_LONG, BF16, 3),
     ("selective-scan-t8192c5120n16", _scan,
      [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16),
       (1, 8192, 16)], F32, 1),
@@ -227,7 +232,11 @@ NAMED = {
                                 "flash_attention_dkv"],
     "selective-scan-grad-t8192c5120n16": ["selective_scan_fwd",
                                           "selective_scan_bwd"],
+    # the one-pass backward holds a head pair's whole dq in VMEM: this
+    # is where a ``vmem_limit_bytes`` too small for it shows
     "flash-latent-grad-t8192h32-128-64-128": [
+        "flash_attention_latent_fwd", "flash_attention_latent_bwd"],
+    "flash-latent-grad-t16384h32-128-64-128": [
         "flash_attention_latent_fwd", "flash_attention_latent_dq",
         "flash_attention_latent_dkv"],
 }
@@ -417,13 +426,14 @@ def test_hybrid_decoder_layers_compile_for_v5e(v5e, one_chip_routing, on_tpu,
 def test_latent_decoder_layer_compiles_for_v5e(v5e, one_chip_routing, on_tpu):
     """One recomputed SPARSE decoder layer of the latent cell at its
     published widths and 8,192 positions, forward and backward, state
-    and all: Mosaic takes the three latent kernels and the layer reaches
-    them, each ONCE though the layer is recomputed (its policy keeps
-    the forward kernel's results); the grouped products' forward stands
-    twice, the expert layer's own recomputation.  No 192-wide key exists
-    (the logit is formed as a sum in the kernels), and the shared rotary
-    key is never broadcast to the heads: besides its (T, 64) self only
-    the (T, 128) tile holding it twice."""
+    and all: Mosaic takes the latent forward and one-pass backward
+    kernels and the layer reaches them, each ONCE though the layer is
+    recomputed (its policy keeps the forward kernel's results); the
+    grouped products' forward stands twice, the expert layer's own
+    recomputation.  No 192-wide key exists (the logit is formed as a
+    sum in the kernels), and the shared rotary key is never broadcast
+    to the heads: besides its (T, 64) self only the (T, 128) tile
+    holding it twice."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import latent, moe
     layer = latent.LatentDecoderLayer(
         latent.LatentAttention(32, 512, 128, 64, 128, rope_theta=1e6),
@@ -453,8 +463,8 @@ def test_latent_decoder_layer_compiles_for_v5e(v5e, one_chip_routing, on_tpu):
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(calls) == sorted(
-        ["flash_attention_latent_fwd", "flash_attention_latent_dq",
-         "flash_attention_latent_dkv"] + 2 * 3 * ["grouped_matmul_fwd"]
+        ["flash_attention_latent_fwd", "flash_attention_latent_bwd"]
+        + 2 * 3 * ["grouped_matmul_fwd"]
         + 3 * ["grouped_matmul_dlhs", "grouped_matmul_drhs"])
     # no (T, 32, 192) array: 192 is no dimension of anything
     assert not re.search(r"\[[\d,]*\b192\b[\d,]*\]", text)
