@@ -72,6 +72,14 @@ def init_zoo_context(conf: Optional[Dict[str, Any]] = None,
     if _context is not None:
         return _context
 
+    # the compile-stage clocks (docs/observability.md, "The start-up
+    # timeline") run from here on, so that they hold a model's
+    # initialisation and the caller's own programs, not only what
+    # compiles after the first trainer is built
+    from analytics_zoo_tpu.observability.diagnostics import (
+        install_compile_listener)
+    install_compile_listener()
+
     # Programmatic sets made BEFORE context init (get_config().set)
     # carry over; explicit init conf wins on conflicts.
     from analytics_zoo_tpu.common import config as config_mod

@@ -32,6 +32,7 @@ on a hot path.
 
 from __future__ import annotations
 
+import collections
 import logging
 import threading
 import time
@@ -41,6 +42,7 @@ import numpy as np
 
 from analytics_zoo_tpu.observability.metrics import (
     MetricsRegistry, get_registry)
+from analytics_zoo_tpu.observability.tracing import get_tracer
 
 log = logging.getLogger("analytics_zoo_tpu.observability")
 
@@ -97,22 +99,123 @@ def _tree_leaves(tree):
 _listener_lock = threading.Lock()
 _listener_installed = False
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
-def _backend_compile_listener(event: str, duration: float, **_kw) -> None:
-    """jax.monitoring duration listener: accumulate the runtime's own
-    compile clocks.  Never raises (it runs inside jax internals)."""
-    try:
-        if "compile" not in event:
-            return
+
+class _CompileSeries:
+    """The compile clocks' counters, made once a registry as
+    ``Tracer._count`` makes the span counters (tests swap the registry):
+    a compile fires three duration events and a cache answer, and a
+    jitted ``jnp`` function traced inside a train program fires one
+    each."""
+
+    def __init__(self):
+        self._registry = None
+
+    def get(self) -> "_CompileSeries":
         reg = get_registry()
-        if event.endswith("backend_compile_duration"):
-            reg.counter(
-                "jax_backend_compiles_total",
-                "XLA backend compilations (jax.monitoring)").inc()
-            reg.counter(
-                "jax_backend_compile_seconds_total",
-                "seconds inside XLA backend_compile "
-                "(jax.monitoring)").inc(float(duration))
+        if reg is not self._registry:
+            self._make(reg)
+        return self
+
+    def _make(self, reg) -> None:
+        self.backend_compiles = reg.counter(
+            "jax_backend_compiles_total",
+            "XLA backend compilations (jax.monitoring)")
+        self.backend_seconds = reg.counter(
+            "jax_backend_compile_seconds_total",
+            "seconds inside XLA backend_compile (jax.monitoring); a "
+            "persistent-cache hit's read from disk counts here too")
+        self.trace_seconds = reg.counter(
+            "jax_trace_seconds_total",
+            "seconds tracing a jitted function's Python body to a "
+            "jaxpr, less the traces nested in it: the series sum to "
+            "the time spent tracing", labels=("fn",))
+        self.traces = reg.counter(
+            "jax_traces_total",
+            "traces of a jitted function's Python body (a trace that "
+            "jit's own cache answers is none)", labels=("fn",))
+        self.lower_seconds = reg.counter(
+            "jax_lower_seconds_total",
+            "seconds lowering a traced program to an MLIR module, "
+            "less the traces nested in it", labels=("fn",))
+        self.cache_load_seconds = reg.counter(
+            "compile_cache_load_seconds_total",
+            "seconds reading executables from JAX's persistent "
+            "compilation cache: the part of jax_backend_compile_"
+            "seconds_total that is no compile")
+        self.cache_hits = reg.counter(
+            "compile_cache_hits_total",
+            "compiles answered by JAX's persistent compilation "
+            "cache (an executable read from disk)")
+        self.cache_misses = reg.counter(
+            "compile_cache_misses_total",
+            "compiles JAX's persistent compilation cache could "
+            "not answer and has written (full XLA compile paid; "
+            "one shorter than jax_persistent_cache_min_compile_"
+            "time_secs counts as neither)")
+        self._registry = reg
+
+
+_series = _CompileSeries()
+_stage_local = threading.local()
+
+
+def _stage_self_seconds(duration: float) -> float:
+    """``duration`` of the trace or lowering that has just ended on
+    this thread, less the stages that ran inside it.  JAX reports a
+    stage when it ENDS, so the nested ones have been reported already:
+    they are the kept stages that started no earlier than this one.
+    What is left of every stage adds up to the wall time the thread
+    spent tracing and lowering, which the raw durations overstate (a
+    train program's trace holds the trace of every jitted function and
+    kernel called in it)."""
+    kept = _stage_local.__dict__.setdefault(
+        "kept", collections.deque(maxlen=4096))
+    start = time.time() - duration   # JAX's own clock for these events
+    nested = 0.0
+    while kept and kept[-1][0] >= start - 1e-4:
+        nested += kept.pop()[1]
+    kept.append((start, duration))
+    return max(duration - nested, 0.0)
+
+
+def _fn_label(fun_name: Optional[str]) -> str:
+    """JAX names a trace by the Python function and a lowering by the
+    module (``jit(<function>)``, ``jit_<function>`` in other
+    releases): one label for both."""
+    if not fun_name:
+        return "?"
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name[4:] if fun_name.startswith("jit_") else fun_name
+
+
+def _backend_compile_listener(event: str, duration: float,
+                              fun_name: Optional[str] = None,
+                              **_kw) -> None:
+    """jax.monitoring duration listener: accumulate the runtime's own
+    compile clocks by stage (trace, lowering, backend compile, the
+    persistent cache's read).  Never raises (it runs inside jax
+    internals)."""
+    try:
+        if event == _TRACE_EVENT:
+            series, fn = _series.get(), _fn_label(fun_name)
+            series.traces.labels(fn).inc()
+            series.trace_seconds.labels(fn).inc(
+                _stage_self_seconds(float(duration)))
+        elif event == _LOWER_EVENT:
+            _series.get().lower_seconds.labels(_fn_label(fun_name)).inc(
+                _stage_self_seconds(float(duration)))
+        elif event == _BACKEND_EVENT:
+            series = _series.get()
+            series.backend_compiles.inc()
+            series.backend_seconds.inc(float(duration))
+        elif event == _CACHE_LOAD_EVENT:
+            _series.get().cache_load_seconds.inc(float(duration))
     except Exception:
         pass
 
@@ -122,17 +225,9 @@ def _persistent_cache_listener(event: str, **_kw) -> None:
     cache answered.  Never raises (it runs inside jax internals)."""
     try:
         if event == "/jax/compilation_cache/cache_hits":
-            get_registry().counter(
-                "compile_cache_hits_total",
-                "compiles answered by JAX's persistent compilation "
-                "cache (an executable read from disk)").inc()
+            _series.get().cache_hits.inc()
         elif event == "/jax/compilation_cache/cache_misses":
-            get_registry().counter(
-                "compile_cache_misses_total",
-                "compiles JAX's persistent compilation cache could "
-                "not answer and has written (full XLA compile paid; "
-                "one shorter than jax_persistent_cache_min_compile_"
-                "time_secs counts as neither)").inc()
+            _series.get().cache_misses.inc()
     except Exception:
         pass
 
@@ -306,6 +401,10 @@ class CompileMonitor:
         donated, deleted buffer still has."""
         if not self.cost_analysis:
             return
+        with get_tracer().span("startup_cost_analysis", fn=name):
+            self._cost_analysis(name, fn, args)
+
+    def _cost_analysis(self, name: str, fn, args) -> None:
         try:
             import jax
 

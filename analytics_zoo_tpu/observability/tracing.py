@@ -52,7 +52,13 @@ TRAIN_TIMELINE_SPANS = {
              "train_permute", "train_loss_sync", "train_device_sync",
              "train_boundary", "data_wait", "checkpoint_save",
              "checkpoint_restore", "eval", "aot_warm_start",
-             "moe_stats_read"),
+             "moe_stats_read",
+             # the job's two edges (docs/observability.md, "The
+             # start-up timeline"): once a model or a train()
+             "startup_init_variables", "train_startup",
+             "startup_place_state", "startup_loader",
+             "startup_first_dispatch", "startup_cost_analysis",
+             "train_return"),
     "prefetch": ("data_assemble", "data_place"),
     "worker": ("data_build",),
     "callback": ("callback_grad_norm",),
@@ -248,6 +254,14 @@ class Tracer:
     def events(self) -> List[Dict]:
         with self._lock:
             return list(self._events)
+
+    def events_since(self, start_perf: float) -> List[Dict]:
+        """The calling thread's complete spans that started at or
+        after ``start_perf``, a ``time.perf_counter()`` reading."""
+        ts = (start_perf - self._t0) * 1e6
+        tid = threading.get_ident()
+        return [e for e in self.events() if e["ph"] == "X"
+                and e["tid"] == tid and e["ts"] >= ts]
 
     def chrome_trace(self) -> Dict:
         """The Chrome trace 'JSON Object Format': Perfetto and

@@ -182,6 +182,13 @@ class DistributedTrainer:
         self._m_steps = reg.counter(
             "train_steps_total", "train steps dispatched",
             labels=("path",))
+        # counted in the Python body of each train program, so once a
+        # TRACE of it and never at run time: a first call, a warm-start
+        # and a cost analysis whose signature jit has not traced yet
+        self._m_program_traces = reg.counter(
+            "train_program_traces_total",
+            "traces of a train program's Python body, by the engine "
+            "it is the program of", labels=("path",))
         self._m_prefetch_depth = reg.gauge(
             "train_prefetch_queue_depth",
             "device-placed batches waiting in the prefetch queue")
@@ -232,6 +239,10 @@ class DistributedTrainer:
             "device step time / chip peak (observability.peak_flops "
             "overrides the denominator)")
         self._dispatch_count = 0
+        # called ONCE, after the next sampled device sync: the
+        # per-step path's first proof that a step has finished
+        # (Estimator.train's start-up timeline sets it)
+        self.after_device_sync: Optional[Callable[[], None]] = None
 
     # ------------------------------------------------------------ sharding
     def param_shardings(self, params):
@@ -416,17 +427,25 @@ class DistributedTrainer:
         ``fold_rng`` the program takes (.., rng, step) and derives the
         per-step rng in-jit."""
         donate = (0, 1, 2) if self.donate else ()
+        traces = self._m_program_traces.labels("per_step")
+        # named as their key_hint: jax.monitoring reports a program's
+        # trace and lowering under its function's name
         if fold_rng:
-            fn = lambda p, o, s, b, r, i: self._step_checked(  # noqa: E731
-                p, o, s, b, jax.random.fold_in(r, i))
+            def train_step_at(p, o, s, b, r, i):
+                traces.inc()
+                return self._step_checked(
+                    p, o, s, b, jax.random.fold_in(r, i))
+            fn = train_step_at
         else:
-            fn = self._step_checked
+            def train_step(p, o, s, b, r):
+                traces.inc()
+                return self._step_checked(p, o, s, b, r)
+            fn = train_step
         jitted = engine_jit(
             fn,
             out_shardings=(self._param_shardings, None, self._rep,
                            self._rep, self._rep),
-            donate_argnums=donate,
-            key_hint="train_step_at" if fold_rng else "train_step")
+            donate_argnums=donate, key_hint=fn.__name__)
         # compile/recompile accounting + cost-analysis FLOPs for the
         # live MFU gauge (diagnostics.CompileMonitor)
         return self._monitor.wrap("train_step", jitted)
@@ -486,6 +505,10 @@ class DistributedTrainer:
                     # the host has just blocked on this step: every
                     # pending flag is ready
                     self.drain_finite()
+                    if self.after_device_sync is not None:
+                        done, self.after_device_sync = \
+                            self.after_device_sync, None
+                        done()
         if self._collective_bytes:
             from analytics_zoo_tpu.observability.collectives import (
                 record_step_collectives)
@@ -625,7 +648,7 @@ class DistributedTrainer:
 
     # ------------------------------------------------- device-resident epoch
     def epoch_scan_fn(self, num_batches: int, batch_size: int,
-                      unroll: int = 1):
+                      unroll: int = 1, path: str = "epoch_scan"):
         """Whole-epoch trainer over DEVICE-RESIDENT data — the HBM tier
         of the FeatureSet cache hierarchy (the reference's DRAM cache,
         FeatureSet.scala:229-329, moved all the way onto the chip).
@@ -647,6 +670,10 @@ class DistributedTrainer:
         consume exactly the whole epoch.  When ``put_batch`` falls back
         to REPLICATING (dp doesn't divide across hosts), global rows ==
         local rows and the slice stays ``batch_size``.
+
+        ``path`` is the engine the caller dispatches the program on, as
+        ``train_steps_total{path}`` names it (``epoch_scan`` or
+        ``chunked``): the label its traces are counted under.
         """
         local_bs = mesh_lib.local_batch_size(self.mesh, batch_size)
         del local_bs   # validation only
@@ -662,7 +689,10 @@ class DistributedTrainer:
         nproc = jax.process_count() \
             if mesh_lib.data_split_across_hosts(self.mesh) else 1
 
+        traces = self._m_program_traces.labels(path)
+
         def epoch(params, opt_state, state, x, y, rng, start_step=0):
+            traces.inc()
             # rng for step i is fold_in(rng, start_step + i): with
             # start_step = the global iteration counter this matches
             # the per-step path's fold_in(rng, ts.iteration) exactly,

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.observability.tracing import get_tracer
 from analytics_zoo_tpu.pipeline.api.keras.engine import (
     Container, KTensor, Layer, Node, Params, State, fold_name,
     tap_activation, to_batch_shape, _is_shape,
@@ -59,7 +60,11 @@ class KerasNet(Container):
 
     def get_variables(self):
         if self._variables is None:
-            self.init()
+            # every leaf is drawn here, on the default device: a
+            # start-up phase of its own (a caller that then lays its
+            # own weights over them has paid for both)
+            with get_tracer().span("startup_init_variables"):
+                self.init()
         return self._variables
 
     def set_variables(self, variables):

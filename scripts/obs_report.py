@@ -147,6 +147,58 @@ def _table(rows: List[List[str]], header: List[str]) -> str:
     return "\n".join([fmt(header), sep] + [fmt(r) for r in rows])
 
 
+def _label_value(lab: str) -> str:
+    return lab.split("=", 1)[-1].strip('"') if lab else "?"
+
+
+def _startup_timeline(counters: Dict, gauges: Dict) -> List[str]:
+    """The job's two edges (docs/observability.md, "The start-up
+    timeline"): the start-up spans' seconds, the time to the first
+    finished step, and the exact compile stages by function."""
+    own = {_label_value(lab): v for lab, v in
+           _labeled(counters, "span_self_seconds_total")}
+    total = {_label_value(lab): v for lab, v in
+             _labeled(counters, "span_seconds_total")}
+    edge = sorted((n for n in total if n.startswith("startup_")
+                   or n in ("train_startup", "aot_warm_start",
+                            "train_return")),
+                  key=lambda n: -own.get(n, 0.0))
+    lines: List[str] = []
+    if edge:
+        lines += ["", "start-up timeline (self = less the spans nested "
+                  "in it; train_startup's self is what no phase names):",
+                  _table([[n, f"{total[n]:.2f}s",
+                           f"{own.get(n, 0.0):.2f}s"] for n in edge],
+                         ["span", "seconds", "self"])]
+    first = gauges.get("train_time_to_first_step_seconds")
+    if first:
+        lines.append(f"time to first step: {first:.2f}s (newest train())")
+    stages: Dict[str, List[float]] = {}
+    for i, family in enumerate(("jax_traces_total",
+                                "jax_trace_seconds_total",
+                                "jax_lower_seconds_total")):
+        for lab, v in _labeled(counters, family):
+            stages.setdefault(_label_value(lab), [0.0, 0.0, 0.0])[i] = v
+    if stages:
+        top = sorted(stages.items(), key=lambda kv: -(kv[1][1] + kv[1][2]))
+        lines += ["", "compile stages (exact, jax.monitoring; each less "
+                  "the stages nested in it; first-call wall above is an "
+                  f"upper bound): {len(top)} functions, "
+                  f"{sum(v[1] for v in stages.values()):.2f}s tracing, "
+                  f"{sum(v[2] for v in stages.values()):.2f}s lowering",
+                  _table([[fn, int(v[0]), f"{v[1]:.2f}s", f"{v[2]:.2f}s"]
+                          for fn, v in top[:8]],
+                         ["function", "traces", "trace", "lower"])]
+    load = counters.get("compile_cache_load_seconds_total")
+    if load:
+        lines.append(f"executable cache reads: {load:.2f}s of the "
+                     "backend compile seconds")
+    for lab, n in _labeled(counters, "train_program_traces_total"):
+        lines.append(f"train program [{_label_value(lab)}]: traced "
+                     f"{int(n)} time(s)")
+    return lines
+
+
 # --------------------------------------------------------------- report
 def render_report(label: str, snap: Dict,
                   trace_events: Optional[List[Dict]] = None) -> str:
@@ -161,7 +213,7 @@ def render_report(label: str, snap: Dict,
         total_time = sum(h["sum"] for _, h in attr) or 1e-12
         rows = []
         for lab, h in attr:
-            comp = lab.split("=", 1)[-1].strip('"') if lab else "?"
+            comp = _label_value(lab)
             rows.append([
                 comp, h["count"], _fmt_seconds(h["p50"]),
                 _fmt_seconds(h["p95"]), f"{h['sum']:.2f}s",
@@ -198,7 +250,7 @@ def render_report(label: str, snap: Dict,
     # ---- compilation ----------------------------------------------
     comp_rows = []
     for lab, n in _labeled(counters, "jax_compiles_total"):
-        fn = lab.split("=", 1)[-1].strip('"') if lab else "?"
+        fn = _label_value(lab)
         secs = dict(_labeled(counters, "jax_compile_seconds_total")
                     ).get(lab, 0.0)
         rec = dict(_labeled(counters, "jax_recompiles_total")
@@ -223,6 +275,8 @@ def render_report(label: str, snap: Dict,
         lines.append(
             f"executable cache: {int(hits)} hit(s) / {int(misses)} "
             f"miss(es) ({rate:.0f}% hit rate)")
+
+    lines += _startup_timeline(counters, gauges)
 
     # ---- fused kernel suite / roofline (docs/perf-tuning.md) -------
     builds = _labeled(counters, "fused_kernel_builds_total")

@@ -231,6 +231,12 @@ class TestSecondProcessWarmStart:
         # train/predict bit-identical to the cold run
         assert warm["cache_hits"] == cold["cache_misses"]
         assert warm["cache_misses"] == 0
+        # ... and so is what compiles between init_zoo_context, where
+        # the listener runs from since PR 35, and the compile monitor,
+        # from where the worker counts the four numbers above
+        early = cold["before_monitor"]
+        assert warm["before_monitor"] == {
+            "hits": early["hits"] + early["misses"], "misses": 0}
         assert warm["train_step_compiles"] == 1
         assert warm["recompiles_after_warmup"] == 0
         assert warm["params_digest"] == cold["params_digest"]
