@@ -10,12 +10,34 @@ ring handles the inter-chip blocks, this kernel is what each chip
 should run on its local block.
 
 ``flash_attention_token_major`` (and ``flash_attention``, the same for
-callers that hold head-major arrays) is differentiable: a
-``custom_vjp`` routes the backward through two Pallas kernels (the
-standard flash-attention backward — recompute the probability blocks
-from the forward's saved log-sum-exp, then ``dv = PᵀdO``,
-``ds = P∘(dOVᵀ - D)``, ``dq = dsK``, ``dk = dsᵀQ``), so the same memory
-bound holds in training.
+callers that hold head-major arrays) is differentiable: the forward
+kernel's results carry a ``custom_vjp`` whose backward is the standard
+flash-attention backward (recompute the probability blocks from the
+forward's saved log-sum-exp, then ``dv = PᵀdO``, ``ds = P∘(dOVᵀ - D)``,
+``dq = dsK``, ``dk = dsᵀQ``), so the same memory bound holds in
+training.  It has two forms, chosen by the operands' shapes alone
+(``_fits_resident``; ``fused_kernel_builds_total{kernel=
+"flash_attention_backward",path="one_pass"|"two_pass"}`` says which a
+program got), whose arithmetic a (tile pair, head) is ONE function
+(``_key_side``):
+
+* **one pass** (``flash_attention_bwd``), wherever a query tile's WHOLE
+  dq and its K/V tile's whole dk and dv fit ``_RESIDENT_VMEM`` (float32
+  accumulators and two buffers of each output block: 8,192 positions of
+  128 lanes in bfloat16 do, 24 MiB).  The grid is (batch, K/V lane
+  tile, query tile of its group, walk), the walk ``_tile_pairs``'
+  by-k-tile list: for each key tile its query tiles.  Sᵀ, the mask, Pᵀ,
+  dPᵀ and dSᵀ are formed ONCE a (tile pair, head) and feed all three
+  gradients: five products.  dq accumulates in float32 scratch addressed
+  by the query tile's rows and leaves once a walk; dk and dv accumulate
+  by the key tile's rows over the walks of ALL the group's query tiles
+  and leave once a K/V tile, so no per-query-head dk/dv exists in HBM.
+  ``D`` = rowsum(dO∘O) is formed before the kernel, by XLA.
+* **two passes** (``flash_attention_dq`` + ``_dkv``) past that length
+  (16,384 positions at 128 lanes): the dq kernel walks by query tile
+  (and forms ``D``), the dkv kernel by key tile with the group's query
+  heads innermost; each forms S, P, dP and dS for itself, seven
+  products.
 
 Layout: the operands lie where a projection wrote them, (B, T, H·D),
 and a head is a BLOCK OF THE LAST DIMENSION, picked by the index map:
@@ -35,7 +57,7 @@ time, so no sequence length is capped by VMEM.  The pairs axis walks a
 STATIC list of (q tile, k tile) pairs, worked out on the host from the
 mask's description and handed to the kernels as scalar-prefetch tables:
 a tile with no allowed pair is not in the list, so it costs neither a
-grid step nor a DMA, in the forward, dq and dkv kernels alike; a tile
+grid step nor a DMA, in the forward and the backward kernels alike; a tile
 every pair of which is allowed skips the mask's arithmetic.  The mask
 itself is evaluated from iotas inside the kernel (``_key_interval``:
 each query row may read one interval of key positions in a key tile),
@@ -44,8 +66,8 @@ band of ``W`` keys) and ``block_diffusion(L, B)``.
 
 Grouped-query attention: ``k``/``v`` may have fewer heads than ``q``;
 query head ``h`` reads K/V head ``h // group`` through the index map,
-and the dkv kernel walks the group's query heads itself, so no repeated
-K/V and no per-query-head dk/dv exists in HBM.  K and V are operands
+and the backward kernels walk the group's query heads themselves, so no
+repeated K/V and no per-query-head dk/dv exists in HBM.  K and V are operands
 like any other: they may be another layer's.
 
 The differential pair (``differential=True``): 64-wide heads in pairs, a
@@ -68,7 +90,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.compile.engine import engine_jit
-from analytics_zoo_tpu.ops.fused import keep_result
+from analytics_zoo_tpu.ops.fused import count_build, keep_result
 
 NEG = -1e30
 # flags of one (q tile, k tile) pair in a kernel's walk
@@ -107,9 +129,9 @@ def _key_interval(mask, q_pos, k_clean, xp):
     """``(lo, hi)``: query position ``q_pos`` may read the keys at
     positions ``lo <= k < hi`` of a key tile (``k_clean``: whether that
     tile lies in the clean half; unused by ``causal``).  THE definition
-    of the masks: the host's tile tables (``xp = numpy``) and the three
-    kernels (``xp = jax.numpy``, on a row or a column of positions) all
-    call it, so P is recomputed under the identical mask."""
+    of the masks: the host's tile tables (``xp = numpy``) and every
+    kernel (``xp = jax.numpy``, on a row or a column of positions) call
+    it, so P is recomputed under the identical mask."""
     if mask == "causal":
         return xp.zeros_like(q_pos), q_pos + 1
     if isinstance(mask, SlidingWindowMask):
@@ -141,8 +163,8 @@ def allowed_pairs(mask, t: int) -> np.ndarray:
 def _tile_pairs(mask, t: int, block_q: int, block_k: int):
     """The (q tile, k tile) pairs that hold an allowed pair, in the
     order the forward and dq kernels walk them (by q tile) and in the
-    order the dkv kernel does (by k tile), each as int32 arrays
-    ``(q_idx, k_idx, flags)``."""
+    order the one-pass backward and the dkv kernel do (by k tile), each
+    as int32 arrays ``(q_idx, k_idx, flags)``."""
     nq, nk = t // block_q, t // block_k
     if mask is None:
         any_ = np.ones((nq, nk), bool)
@@ -241,6 +263,20 @@ def _half(mask, t: int) -> int:
 
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 _LANES = 128
+# VMEM the one-pass backward may hold for what stays resident over a
+# walk: the float32 accumulators of one query tile's WHOLE dq and of its
+# K/V tile's whole dk and dv, and the two buffers of each of the three
+# output blocks (24 MiB of it at 8,192 positions of 128 lanes in
+# bfloat16).  A longer sequence takes the dq and dkv kernels.
+_RESIDENT_VMEM = 32 << 20
+# What a kernel may use unasked on the v5e, and what the tiles and the
+# intermediates of a tile pair take of it at block 512: the one-pass
+# kernel asks for this much beside what it keeps resident and no more,
+# because XLA keeps arrays between operations in the VMEM that no kernel
+# claims (a limit of 64 MiB on the GPT cell's backward, which keeps
+# under 1 MiB resident, cost the step 6.8 ms in copies and slower
+# fusions: my chip runs, PR 36).
+_SCOPED_VMEM = 16 << 20
 
 
 def _col_to_row(col):
@@ -433,20 +469,45 @@ def _flash_dq_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
+def _key_side(j: int, per: int, q, k_blk, v_blk, do, lse, delta, masked):
+    """Head ``j`` of one tile pair with the logits held TRANSPOSED,
+    ``(block_k, block_q)``, so that ``lse`` and ``delta`` enter as
+    lane-dense rows and every product is a plain or an ``a @ b.T`` one:
+    forms P^T, dP^T and dS^T and returns dS^T (cast for its products)
+    with the head's dk and dv.  THE backward arithmetic of a (pair,
+    head): the dkv kernel and the one-pass kernel both call it.  ``q``
+    enters pre-scaled in the input dtype, as the forward formed it (so
+    ``exp(s - lse)`` reproduces the forward's P: a higher-precision
+    recompute would desynchronise from the saved lse under bf16), and so
+    the scale is already in dk's accumulation."""
+    width = q.shape[1]
+    if do.shape[1] != width:             # the differential pair
+        q_j, do_j = _head_lanes(j, per, q)[0], do[:, _own(j, width)]
+    else:
+        q_j, do_j = _head_lanes(j, per, q, do)
+    s_t = masked(jax.lax.dot_general(k_blk, q_j, _NT,
+                                     preferred_element_type=jnp.float32))
+    p_t = jnp.exp(s_t - lse)                                # (bk, bq)
+    dv_j = jnp.dot(p_t.astype(do.dtype), do_j,
+                   preferred_element_type=jnp.float32)
+    dp_t = jax.lax.dot_general(v_blk, do_j, _NT,
+                               preferred_element_type=jnp.float32)
+    ds_t = (p_t * (dp_t - delta)).astype(q.dtype)
+    dk_j = jnp.dot(ds_t, q_j, preferred_element_type=jnp.float32)
+    return ds_t, dk_j, dv_j
+
+
 def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       mask, mask_all: bool, scale: float, block_q: int,
                       block_k: int, half: int):
-    """dk/dv for one k tile of one lane tile's K/V heads: the grid walks
-    the tile's q tiles and, innermost, the query heads that share the
-    K/V head, accumulating into VMEM scratch (TPU pallas runs the grid
-    in order on a core) and writing the tile once.  The logits are held
-    TRANSPOSED, (block_k, block_q), so lse and delta enter as lane-dense
-    rows and every product is a plain or an ``a @ b.T`` one."""
+    """dk/dv for one k tile of one lane tile's K/V heads (the two-pass
+    form): the grid walks the tile's q tiles and, innermost, the query
+    heads that share the K/V head, accumulating into VMEM scratch (TPU
+    pallas runs the grid in order on a core) and writing the tile
+    once."""
     p_id, g = pl.program_id(2), pl.program_id(3)
     flags = fl_ref[p_id]
-    per, width = lse_ref.shape[0], q_ref.shape[1]
-    differential = do_ref.shape[1] != width
 
     @pl.when(((flags & _FIRST) != 0) & (g == 0))
     def _init():
@@ -454,37 +515,87 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     k_blk, v_blk, do = k_ref[...], v_ref[...], do_ref[...]
-    # same-dtype q*scale as the forward (see dq kernel note)
     q = q_ref[...] * scale
     k_start = ki_ref[p_id] * block_k
     q_pos = _positions(qi_ref[p_id] * block_q, block_q, 1)
     k_pos = _positions(k_start, block_k, 0)
+
+    def masked(s_t):
+        return _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
+                       k_start >= half)
+
+    per = lse_ref.shape[0]
     dk = dv = None
     for j in range(per):
-        if differential:
-            q_j, do_j = _head_lanes(j, per, q)[0], do[:, _own(j, width)]
-        else:
-            q_j, do_j = _head_lanes(j, per, q, do)
-        s_t = jax.lax.dot_general(k_blk, q_j, _NT,
-                                  preferred_element_type=jnp.float32)
-        s_t = _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
-                      k_start >= half)
-        p_t = jnp.exp(s_t - lse_ref[j])                     # (bk, bq)
-        dv_j = jnp.dot(p_t.astype(do.dtype), do_j,
-                       preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(v_blk, do_j, _NT,
-                                   preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[j])
-        # dk = Σ ds_ijᵀ (scale·q_i): q enters pre-scaled, so the scale
-        # is already in the accumulation
-        dk_j = jnp.dot(ds_t.astype(q.dtype), q_j,
-                       preferred_element_type=jnp.float32)
+        _, dk_j, dv_j = _key_side(j, per, q, k_blk, v_blk, do, lse_ref[j],
+                                  delta_ref[j], masked)
         dk, dv = (dk_j, dv_j) if dk is None else (dk + dk_j, dv + dv_j)
     dk_acc[...] += dk
     dv_acc[...] += dv
 
     @pl.when(((flags & _LAST) != 0) & (g == pl.num_programs(3) - 1))
     def _store():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                      dk_acc, dv_acc, *, mask, mask_all: bool, scale: float,
+                      block_q: int, block_k: int, half: int):
+    """All three gradients in one pass (grid: batch, K/V lane tile,
+    query tile of its group, the by-k walk): P, dP and dS are formed
+    ONCE a (tile pair, head), transposed (``_key_side``, the dkv
+    kernel's), and feed dk and dv of the key tile's rows and, through
+    dS's transpose, dq of the q tile's rows.  The query tile's whole dq
+    stays in ``dq_acc`` over its walk and leaves on the walk's last
+    entry; the K/V tile's whole dk and dv stay in ``dk_acc`` /
+    ``dv_acc`` over the walks of ALL the group's query tiles and leave
+    once, so rows no pair reaches leave as the zeros they were set to."""
+    g, p_id = pl.program_id(2), pl.program_id(3)
+    flags = fl_ref[p_id]
+    first, last = p_id == 0, p_id == pl.num_programs(3) - 1
+
+    @pl.when(first)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first & (g == 0))
+    def _init_shared():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    k_blk, v_blk, do = k_ref[...], v_ref[...], do_ref[...]
+    q = q_ref[...] * scale
+    q_start = pl.multiple_of(qi_ref[p_id] * block_q, block_q)
+    k_start = pl.multiple_of(ki_ref[p_id] * block_k, block_k)
+    q_pos = _positions(q_start, block_q, 1)
+    k_pos = _positions(k_start, block_k, 0)
+
+    def masked(s_t):
+        return _masked(s_t, mask, mask_all, flags, q_pos, k_pos,
+                       k_start >= half)
+
+    per = lse_ref.shape[0]
+    dq = dk = dv = None
+    for j in range(per):
+        ds_t, dk_j, dv_j = _key_side(j, per, q, k_blk, v_blk, do,
+                                     lse_ref[j], delta_ref[j], masked)
+        dq_j = jnp.dot(ds_t.T, _head_lanes(j, per, k_blk)[0],
+                       preferred_element_type=jnp.float32)  # (bq, width)
+        dq, dk, dv = (dq_j, dk_j, dv_j) if dq is None \
+            else (dq + dq_j, dk + dk_j, dv + dv_j)
+    dq_acc[pl.ds(q_start, block_q), :] += dq
+    k_rows = pl.ds(k_start, block_k)
+    dk_acc[k_rows, :] += dk
+    dv_acc[k_rows, :] += dv
+
+    @pl.when(last)
+    def _store():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last & (g == pl.num_programs(2) - 1))
+    def _store_shared():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -656,8 +767,28 @@ def _attach_fwd(ops, out, lse, cfg):
     return out, (ops, out, lse)
 
 
+def _resident_bytes(t: int, width: int, itemsize: int) -> int:
+    """What the one-pass backward keeps in VMEM over a walk: a query
+    tile's whole dq and its K/V tile's whole dk and dv over ``t``
+    positions of ``width`` lanes, each a float32 accumulator and the two
+    buffers of its output block.  The group's size does not enter: its
+    query tiles go by one at a time."""
+    return 3 * t * width * (4 + 2 * itemsize)
+
+
+def _fits_resident(t: int, width: int, itemsize: int) -> bool:
+    """The one-pass backward's condition."""
+    return _resident_bytes(t, width, itemsize) <= _RESIDENT_VMEM
+
+
 def _attach_bwd(cfg, res, dout):
-    return (*_backward(res, dout, cfg), None, None)
+    ops = res[0]
+    hd = _heads(ops, *cfg[5:])
+    one_pass = _fits_resident(ops[0].shape[1], hd.width,
+                              ops[0].dtype.itemsize)
+    count_build("flash_attention_backward",
+                "one_pass" if one_pass else "two_pass")
+    return (*_backward(res, dout, cfg, one_pass), None, None)
 
 
 def _side_by_side(parts):
@@ -675,7 +806,101 @@ def _side_by_side(parts):
         for i, a in enumerate(parts)))
 
 
-def _flash_bwd_impl(res, dout, cfg):
+def _flash_bwd_impl(res, dout, cfg, one_pass: bool):
+    """The kernels' three gradients, in either form, as the cotangent
+    of ``ops``."""
+    dq, dk, dv = (_one_pass if one_pass else _two_pass)(res, dout, cfg)
+    if len(res[0]) == 1:
+        return (_side_by_side([dq, dk, dv]),),
+    return (dq, dk, dv),
+
+
+def _by_k_specs(block_q: int, block_k: int, hd: _Heads, walk_first: bool):
+    """Block specs of a walk by k tile.  Grid: batch, K/V lane tile,
+    then the walk and the query tiles of the K/V tile's group, in that
+    order if ``walk_first`` (the dkv kernel's) and the other way round
+    if not (the one-pass kernel's).  A walk entry's q-sized tile of the
+    query tile's lanes (``width``: of its output's or cotangent's) and
+    its K/V tile, or with ``whole`` positions EVERY position of those
+    lanes as one block that stays while the walk goes by; the query
+    tile's lane-dense rows."""
+    def at(index):
+        if walk_first:
+            return lambda b, i, p, g, qi, ki, fl: index(b, i, g, p, qi, ki)
+        return lambda b, i, g, p, qi, ki, fl: index(b, i, g, p, qi, ki)
+
+    def q_tile(off=0, width=hd.width, whole=0):
+        return pl.BlockSpec(
+            (None, whole or block_q, width),
+            at(lambda b, i, g, p, qi, ki: (b, 0 if whole else qi[p],
+                                           off + i * hd.group + g)))
+
+    def k_tile(off=0, whole=0):
+        return pl.BlockSpec(
+            (None, whole or block_k, hd.width),
+            at(lambda b, i, g, p, qi, ki: (b, 0 if whole else ki[p],
+                                           off + i)))
+
+    rows = pl.BlockSpec(
+        (hd.per, 1, block_q),
+        at(lambda b, i, g, p, qi, ki: (b * hd.tiles + i * hd.group + g,
+                                       0, qi[p])))
+    return q_tile, k_tile, rows
+
+
+def _delta_rows(dout, out, h: int):
+    """rowsum(dO * O) a head (its ``lanes`` of ``out``: 128 under the
+    differential pair) as lane-dense ``(B·H, 1, T)`` rows beside lse's,
+    for a backward that walks by k tile: it meets a q tile once a key
+    tile, so no pair is a q tile's first.  Summed as ONE product with
+    the heads' lanes at float32's precision, a fusion over dO and O; as
+    a reduction over a head's lanes XLA writes the float32 product out
+    and relayouts it (1 ms a layer at 8,192 positions on the v5e)."""
+    b, t, width = out.shape
+    lanes = width // h
+    own = jnp.arange(width) // lanes == jnp.arange(h)[:, None]
+    return jnp.einsum(
+        "hl,btl->bht", own.astype(jnp.float32),
+        dout.astype(jnp.float32) * out.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST).reshape(b * h, 1, t)
+
+
+def _one_pass(res, dout, cfg):
+    mask, scale, block_q, block_k, interpret, h, h_kv, differential = cfg
+    ops, out, lse = res
+    hd = _heads(ops, h, h_kv, differential)
+    q, k, v = ops if len(ops) == 3 else ops * 3
+    b, t = q.shape[:2]
+    _, by_k = _tile_pairs(mask, t, block_q, block_k)
+    q_off, k_off, v_off = hd.offsets
+    delta = _delta_rows(dout, out, h)
+
+    q_tile, k_tile, rows = _by_k_specs(block_q, block_k, hd, False)
+    kv_shape = (b, t, h_kv * hd.d)
+    resident = pltpu.VMEM((t, hd.width), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, **_statics(cfg, t)),
+        out_shape=(jax.ShapeDtypeStruct((b, t, h * hd.d), q.dtype),
+                   jax.ShapeDtypeStruct(kv_shape, k.dtype),
+                   jax.ShapeDtypeStruct(kv_shape, v.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, hd.tiles // hd.group, hd.group, len(by_k[0])),
+            in_specs=[q_tile(q_off), k_tile(k_off), k_tile(v_off),
+                      q_tile(0, hd.out_width), rows, rows],
+            out_specs=(q_tile(whole=t), k_tile(whole=t), k_tile(whole=t)),
+            scratch_shapes=[resident, resident, resident]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_SCOPED_VMEM + _resident_bytes(
+                t, hd.width, q.dtype.itemsize)),
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )(*_tables(by_k), q, k, v, dout, lse, delta)
+
+
+def _two_pass(res, dout, cfg):
     mask, scale, block_q, block_k, interpret, h, h_kv, differential = cfg
     ops, out, lse = res
     hd = _heads(ops, h, h_kv, differential)
@@ -710,21 +935,7 @@ def _flash_bwd_impl(res, dout, cfg):
     # grid (batch, K/V lane tiles, pairs by k tile, query heads of the
     # group): the dk/dv tile of one K/V head stays in scratch while its
     # q tiles and the group's query heads go by
-    def qg_tile(off=0, width=hd.width):
-        return pl.BlockSpec(
-            (None, block_q, width),
-            lambda b, i, p, g, qi, ki, fl: (b, qi[p],
-                                            off + i * hd.group + g))
-
-    def kg_tile(off=0):
-        return pl.BlockSpec(
-            (None, block_k, hd.width),
-            lambda b, i, p, g, qi, ki, fl: (b, ki[p], off + i))
-
-    rowg = pl.BlockSpec(
-        (hd.per, 1, block_q),
-        lambda b, i, p, g, qi, ki, fl: (b * hd.tiles + i * hd.group + g,
-                                        0, qi[p]))
+    qg_tile, kg_tile, rowg = _by_k_specs(block_q, block_k, hd, True)
     kv_shape = (b, t, h_kv * hd.d)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **static),
@@ -743,13 +954,10 @@ def _flash_bwd_impl(res, dout, cfg):
         interpret=interpret,
         name="flash_attention_dkv",
     )(*_tables(by_k), q, k, v, dout, lse, delta)
-
-    if len(ops) == 1:
-        return (_side_by_side([dq, dk, dv]),),
-    return (dq, dk, dv),
+    return dq, dk, dv
 
 
-_backward = engine_jit(_flash_bwd_impl, static_argnums=(2,),
+_backward = engine_jit(_flash_bwd_impl, static_argnums=(2, 3),
                        key_hint="flash_attention_backward")
 _attach.defvjp(_attach_fwd, _attach_bwd)
 

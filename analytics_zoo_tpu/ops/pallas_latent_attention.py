@@ -71,8 +71,9 @@ from analytics_zoo_tpu.compile.engine import engine_jit
 from analytics_zoo_tpu.ops.fused import count_build, keep_result
 from analytics_zoo_tpu.ops.pallas_attention import (
     _FIRST, _LAST, _NT, KEPT_RESULTS, NEG, _col_to_row, _compiler_params,
-    _head_lanes, _masked, _positions, _resolve_blocks, _row_to_col,
-    _side_by_side, _statics, _tables, _tile_pairs, allowed_pairs)
+    _delta_rows, _head_lanes, _masked, _positions, _resolve_blocks,
+    _row_to_col, _side_by_side, _statics, _tables, _tile_pairs,
+    allowed_pairs)
 
 # lanes of a q_nope / k_nope / v head, and of a rotary part; two heads
 # to a grid step
@@ -472,17 +473,7 @@ def _one_pass(res, dout, cfg):
     tiles = h // PER
     _, by_k = _tile_pairs(mask, t, block_q, block_k)
     wide, narrow = PER * NOPE, PER * ROPE
-    # rowsum(dO * O) a head, as lane-dense rows beside lse's: the walk
-    # meets a q tile once a key tile, so no pair is a q tile's first.
-    # Summed as a product with the heads' lanes at float32's precision,
-    # one fusion over dO and O; as a reduction over a head's 128 lanes
-    # XLA writes the float32 product out and relayouts it (1 ms a layer
-    # at 8,192 positions on the v5e)
-    lanes = (jnp.arange(h * NOPE) // NOPE == jnp.arange(h)[:, None])
-    delta = jnp.einsum(
-        "hl,btl->bht", lanes.astype(jnp.float32),
-        dout.astype(jnp.float32) * out.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST).reshape(b * h, 1, t)
+    delta = _delta_rows(dout, out, h)
 
     q_tile, k_tile, kpe_tile, rows = _pair_specs(block_q, block_k, tiles)
 

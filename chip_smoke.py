@@ -21,14 +21,16 @@ from ``--seed``):
                   batch 32, 2 blocks): the one listed model whose default
                   path meets flash attention, ``bias_gelu`` and
                   ``layernorm_act`` together, compared with the suite's
-                  lax forms and with dense attention;
+                  lax forms and with dense attention (the attention
+                  backward's build counter is printed: one pass);
 * ``hybrid``      the kernels of a decoder-hybrid-decoder at its widths
                   (5,120 scan channels of 16 states; 40 heads of 64 in
                   differential pairs on 20 K/V heads, under a 512-key
                   window and the causal mask; 2,048 positions): the
                   selective scan's two kernels against the sequential
                   ``lax.scan``, the flash kernels' two maps a pair
-                  against dense attention, outputs and gradients, each
+                  against dense attention, outputs and gradients (the
+                  backward in one pass, by its build counter), each
                   side timed.
 
 * ``latent``      the latent-attention cell's mechanisms at its widths
@@ -409,6 +411,13 @@ def serve(model, *, image: int = 224, n_records: int = 8, seed: int = 0):
 
 
 # ------------------------------------------------------------ transformer
+def _backward_in_one_pass(builds: Dict, kernel: str) -> bool:
+    """The only backward form ``builds`` counted for ``kernel`` is the
+    one-pass kernel (the shapes here fit its VMEM budget)."""
+    return [k for k in builds if f'"{kernel}_backward"' in k] == [
+        f'{{kernel="{kernel}_backward",path="one_pass"}}']
+
+
 def _attention_vs_dense(shape: Sequence[int], dtype, seed: int) -> Dict:
     """flash_attention against the dense reference at ``shape``: output
     and the gradients of q, k and v, as scale-relative errors."""
@@ -506,6 +515,9 @@ def transformer(*, width: int = 768, heads: int = 12, seq: int = 512,
            "fit_loss": [float(h["loss"]) for h in history]}
     checks = {
         "flash_attention_pallas": on_pallas(builds, "flash_attention"),
+        "backward_in_one_pass": _backward_in_one_pass(
+            builds, "flash_attention") and _backward_in_one_pass(
+            fit_builds, "flash_attention"),
         "bias_gelu_pallas": on_pallas(builds, "bias_gelu"),
         "layernorm_act_pallas": on_pallas(builds, "layernorm_act"),
         "fit_kernels_pallas": all(
@@ -725,6 +737,9 @@ def hybrid(*, seq: int = 2048, hidden: int = 2560, channels: int = 5120,
                 pallas_attention.KEPT_RESULTS + selective_scan.KEPT_RESULTS),
         "selective_scan_pallas":
             builds.get('{kernel="selective_scan",path="pallas"}', 0) > 0,
+        # the pair's backward under the window and the causal mask
+        "backward_in_one_pass": _backward_in_one_pass(
+            builds, "flash_attention"),
         "scan_agrees_with_lax": all(e <= SCAN_TOL
                                     for e in scan_err.values()),
         "flash_agrees_with_dense": all(
@@ -870,9 +885,8 @@ def latent(*, seq: int = 2048, heads: int = 32, hidden: int = 2048,
         "flash_agrees_with_dense": all(e <= ATTENTION_TOL
                                        for e in attention.values()),
         "unread_columns_get_no_gradient": unread == 0.0,
-        "backward_in_one_pass": [
-            k for k in builds if "flash_attention_latent_backward" in k] == [
-            '{kernel="flash_attention_latent_backward",path="one_pass"}'],
+        "backward_in_one_pass": _backward_in_one_pass(
+            builds, "flash_attention_latent"),
         # a float32 product at the highest precision picks the same
         # experts as the formula (a token in a thousand may sit on a tie)
         "router_picks_the_formulas_experts":

@@ -107,6 +107,28 @@ def _every_cell_reports_the_setup_metrics(request, monkeypatch):
 
 
 @pytest.fixture(autouse=True)
+def _setup_metrics_read_a_fresh_registry(request):
+    """``tests/benchmark/test_bench_startup_metrics.py`` drives the
+    harness, whose set-up metrics are ABSOLUTE readings of the process's
+    registry as the window opens (a benchmark run is a process of its
+    own).  In a worker that has trained before, the earlier tests'
+    span seconds exceed the toy run's ``setup_s`` and
+    ``setup_outside_program_s`` reads negative; which files share a
+    worker follows their durations (the test passed in PR 35's whole run
+    and failed in PR 36's first, and fails after
+    ``test_bench_harness.py`` in one process on either tree).  A PR may
+    edit no file under the benchmark's paths, so that file gets its
+    fresh registry here, as ``test_startup_timeline.py`` gives itself
+    one; a ``benchmark`` PR moves this into the file."""
+    if request.module.__name__.rsplit(".", 1)[-1] == \
+            "test_bench_startup_metrics":
+        from analytics_zoo_tpu.observability import reset_registry
+        from analytics_zoo_tpu.observability.tracing import reset_tracer
+        reset_registry()
+        reset_tracer()
+
+
+@pytest.fixture(autouse=True)
 def _fresh_context():
     """Reset global state between tests: context and layer naming (so
     param init rng streams don't depend on test execution order)."""
@@ -153,6 +175,27 @@ def pallas_calls():
         walk(jax.make_jaxpr(fn)(*args).jaxpr)
         return dict(found)
     return count
+
+
+@pytest.fixture
+def pallas_grids():
+    """-> ``grids(fn, *args)``: the grid of each ``pallas_call`` in the
+    jaxpr of ``fn(*args)``, by its ``name=``."""
+    import jax
+
+    def grids(fn, *args):
+        found = {}
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found[eqn.params["name"]] = tuple(
+                        eqn.params["grid_mapping"].grid)
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    walk(inner)
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+    return grids
 
 
 @pytest.fixture

@@ -90,11 +90,18 @@ def test_transformer(one_chip, capsys):
                          vocab=100, fit_steps=2)
     assert ok, line
     assert set(line["flash_vs_dense"]) == {"bfloat16", "float32"}
+    # the attention backward's form is printed beside the other builds
+    # (the train program is traced twice: the call, the cost analysis)
+    one_pass = '{kernel="flash_attention_backward",path="one_pass"}'
+    assert line["kernel_builds"][one_pass] == 1.0
+    assert line["kernel_builds_fit"][one_pass] == 2.0
+    assert line["checks"]["backward_in_one_pass"]
     # the reference run really was the lax forms, and only it (two
     # heads of 64 share a lane tile: the kernels' packed form)
     assert line["kernel_builds_reference"] == {
         '{kernel="flash_attention",path="pallas"}': 1.0,
         '{kernel="flash_attention_packed",path="pallas"}': 1.0,
+        '{kernel="flash_attention_backward",path="one_pass"}': 1.0,
         '{kernel="bias_gelu",path="lax"}': 1.0,
         '{kernel="layernorm_act",path="lax"}': 1.0}
     assert get_config().get("ops.fused") == "auto"
@@ -105,8 +112,11 @@ def test_hybrid(one_chip, capsys):
                          hidden=64, channels=1024, states=4, heads=4,
                          kv_heads=2, window=100)
     assert ok, line
+    # the pair's backward under the window and under the causal mask
     assert line["kernel_builds"] == {
-        '{kernel="selective_scan",path="pallas"}': 1.0}
+        '{kernel="selective_scan",path="pallas"}': 1.0,
+        '{kernel="flash_attention_backward",path="one_pass"}': 2.0}
+    assert line["checks"]["backward_in_one_pass"]
     assert set(line["differential_flash_vs_dense"]) == {"window", "causal"}
     assert set(line["forward_backward_s"]) == {
         "selective_scan", "selective_scan_lax", "flash_window",

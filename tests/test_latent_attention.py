@@ -244,23 +244,7 @@ def test_the_kernels_take_their_sizes_only():
                                       ops[3], n_head=2, interpret=True)
 
 
-def pallas_grids(fn, *args):
-    """-> the grid of each ``pallas_call`` in ``fn(*args)``'s jaxpr, by
-    its ``name=``."""
-    found = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for inner in jax.core.jaxprs_in_params(eqn.params):
-                walk(inner)
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
-
-
-def test_the_one_pass_walk_goes_through_each_key_tile_once():
+def test_the_one_pass_walk_goes_through_each_key_tile_once(pallas_grids):
     """The one-pass backward walks ``_tile_pairs``' by-k-tile list as it
     stands, once a head pair (the pairs are a grid axis outside it):
     each key tile's run of q tiles is contiguous and met once, its first
@@ -285,7 +269,7 @@ def test_the_one_pass_walk_goes_through_each_key_tile_once():
 
 
 def test_the_dkv_walk_goes_through_each_key_tile_once_a_head_pair(
-        monkeypatch):
+        monkeypatch, pallas_grids):
     """The two-pass form's dkv walk.  Per key tile: every head pair's
     run of q tiles, the run's own first and last flagged (``dk_nope``
     and ``dv`` leave there), the key tile's first and last entry flagged

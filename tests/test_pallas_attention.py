@@ -202,6 +202,36 @@ def test_bad_mask_arguments_are_refused():
         block_diffusion(64, 5)
 
 
+# ------------------------------------------ the backward's two forms
+from analytics_zoo_tpu.ops import pallas_attention as kernels  # noqa: E402
+
+FORMS = ["one_pass", "two_pass"]
+
+
+@pytest.fixture
+def backward_form(request, monkeypatch):
+    """The backward in the form named: every shape here fits the
+    one-pass form's budget, so the two-pass form is reached by a budget
+    nothing fits."""
+    if request.param == "two_pass":
+        monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
+    return request.param
+
+
+def backward_builds():
+    """{form: how often the counter says the backward was built so}."""
+    from analytics_zoo_tpu.observability import get_registry
+    counters = get_registry().snapshot()["counters"]
+    return {form: counters.get(
+        'fused_kernel_builds_total{kernel="flash_attention_backward",'
+        'path="%s"}' % form, 0) for form in FORMS}
+
+
+def forms_built_since(before):
+    return {form for form, n in backward_builds().items()
+            if n > before[form]}
+
+
 # ------------------------- the token-major core, heads sharing a tile
 from analytics_zoo_tpu.ops.pallas_attention import (  # noqa: E402
     _heads_per_tile, flash_attention_token_major)
@@ -220,14 +250,16 @@ def _head_major(a, n, d):
     return jnp.moveaxis(a.reshape(a.shape[0], a.shape[1], n, d), 1, 2)
 
 
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
 @pytest.mark.parametrize("mask", sorted(CORE_MASKS))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("heads", sorted(HEADS))
-def test_token_major_core_matches_dense(heads, dtype, mask):
+def test_token_major_core_matches_dense(heads, dtype, mask, backward_form):
     """The kernels on (B, T, H·D) operands, a head a block of the last
     dimension (two 64-wide heads to a 128-lane block, or one of 128;
     q, k and v apart or side by side in one array), against dense
-    attention over the same values: forward and all three gradients."""
+    attention over the same values: forward and all three gradients,
+    the backward in one pass and as the dq and dkv kernels."""
     h, h_kv, d, fused = HEADS[heads]
     assert _heads_per_tile(h, h_kv, d) == 128 // d
     mask, b, t = CORE_MASKS[mask], 1, 256
@@ -255,7 +287,9 @@ def test_token_major_core_matches_dense(heads, dtype, mask):
         else dict(rtol=0.05, atol=0.05)
     np.testing.assert_allclose(flash(qkv), dense(qkv),
                                rtol=tol["rtol"] * 10)
+    before = backward_builds()
     got, want = jax.grad(flash)(qkv), jax.grad(dense)(qkv)
+    assert forms_built_since(before) == {backward_form}
     assert got.dtype == qkv.dtype
     # dq, dk and dv, side by side as q, k and v are
     for g, r in zip(jnp.split(got.astype(jnp.float32), cuts, axis=-1),
@@ -289,8 +323,7 @@ def test_forward_call_outside_the_vjp_cuts_no_cotangent(heads, pallas_calls):
         return jnp.sum(w * jnp.moveaxis(out, 1, 2).reshape(b, t, h * d))
 
     assert pallas_calls(jax.grad(flash, (0, 1, 2)), *ops) == {
-        "flash_attention_fwd": 1, "flash_attention_dq": 1,
-        "flash_attention_dkv": 1}
+        "flash_attention_fwd": 1, "flash_attention_bwd": 1}
     for name, got, want in zip("qkv", jax.grad(flash, (0, 1, 2))(*ops),
                                jax.grad(dense, (0, 1, 2))(*ops)):
         assert float(jnp.max(jnp.abs(want))) > 0
@@ -394,3 +427,252 @@ def test_build_counter_says_which_form_a_traced_layer_got(one_chip_routing):
         MultiHeadSelfAttention(768, 12), [(None, 512, 768), (None, 512)],
         f32, jax.ShapeDtypeStruct((2, 512), jnp.float32)) == {
             '{kernel="flash_attention",path="lax"}': 1.0}
+
+
+# --------------- the backward in one pass and as the dq and dkv kernels
+from analytics_zoo_tpu.ops.pallas_attention import (  # noqa: E402
+    _FIRST, _LAST, sliding_window)
+
+FORM_MASKS = {"causal": "causal", "window": sliding_window(100),
+              "block_diffusion": block_diffusion(128, 4)}
+
+
+def _pair_dense(q, k, v, h, h_kv, allowed):
+    """The differential pair written out: every head's map (over its own
+    K head) applied to its K/V pair's two V heads side by side."""
+    b, t, _ = q.shape
+    d = q.shape[-1] // h
+    q = q.reshape(b, t, h // 2, 2, d)
+    k = k.reshape(b, t, h_kv // 2, 2, d)
+    v = v.reshape(b, t, h_kv // 2, 2 * d)
+    group = h // h_kv
+    outs = []
+    for j in range(h // 2):
+        for r in range(2):
+            s = jnp.einsum("btd,bsd->bts", q[:, :, j, r],
+                           k[:, :, j // group, r]) / np.sqrt(d)
+            p = jax.nn.softmax(jnp.where(allowed, s, -1e30), axis=-1)
+            outs.append(jnp.einsum("bts,bse->bte", p, v[:, :, j // group]))
+    return jnp.concatenate(outs, axis=-1)
+
+
+def _operands(b, t, h, h_kv, d, out_lanes, seed):
+    rs = np.random.RandomState(seed)
+    return [jnp.asarray(rs.randn(b, t, n) * 0.5, jnp.float32)
+            for n in (h * d, h_kv * d, h_kv * d, out_lanes)]
+
+
+def _mask_kw(mask):
+    return dict(causal=True) if mask == "causal" else dict(mask=mask)
+
+
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+@pytest.mark.parametrize("mask", sorted(FORM_MASKS))
+@pytest.mark.parametrize("group", [4, 8])
+def test_grouped_heads_of_128_in_both_forms(group, mask, backward_form):
+    """``group`` query heads of 128 on ONE K/V head (the sparse cell has
+    eight): the one-pass kernel keeps the K/V tile's whole dk and dv
+    while the group's query tiles go by one at a time, the dkv kernel
+    walks the group innermost; either is the sum over the group."""
+    mask, t, d = FORM_MASKS[mask], 256, 128
+    q, k, v, w = _operands(1, t, group, 1, d, group * d, 10)
+    allowed = allowed_pairs(mask, t)
+
+    def flash(q, k, v):
+        return jnp.sum(w * flash_attention_token_major(
+            q, k, v, n_head=group, block_q=128, block_k=128,
+            interpret=True, **_mask_kw(mask)))
+
+    def dense(q, k, v):
+        out = _dense(_head_major(q, group, d), _head_major(k, 1, d),
+                     _head_major(v, 1, d), allowed)
+        return jnp.sum(w * jnp.moveaxis(out, 1, 2).reshape(1, t, group * d))
+
+    before = backward_builds()
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    assert forms_built_since(before) == {backward_form}
+    for name, g, r in zip("qkv", got, jax.grad(dense, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+@pytest.mark.parametrize("fused", [False, True], ids=["q-k-v", "one-array"])
+@pytest.mark.parametrize("mask", sorted(FORM_MASKS))
+def test_differential_pair_with_group_2_in_both_forms(mask, fused,
+                                                      backward_form):
+    """8 query heads of 64 in pairs on 4 K/V heads: two query tiles to a
+    K/V tile, two heads to a tile, each head's map over the pair's whole
+    V and a cotangent two tiles wide; ``delta`` a head is the row sum
+    over its 128 output lanes."""
+    mask, t, h, h_kv, d = FORM_MASKS[mask], 256, 8, 4, 64
+    q, k, v, w = _operands(2, t, h, h_kv, d, 2 * h * d, 11)
+    allowed = jnp.asarray(allowed_pairs(mask, t))
+
+    def flash(q, k, v):
+        ops = (jnp.concatenate([q, k, v], -1),) if fused else (q, k, v)
+        return jnp.sum(w * flash_attention_token_major(
+            *ops, n_head=h, n_kv_head=h_kv, differential=True,
+            block_q=128, block_k=128, interpret=True, **_mask_kw(mask)))
+
+    def dense(q, k, v):
+        return jnp.sum(w * _pair_dense(q, k, v, h, h_kv, allowed))
+
+    before = backward_builds()
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    assert forms_built_since(before) == {backward_form}
+    for name, g, r in zip("qkv", got, jax.grad(dense, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=2e-5, err_msg=name)
+
+
+# (query heads, K/V heads, head width, the pair, mask)
+AGREE = {"packed-causal": (4, 4, 64, False, "causal"),
+         "group-8-block_diffusion": (8, 1, 128, False,
+                                     block_diffusion(256, 4)),
+         "pair-group-2-window": (8, 4, 64, True, sliding_window(200))}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(AGREE))
+def test_the_two_forms_agree_to_rounding(monkeypatch, case, dtype):
+    """One per-head function (``_key_side``), the same products on the
+    same dtypes from the same ``lse``: the forms differ in the order
+    float32 sums are taken (dq over key tiles, dk and dv over the
+    group's query tiles, ``delta`` outside the kernel)."""
+    h, h_kv, d, pair, mask = AGREE[case]
+    t = 512
+    ops = [a.astype(dtype) for a in _operands(
+        1, t, h, h_kv, d, (2 if pair else 1) * h * d, 12)]
+    w = ops.pop().astype(jnp.float32)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(w * flash_attention_token_major(
+            *a, n_head=h, differential=pair, block_q=128, block_k=128,
+            interpret=True, **_mask_kw(mask)).astype(jnp.float32)),
+            (0, 1, 2))(*ops)
+
+    one = grads()
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
+    two = grads()
+    # bfloat16 results are each rounded once, from float32 sums taken in
+    # another order: a unit in the last place at most
+    tol = 2e-6 if dtype == "float32" else 2 ** -7
+    for a, b in zip(one, two):
+        a, b = (x.astype(jnp.float32) for x in (a, b))
+        scale = float(jnp.max(jnp.abs(b)))
+        assert scale > 0
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * scale
+
+
+def test_the_backward_form_follows_the_length(monkeypatch, pallas_calls):
+    """One pass wherever a query tile's whole dq and its K/V tile's
+    whole dk and dv (float32 accumulators, two buffers of each output
+    block) fit ``_RESIDENT_VMEM``; the dq and dkv kernels past it.  The
+    group's size does not enter.  The counter says which a program got."""
+    # the sparse cell's 8,192 positions of 128 lanes in bfloat16 (eight
+    # query heads to a K/V head) fit, and the hybrid cell's; twice that
+    # does not; the GPT cell's 512 in float32 do
+    assert kernels._fits_resident(8192, 128, 2)
+    assert not kernels._fits_resident(16384, 128, 2)
+    assert kernels._fits_resident(512, 128, 4)
+    assert not kernels._fits_resident(8192, 128, 4)
+    # at float32: three arrays of 128 lanes, 4 + 2 x 4 bytes a position
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 256 * 3 * 128 * 12)
+    assert kernels._fits_resident(256, 128, 4)
+    assert not kernels._fits_resident(257, 128, 4)
+    names = {256: {"flash_attention_bwd"},
+             512: {"flash_attention_dq", "flash_attention_dkv"}}
+    for t, form in ((256, "one_pass"), (512, "two_pass")):
+        q, k, v, w = _operands(1, t, 8, 1, 128, 8 * 128, 13)
+        grads = jax.grad(lambda *a: jnp.sum(w * flash_attention_token_major(
+            *a, n_head=8, causal=True, interpret=True)), (0, 1, 2))
+        before = backward_builds()
+        calls = pallas_calls(grads, q, k, v)
+        assert set(calls) == {"flash_attention_fwd"} | names[t]
+        assert all(n == 1 for n in calls.values())
+        assert forms_built_since(before) == {form}
+
+
+@pytest.mark.parametrize("mask", sorted(FORM_MASKS))
+def test_the_one_pass_walk_goes_through_each_key_tile_once(
+        mask, monkeypatch, pallas_grids):
+    """The one-pass backward walks ``_tile_pairs``' by-k-tile list as it
+    stands, once a query tile of the group: key tile by key tile, each
+    key tile's run of q tiles contiguous, in order and met once, the
+    pairs the by-q walk's.  Its grid is (batch, K/V lane tiles, query
+    tiles of a group, the walk), against the dkv kernel's (batch, K/V
+    lane tiles, the walk, the group innermost)."""
+    mask, t, blk = FORM_MASKS[mask], 256, 64
+    (aq, ak, _), (qi, ki, fl) = _tile_pairs(mask, t, blk, blk)
+    assert list(ki) == sorted(ki)                    # key tile by key tile
+    assert sorted(zip(qi, ki)) == sorted(zip(aq, ak))
+    assert set(ki) == set(range(t // blk))           # every key tile read
+    for k in range(t // blk):
+        run = np.flatnonzero(ki == k)
+        assert list(run) == list(range(run[0], run[-1] + 1))
+        assert list(qi[run]) == sorted(qi[run])
+        assert fl[run[0]] & _FIRST and fl[run[-1]] & _LAST
+        assert (fl[run] & _FIRST != 0).sum() == 1
+        assert (fl[run] & _LAST != 0).sum() == 1
+    # 8 query heads of 128 on 2 K/V heads, batch 2
+    q, k, v, w = _operands(2, t, 8, 2, 128, 8 * 128, 14)
+
+    def grads():         # a function of its own a form: a trace is cached
+        return jax.grad(lambda *a: jnp.sum(w * flash_attention_token_major(
+            *a, n_head=8, block_q=blk, block_k=blk, interpret=True,
+            **_mask_kw(mask))), (0, 1, 2))
+    n = len(qi)
+    assert pallas_grids(grads(), q, k, v) == {
+        "flash_attention_fwd": (2, 8, n),
+        "flash_attention_bwd": (2, 2, 4, n)}
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
+    assert pallas_grids(grads(), q, k, v) == {
+        "flash_attention_fwd": (2, 8, n), "flash_attention_dq": (2, 8, n),
+        "flash_attention_dkv": (2, 2, n, 4)}
+
+
+def test_packed_heads_walk_once_a_lane_tile(pallas_grids):
+    """Two 64-wide heads to a lane tile, every query head its own K/V
+    head: the group axis has one entry, and the fused operand's three
+    parts are block offsets."""
+    qkv = jnp.zeros((2, 256, 3 * 4 * 64))
+    grads = jax.grad(lambda a: jnp.sum(flash_attention_token_major(
+        a, n_head=4, causal=True, block_q=128, block_k=128,
+        interpret=True)))
+    assert pallas_grids(grads, qkv) == {
+        "flash_attention_fwd": (2, 2, 3), "flash_attention_bwd": (2, 2, 1, 3)}
+
+
+def test_rows_no_pair_reaches_leave_as_zeros(monkeypatch):
+    """Every key tile is read under the three masks; a walk that leaves
+    one out (here: the causal list less every pair of the last key tile
+    and of the first q tile) must still write that key tile's dk and dv,
+    and that q tile's dq, as zeros: the resident accumulators are zeroed
+    before a walk, not at a tile's first pair.  The rows the walk does
+    reach are what the whole list gives them where the dropped pairs do
+    not enter."""
+    t, blk, h, d = 512, 128, 2, 128
+    q, k, v, w = _operands(1, t, h, 1, d, h * d, 15)
+    cfg = ("causal", d ** -0.5, blk, blk, True, h, 1, False)
+    out, lse = kernels._flash_fwd_impl((q, k, v), cfg)
+    whole = kernels._one_pass(((q, k, v), out, lse), w, cfg)
+
+    by_q, (qi, ki, fl) = _tile_pairs("causal", t, blk, blk)
+    keep = (ki != t // blk - 1) & (qi != 0)
+    qi, ki, fl = qi[keep], ki[keep], fl[keep] & ~(_FIRST | _LAST)
+    fl[np.r_[True, ki[1:] != ki[:-1]]] |= _FIRST
+    fl[np.r_[ki[1:] != ki[:-1], True]] |= _LAST
+    monkeypatch.setattr(kernels, "_tile_pairs",
+                        lambda *a: (by_q, (qi, ki, fl)))
+    dq, dk, dv = kernels._one_pass(((q, k, v), out, lse), w, cfg)
+    assert not np.asarray(dk[:, -blk:]).any()
+    assert not np.asarray(dv[:, -blk:]).any()
+    assert not np.asarray(dq[:, :blk]).any()
+    # key tile 0 lost its pair with q tile 0; key tiles 1 and 2 lost none
+    for got, want in zip((dk, dv), whole[1:]):
+        np.testing.assert_allclose(got[:, blk:-blk], want[:, blk:-blk],
+                                   rtol=1e-6, atol=1e-6)
+        assert float(jnp.max(jnp.abs(got[:, :blk] - want[:, :blk]))) > 0
+    # q tiles 1 and 2 never read the last key tile; q tile 3 did
+    np.testing.assert_allclose(dq[:, blk:-blk], whole[0][:, blk:-blk],
+                               rtol=1e-6, atol=1e-6)
+    assert float(jnp.max(jnp.abs(dq[:, -blk:] - whole[0][:, -blk:]))) > 0
