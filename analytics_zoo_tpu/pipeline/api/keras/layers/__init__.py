@@ -68,6 +68,8 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.latent import (
     LatentAttention, LatentDecoderLayer,
 )
+from analytics_zoo_tpu.pipeline.api.keras.layers.prerouted import (
+    PreRoutedDecoderLayer)
 
 # Keras-2 style aliases
 Conv1D = Convolution1D
@@ -103,7 +105,7 @@ __all__ = [
     "SpaceToDepth2D", "MoE", "DroplessMoE",
     "DifferentialAttention", "GatedFeedForward", "GatedMemoryUnit",
     "HybridDecoderLayer", "Mamba", "NextTokenLoss",
-    "LatentAttention", "LatentDecoderLayer",
+    "LatentAttention", "LatentDecoderLayer", "PreRoutedDecoderLayer",
     "AddConstant", "BinaryThreshold", "CAdd", "CMul", "Exp",
     "GaussianSampler", "HardShrink", "HardTanh", "Identity", "Log",
     "LRN2D", "Mul", "MulConstant", "Negative", "Power",

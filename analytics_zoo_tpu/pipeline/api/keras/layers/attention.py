@@ -194,13 +194,16 @@ class GroupedQueryAttention(Layer):
     """Self-attention with fewer key/value heads than query heads, as
     the current open decoders have it: separate q/k/v/o projections
     without bias, an optional RMS norm over each head of q and k (own
-    gains), rotary positions (rotate-half form), and query head ``i``
-    reading K/V head ``i // (n_head / n_kv_head)``.
+    gains), rotary positions (rotate-half form; ``rope_theta=None``: no
+    position encoding at all), and query head ``i`` reading K/V head
+    ``i // (n_head / n_kv_head)``.
 
     Inputs ``[x, positions]``: ``x`` (B, T, D) and integer position ids
     (B, T).  ``mask``: ``None`` (every query reads every key),
-    ``"causal"``, or ``ops.pallas_attention.block_diffusion(L, B)``
-    over ``T = 2 L`` positions (the noisy copy, then the clean one).
+    ``"causal"``, ``ops.pallas_attention.sliding_window(W)`` (causal
+    inside a band of ``W`` keys) or
+    ``ops.pallas_attention.block_diffusion(L, B)`` over ``T = 2 L``
+    positions (the noisy copy, then the clean one).
 
     Routing is ``MultiHeadSelfAttention``'s: the flash kernels on one
     device (heads are blocks of the projections' last dimension, K/V
@@ -208,7 +211,7 @@ class GroupedQueryAttention(Layer):
     skipped), dense attention under an explicit mask elsewhere."""
 
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
-                 rope_theta: float = 10000.0, qk_norm: bool = True,
+                 rope_theta: Optional[float] = 10000.0, qk_norm: bool = True,
                  norm_epsilon: float = 1e-6, mask=None, **kwargs):
         super().__init__(**kwargs)
         if n_head % n_kv_head:
@@ -216,7 +219,7 @@ class GroupedQueryAttention(Layer):
                 f"{n_kv_head} K/V heads do not divide {n_head} heads")
         self.n_head, self.n_kv_head = int(n_head), int(n_kv_head)
         self.head_dim = int(head_dim)
-        self.rope_theta = float(rope_theta)
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
         self.qk_norm = bool(qk_norm)
         self.norm_epsilon = float(norm_epsilon)
         self.mask = mask
@@ -257,10 +260,13 @@ class GroupedQueryAttention(Layer):
                 y = rms_norm(y, params[gain], self.norm_epsilon)
             return y
 
-        q = rotary_embedding(heads("q_kernel", self.n_head, "q_norm"),
-                             positions, self.rope_theta)
-        k = rotary_embedding(heads("k_kernel", self.n_kv_head, "k_norm"),
-                             positions, self.rope_theta)
+        def rotated(y):
+            if self.rope_theta is None:
+                return y
+            return rotary_embedding(y, positions, self.rope_theta)
+
+        q = rotated(heads("q_kernel", self.n_head, "q_norm"))
+        k = rotated(heads("k_kernel", self.n_kv_head, "k_norm"))
         v = heads("v_kernel", self.n_kv_head, None)
 
         causal = self.mask == "causal"

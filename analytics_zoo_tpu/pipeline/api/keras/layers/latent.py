@@ -43,18 +43,16 @@ the softmax and the loss are float32.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.ops.dtypes import get_policy
-from analytics_zoo_tpu.pipeline.api.keras.engine import Layer, Params, State
+from analytics_zoo_tpu.pipeline.api.keras.engine import Layer, Params
 from analytics_zoo_tpu.pipeline.api.keras.layers.attention import _mesh, _mm
 from analytics_zoo_tpu.pipeline.api.keras.layers.normalization import (
     rms_norm)
 from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
-    GatedFeedForward, KeepsKernelResults)
+    GatedFeedForward, PreNormDecoderLayer)
 
 
 def _latent_route(t: int, n_head: int, nope: int, rope: int, v: int) -> bool:
@@ -148,62 +146,9 @@ class LatentAttention(Layer):
         return _mm(ctx, params["o_kernel"]).astype(x.dtype)
 
 
-class LatentDecoderLayer(KeepsKernelResults):
-    """One decoder layer: ``h = h + attention(RMSNorm(h))``, then ``h = h
-    + ffn(RMSNorm(h))``.  ``ffn``: a ``GatedFeedForward`` (a dense layer)
-    or a ``DroplessMoE`` (a sparse one, whose state — ``rows_routed``,
-    ``selection_bias`` — is this layer's, so that
-    ``observability.moe_stats`` finds it).  The layer holds the weights
-    of its parts under ``ln1_gamma``, ``attn_*``, ``ln2_gamma``,
-    ``ffn_*``.
-
-    ``recompute``: the layer's internals are computed again in the
-    backward pass, EXCEPT what its flash kernels wrote, which the policy
-    keeps by name (``KeepsKernelResults``)."""
-
-    def __init__(self, attention: Layer, ffn: Layer, epsilon: float = 1e-6,
-                 recompute: bool = False, **kwargs):
-        super().__init__(**kwargs)
-        self.attention, self.ffn = attention, ffn
-        self.epsilon, self.recompute = float(epsilon), bool(recompute)
-
-    def build(self, rng, input_shape) -> Params:
-        params: Params = {}
-        for i, (prefix, part) in enumerate((("attn", self.attention),
-                                            ("ffn", self.ffn))):
-            self.add_weight(params, rng, f"ln{i + 1}_gamma",
-                            (input_shape[-1],), init="one")
-            sub = part.init(jax.random.fold_in(rng, i),
-                            input_shape)["params"]
-            params.update({f"{prefix}_{k}": v for k, v in sub.items()})
-        return params
-
-    def init_state(self, input_shape) -> State:
-        return self.ffn.init_state(input_shape)
-
-    def _body(self, params, state, h):
-        def part(prefix):
-            return {k[len(prefix) + 1:]: v for k, v in params.items()
-                    if k.startswith(prefix + "_")}
-
-        # the norms' results stay float32: the products cast their
-        # operands themselves and the router reads float32
-        a = rms_norm(h, params["ln1_gamma"], self.epsilon)
-        h = h + self.attention.call(part("attn"), a).astype(h.dtype)
-        m = rms_norm(h, params["ln2_gamma"], self.epsilon)
-        y, state = self.ffn.apply(part("ffn"), m, state=state)
-        y = y[0] if isinstance(y, (list, tuple)) else y      # [y, aux]
-        return h + y.astype(h.dtype), state
-
-    def apply(self, params, x, state: Optional[State] = None,
-              training=False, rng=None):
-        # a dense layer's empty state comes back as it went in
-        if self.recompute:
-            return self._recomputed(self._body, params, state, x)
-        return self._body(params, state, x)
-
-    def call(self, params, x, training=False, rng=None):
-        return self.apply(params, x)[0]
+class LatentDecoderLayer(PreNormDecoderLayer):
+    """``PreNormDecoderLayer`` round a ``LatentAttention`` (which reads
+    its normed input alone): the block of ``latent_moe_decoder``."""
 
 
 def latent_moe_decoder(*, seq_len: int, vocab_size: int, hidden_size: int,

@@ -19,14 +19,16 @@ by ``aux_loss()`` after a forward — add it to the objective via
 
 ``DroplessMoE`` is the layer of the current open decoders: any
 ``top_k`` of any number of experts with renormalised gates, gated
-(SiLU) experts, no capacity and no dropped token, rows sorted by expert
-and multiplied by ``ops.grouped_matmul``; told which experts it holds
-(``experts_held``), it computes their part of the sum only — what one
-rank of an expert-parallel group runs.  Its router has two forms: the
-softmax over all experts with the ``top_k`` largest probabilities, and
-(``scoring="sigmoid"``) independent sigmoid scores selected under a
-bias the weights do not see, normalised and scaled, beside shared
-experts that every token passes through.
+experts (SiLU or ReLU gate), no capacity and no dropped token, rows
+sorted by expert and multiplied by ``ops.grouped_matmul``; told which
+experts it holds (``experts_held``), it computes their part of the sum
+only — what one rank of an expert-parallel group runs.  Its router has
+two forms: the softmax over all experts with the ``top_k`` largest
+probabilities, and (``scoring="sigmoid"``) independent sigmoid scores
+selected under a bias the weights do not see, normalised and scaled,
+beside shared experts that every token passes through.  The router may
+read another input than the experts do: the caller forms the routing
+with ``route`` and hands it to ``apply``.
 """
 
 from __future__ import annotations
@@ -190,6 +192,10 @@ class _Route(NamedTuple):
     row_token: jax.Array   # (R,) its token; N where the row holds none
 
 
+# the experts' gate: SwiGLU and ReGLU
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _with_zero_row(a):
     """``a`` with one more row, of zeros: what an index of ``N`` reads,
     so that a row that holds no assignment needs no mask."""
@@ -248,7 +254,8 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 class DroplessMoE(Layer):
     """Router over all ``num_experts`` → the ``top_k`` largest
     probabilities (renormalised to sum to one under ``norm_topk_prob``)
-    → gated experts ``(silu(x Wg) * (x Wu)) Wd`` → gated sum.  Input
+    → gated experts ``(act(x Wg) * (x Wu)) Wd`` → gated sum, ``act``
+    the ``activation``: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).  Input
     ``(B, T, d)``; outputs ``[y, aux]``: ``y`` of the input's shape and
     the load-balance term of each sequence, ``(B,)``:
     ``num_experts * sum_e f_e * p_e`` with ``f_e`` the share of the
@@ -285,6 +292,11 @@ class DroplessMoE(Layer):
     It is whole on every rank: the ranks' shares of ``y`` add up to the
     uncut layer's once the shared part is counted once.
 
+    Routed from another input: ``apply(..., routing=route(router, a))``
+    sends ``x`` to the experts that ``a`` selected, with ``a``'s gates
+    (a router placed before attention reads the attention's input);
+    ``aux`` is then the routing's.
+
     State (not trained, carried like BatchNorm's moving statistics):
     ``rows_routed`` (count + 1,) int32 — assignments so far to each
     held expert and, last, to all the others; wraps at 2**32.
@@ -295,11 +307,15 @@ class DroplessMoE(Layer):
                  block_rows: int = 256, init="glorot_uniform",
                  scoring: str = "softmax",
                  routed_scaling_factor: float = 1.0,
-                 shared_hidden: int = 0, **kwargs):
+                 shared_hidden: int = 0, activation: str = "silu",
+                 **kwargs):
         super().__init__(**kwargs)
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+        if activation not in _GATES:
+            raise ValueError(f"activation {activation!r}: silu or relu")
         self.scoring = scoring
+        self.activation = activation
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.shared_hidden = int(shared_hidden)
         self.num_experts = int(num_experts)
@@ -348,17 +364,28 @@ class DroplessMoE(Layer):
     def compute_output_shape(self, input_shape):
         return [tuple(input_shape), (input_shape[0],)]
 
-    def route(self, router, x, bias=None):
+    def route(self, router, x, bias=None, precision=None):
         """``(gates (N, k) float32, experts (N, k) int32, aux (B,))``
         for ``x`` (B, T, d): the router in float32 over all experts;
-        ``bias`` (num_experts,): the sigmoid scores' selection bias."""
+        ``bias`` (num_experts,): the sigmoid scores' selection bias.
+        The router's product is float32 at ``precision`` where one is
+        given (the sigmoid router's always, at the highest), else in the
+        compute dtype accumulated in float32."""
         policy = get_policy()
         b, t, d = x.shape
         if self.scoring == "sigmoid":
-            scores = jax.nn.sigmoid(jnp.matmul(
-                x.reshape(b * t, d).astype(jnp.float32),
-                router.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
+            precision = jax.lax.Precision.HIGHEST
+        if precision is not None:
+            logits = jnp.matmul(x.reshape(b * t, d).astype(jnp.float32),
+                                router.astype(jnp.float32),
+                                precision=precision)
+        else:
+            logits = jax.lax.dot_general(
+                policy.cast_compute(x.reshape(b * t, d)),
+                policy.cast_compute(router), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
             _, experts = jax.lax.top_k(
                 jax.lax.stop_gradient(scores + bias), self.top_k)
             gates = jnp.take_along_axis(scores, experts, axis=-1)
@@ -366,10 +393,6 @@ class DroplessMoE(Layer):
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
             return (gates * self.routed_scaling_factor, experts,
                     jnp.zeros((b,), jnp.float32))
-        logits = jax.lax.dot_general(
-            policy.cast_compute(x.reshape(b * t, d)),
-            policy.cast_compute(router), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         gates, experts = jax.lax.top_k(probs, self.top_k)
         if self.norm_topk_prob:
@@ -414,14 +437,24 @@ class DroplessMoE(Layer):
                        row_token)
         return route, layout, rows
 
-    def apply(self, params, x, state=None, training=False, rng=None):
+    def apply(self, params, x, state=None, training=False, rng=None,
+              routing=None):
+        """``routing``: what ``route`` gave for another input of the
+        same tokens; default, ``x`` routes itself."""
+        from analytics_zoo_tpu.ops.fused import count_build
         from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
         compute = get_policy().compute_dtype
         b, t, d = x.shape
-        gates, experts, aux = self.route(
-            params["router"], x,
-            state["selection_bias"] if self.scoring == "sigmoid" else None)
+        if routing is None:
+            routing = self.route(
+                params["router"], x,
+                state["selection_bias"] if self.scoring == "sigmoid"
+                else None)
+        gates, experts, aux = routing
         route, layout, rows = self.layout(experts)
+        gate_act = _GATES[self.activation]
+        if self.activation == "relu":
+            count_build("moe_experts", "reglu")
 
         def held_experts(xt, gates, gate, up, down):
             # recomputed in the backward pass: the buffers are sized for
@@ -430,7 +463,7 @@ class DroplessMoE(Layer):
             x_buf = _dispatch(xt, route)
             g = grouped_matmul(x_buf, gate, layout)
             u = grouped_matmul(x_buf, up, layout)
-            h = (jax.nn.silu(g.astype(jnp.float32))
+            h = (gate_act(g.astype(jnp.float32))
                  * u.astype(jnp.float32)).astype(compute)
             return _combine(grouped_matmul(h, down, layout), gates, route)
 

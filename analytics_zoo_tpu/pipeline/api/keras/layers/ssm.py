@@ -353,6 +353,80 @@ class KeepsKernelResults(Layer):
         self._kept_bytes = kept
 
 
+class PreNormDecoderLayer(KeepsKernelResults):
+    """One decoder layer: ``h = h + attention(RMSNorm(h))``, then ``h = h
+    + ffn(RMSNorm(h))``.  ``ffn``: a ``GatedFeedForward`` (a dense layer)
+    or a ``DroplessMoE`` (a sparse one, whose state — ``rows_routed``,
+    ``selection_bias`` — is this layer's, so that
+    ``observability.moe_stats`` finds it).  The layer holds the weights
+    of its parts under ``ln1_gamma``, ``attn_*``, ``ln2_gamma``,
+    ``ffn_*``.  A subclass names what the attention reads besides
+    (``_attention_inputs``) and may route the ffn from the attention's
+    input (``_routing``).
+
+    ``recompute``: the layer's internals are computed again in the
+    backward pass, EXCEPT what its flash kernels wrote, which the policy
+    keeps by name (``KeepsKernelResults``)."""
+
+    def __init__(self, attention: Layer, ffn: Layer, epsilon: float = 1e-6,
+                 recompute: bool = False, **kwargs):
+        super().__init__(**kwargs)
+        self.attention, self.ffn = attention, ffn
+        self.epsilon, self.recompute = float(epsilon), bool(recompute)
+
+    def _attention_inputs(self, a):
+        """What the attention reads: its normed input ``a`` (B, T, D)."""
+        return a
+
+    def _attention_input_shape(self, input_shape):
+        return input_shape
+
+    def _routing(self, ffn_params, a) -> dict:
+        """Further arguments of the ffn's ``apply``, formed from the
+        attention's normed input ``a``: none."""
+        return {}
+
+    def build(self, rng, input_shape) -> Params:
+        params: Params = {}
+        for i, (prefix, part, shape) in enumerate((
+                ("attn", self.attention,
+                 self._attention_input_shape(input_shape)),
+                ("ffn", self.ffn, input_shape))):
+            self.add_weight(params, rng, f"ln{i + 1}_gamma",
+                            (input_shape[-1],), init="one")
+            sub = part.init(jax.random.fold_in(rng, i), shape)["params"]
+            params.update({f"{prefix}_{k}": v for k, v in sub.items()})
+        return params
+
+    def init_state(self, input_shape):
+        return self.ffn.init_state(input_shape)
+
+    def _body(self, params, state, h):
+        def part(prefix):
+            return {k[len(prefix) + 1:]: v for k, v in params.items()
+                    if k.startswith(prefix + "_")}
+
+        # the norms' results stay float32: the products cast their
+        # operands themselves and the router reads float32
+        a = rms_norm(h, params["ln1_gamma"], self.epsilon)
+        routing = self._routing(part("ffn"), a)
+        h = h + self.attention.call(
+            part("attn"), self._attention_inputs(a)).astype(h.dtype)
+        m = rms_norm(h, params["ln2_gamma"], self.epsilon)
+        y, state = self.ffn.apply(part("ffn"), m, state=state, **routing)
+        y = y[0] if isinstance(y, (list, tuple)) else y      # [y, aux]
+        return h + y.astype(h.dtype), state
+
+    def apply(self, params, x, state=None, training=False, rng=None):
+        # a dense layer's empty state comes back as it went in
+        if self.recompute:
+            return self._recomputed(self._body, params, state, x)
+        return self._body(params, state, x)
+
+    def call(self, params, x, training=False, rng=None):
+        return self.apply(params, x)[0]
+
+
 class HybridDecoderLayer(KeepsKernelResults):
     """One decoder layer: ``h = h + mixer(LN1(h))``, then ``h = h +
     ffn(LN2(h))``, LayerNorm with weight and bias.  Inputs ``h`` or ``[h,

@@ -508,6 +508,53 @@ def test_latent_decoder_layer_compiles_for_v5e(v5e, one_chip_routing, on_tpu):
             raise AssertionError(f"k_pe broadcast to the heads: {line}")
 
 
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_prerouted_decoder_layer_compiles_for_v5e(v5e, one_chip_routing,
+                                                  on_tpu, kind):
+    """One recomputed decoder layer of the windowed cell at its published
+    widths and 16,384 positions, forward and backward, state and all:
+    28 query heads on 4 K/V heads (groups of 7), no positions and the
+    causal mask or rotary under the 4,096-key window.  Past the one-pass
+    budget Mosaic takes the two-pass backward (``_dq`` + ``_dkv``); the
+    forward kernel stands once though the layer is recomputed; the
+    ReGLU experts' grouped products as in the latent layer."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import (
+        attention, moe, prerouted)
+    windowed = kind == "window"
+    layer = prerouted.PreRoutedDecoderLayer(
+        attention.GroupedQueryAttention(
+            28, 4, 128, rope_theta=1.5e6 if windowed else None,
+            qk_norm=False,
+            mask=sliding_window(4096) if windowed else "causal"),
+        moe.DroplessMoE(64, 768, top_k=6, experts_held=(0, 16),
+                        init="normal", activation="relu"),
+        recompute=True)
+    stream = (1, 16384, 2560)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: layer.build(jax.random.PRNGKey(0), stream)))
+    state = shaped(jax.eval_shape(lambda: layer.init_state(stream)))
+
+    def loss(params, h, state):
+        out, new = layer.apply(params, h, state=state)
+        return jnp.sum(jnp.square(out)), new
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, jax.ShapeDtypeStruct(stream, F32, sharding=v5e), state
+    ).compile().as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == sorted(
+        ["flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"]
+        + 2 * 3 * ["grouped_matmul_fwd"]
+        + 3 * ["grouped_matmul_dlhs", "grouped_matmul_drhs"])
+
+
 def test_capability_probe_compiles_for_v5e(v5e):
     """On a TPU a probe the compiler refuses is an error, so the probe
     itself must be a kernel the v5e accepts."""
