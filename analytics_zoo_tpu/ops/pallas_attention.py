@@ -15,11 +15,11 @@ kernel's results carry a ``custom_vjp`` whose backward is the standard
 flash-attention backward (recompute the probability blocks from the
 forward's saved log-sum-exp, then ``dv = PᵀdO``, ``ds = P∘(dOVᵀ - D)``,
 ``dq = dsK``, ``dk = dsᵀQ``), so the same memory bound holds in
-training.  It has two forms, chosen by the operands' shapes alone
-(``_fits_resident``; ``fused_kernel_builds_total{kernel=
-"flash_attention_backward",path="one_pass"|"two_pass"}`` says which a
-program got), whose arithmetic a (tile pair, head) is ONE function
-(``_key_side``):
+training.  It has three forms, chosen by the operands' shapes alone
+(``_backward_form``; ``fused_kernel_builds_total{kernel=
+"flash_attention_backward",path="one_pass"|"one_pass_heads"|
+"two_pass"}`` says which a program got), whose arithmetic a (tile pair,
+head) is ONE function (``_key_side``):
 
 * **one pass** (``flash_attention_bwd``), wherever a query tile's WHOLE
   dq and its K/V tile's whole dk and dv fit ``_RESIDENT_VMEM`` (float32
@@ -33,11 +33,19 @@ program got), whose arithmetic a (tile pair, head) is ONE function
   by the key tile's rows over the walks of ALL the group's query tiles
   and leave once a K/V tile, so no per-query-head dk/dv exists in HBM.
   ``D`` = rowsum(dO∘O) is formed before the kernel, by XLA.
-* **two passes** (``flash_attention_dq`` + ``_dkv``) past that length
-  (16,384 positions at 128 lanes): the dq kernel walks by query tile
-  (and forms ``D``), the dkv kernel by key tile with the group's query
-  heads innermost; each forms S, P, dP and dS for itself, seven
-  products.
+* **one pass, dk and dv a query head** (``flash_attention_bwd_heads``)
+  past that length, wherever the query tile's whole dq ALONE fits
+  (16,384 and 32,768 positions at 128 lanes in bfloat16: 16 and 32
+  MiB): the same kernel body, grid and walk, but dk and dv accumulate a
+  KEY TILE of one query head's rows and leave, in float32, on the key
+  tile's last walk entry, so each query head's dk and dv exist in HBM
+  (470 MB a layer at 16,384 positions of 28 heads, as ``(B, group, T,
+  H_kv·D)``) and XLA sums the group's, at float32 as the VMEM
+  accumulators sum them.
+* **two passes** (``flash_attention_dq`` + ``_dkv``) past that (65,536
+  positions): the dq kernel walks by query tile (and forms ``D``), the
+  dkv kernel by key tile with the group's query heads innermost; each
+  forms S, P, dP and dS for itself, seven products.
 
 Layout: the operands lie where a projection wrote them, (B, T, H·D),
 and a head is a BLOCK OF THE LAST DIMENSION, picked by the index map:
@@ -67,8 +75,9 @@ band of ``W`` keys) and ``block_diffusion(L, B)``.
 Grouped-query attention: ``k``/``v`` may have fewer heads than ``q``;
 query head ``h`` reads K/V head ``h // group`` through the index map,
 and the backward kernels walk the group's query heads themselves, so no
-repeated K/V and no per-query-head dk/dv exists in HBM.  K and V are operands
-like any other: they may be another layer's.
+repeated K/V exists in HBM, nor a per-query-head dk/dv but in the form
+with dq alone resident.  K and V are operands like any other: they may
+be another layer's.
 
 The differential pair (``differential=True``): 64-wide heads in pairs, a
 pair a lane tile, fewer K/V pairs than query pairs.  Each head's logits
@@ -267,7 +276,9 @@ _LANES = 128
 # walk: the float32 accumulators of one query tile's WHOLE dq and of its
 # K/V tile's whole dk and dv, and the two buffers of each of the three
 # output blocks (24 MiB of it at 8,192 positions of 128 lanes in
-# bfloat16).  A longer sequence takes the dq and dkv kernels.
+# bfloat16).  A longer sequence keeps dq alone (16 MiB at 16,384) and
+# writes dk and dv a query head; past that it takes the dq and dkv
+# kernels.
 _RESIDENT_VMEM = 32 << 20
 # What a kernel may use unasked on the v5e, and what the tiles and the
 # intermediates of a tile pair take of it at block 512: the one-pass
@@ -542,7 +553,8 @@ def _flash_dkv_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
                       dk_acc, dv_acc, *, mask, mask_all: bool, scale: float,
-                      block_q: int, block_k: int, half: int):
+                      block_q: int, block_k: int, half: int,
+                      heads_apart: bool = False):
     """All three gradients in one pass (grid: batch, K/V lane tile,
     query tile of its group, the by-k walk): P, dP and dS are formed
     ONCE a (tile pair, head), transposed (``_key_side``, the dkv
@@ -551,7 +563,12 @@ def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     stays in ``dq_acc`` over its walk and leaves on the walk's last
     entry; the K/V tile's whole dk and dv stay in ``dk_acc`` /
     ``dv_acc`` over the walks of ALL the group's query tiles and leave
-    once, so rows no pair reaches leave as the zeros they were set to."""
+    once, so rows no pair reaches leave as the zeros they were set to.
+
+    ``heads_apart`` (where the whole dk and dv do not fit beside dq):
+    ``dk_acc`` / ``dv_acc`` hold ONE key tile of this query tile's own
+    dk and dv, set to zero on the key tile's first walk entry and
+    leaving, in float32, on its last; XLA sums the group's."""
     g, p_id = pl.program_id(2), pl.program_id(3)
     flags = fl_ref[p_id]
     first, last = p_id == 0, p_id == pl.num_programs(3) - 1
@@ -560,7 +577,7 @@ def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(first & (g == 0))
+    @pl.when((flags & _FIRST) != 0 if heads_apart else first & (g == 0))
     def _init_shared():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -586,7 +603,7 @@ def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
         dq, dk, dv = (dq_j, dk_j, dv_j) if dq is None \
             else (dq + dq_j, dk + dk_j, dv + dv_j)
     dq_acc[pl.ds(q_start, block_q), :] += dq
-    k_rows = pl.ds(k_start, block_k)
+    k_rows = slice(None) if heads_apart else pl.ds(k_start, block_k)
     dk_acc[k_rows, :] += dk
     dv_acc[k_rows, :] += dv
 
@@ -594,7 +611,8 @@ def _flash_bwd_kernel(qi_ref, ki_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     def _store():
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
-    @pl.when(last & (g == pl.num_programs(2) - 1))
+    @pl.when((flags & _LAST) != 0 if heads_apart
+             else last & (g == pl.num_programs(2) - 1))
     def _store_shared():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -767,28 +785,40 @@ def _attach_fwd(ops, out, lse, cfg):
     return out, (ops, out, lse)
 
 
-def _resident_bytes(t: int, width: int, itemsize: int) -> int:
+def _resident_bytes(t: int, width: int, itemsize: int,
+                    arrays: int = 3) -> int:
     """What the one-pass backward keeps in VMEM over a walk: a query
-    tile's whole dq and its K/V tile's whole dk and dv over ``t``
-    positions of ``width`` lanes, each a float32 accumulator and the two
-    buffers of its output block.  The group's size does not enter: its
-    query tiles go by one at a time."""
-    return 3 * t * width * (4 + 2 * itemsize)
+    tile's whole dq and its K/V tile's whole dk and dv (``arrays=1``:
+    dq alone) over ``t`` positions of ``width`` lanes, each a float32
+    accumulator and the two buffers of its output block.  The group's
+    size does not enter: its query tiles go by one at a time."""
+    return arrays * t * width * (4 + 2 * itemsize)
 
 
-def _fits_resident(t: int, width: int, itemsize: int) -> bool:
+def _fits_resident(t: int, width: int, itemsize: int,
+                   arrays: int = 3) -> bool:
     """The one-pass backward's condition."""
-    return _resident_bytes(t, width, itemsize) <= _RESIDENT_VMEM
+    return _resident_bytes(t, width, itemsize, arrays) <= _RESIDENT_VMEM
+
+
+def _backward_form(t: int, width: int, itemsize: int) -> str:
+    """The backward's form, from the operands' shapes alone: one pass
+    wherever dq, dk and dv stay resident (8,192 positions of 128 lanes
+    in bfloat16), one pass with dk and dv a query head where dq alone
+    does (to 32,768), two passes past that."""
+    if _fits_resident(t, width, itemsize):
+        return "one_pass"
+    if _fits_resident(t, width, itemsize, arrays=1):
+        return "one_pass_heads"
+    return "two_pass"
 
 
 def _attach_bwd(cfg, res, dout):
     ops = res[0]
     hd = _heads(ops, *cfg[5:])
-    one_pass = _fits_resident(ops[0].shape[1], hd.width,
-                              ops[0].dtype.itemsize)
-    count_build("flash_attention_backward",
-                "one_pass" if one_pass else "two_pass")
-    return (*_backward(res, dout, cfg, one_pass), None, None)
+    form = _backward_form(ops[0].shape[1], hd.width, ops[0].dtype.itemsize)
+    count_build("flash_attention_backward", form)
+    return (*_backward(res, dout, cfg, form), None, None)
 
 
 def _side_by_side(parts):
@@ -806,10 +836,10 @@ def _side_by_side(parts):
         for i, a in enumerate(parts)))
 
 
-def _flash_bwd_impl(res, dout, cfg, one_pass: bool):
-    """The kernels' three gradients, in either form, as the cotangent
-    of ``ops``."""
-    dq, dk, dv = (_one_pass if one_pass else _two_pass)(res, dout, cfg)
+def _flash_bwd_impl(res, dout, cfg, form: str):
+    """The kernels' three gradients, in the form named
+    (``_backward_form``), as the cotangent of ``ops``."""
+    dq, dk, dv = _FORMS[form](res, dout, cfg)
     if len(res[0]) == 1:
         return (_side_by_side([dq, dk, dv]),),
     return (dq, dk, dv),
@@ -865,7 +895,11 @@ def _delta_rows(dout, out, h: int):
         precision=jax.lax.Precision.HIGHEST).reshape(b * h, 1, t)
 
 
-def _one_pass(res, dout, cfg):
+def _one_pass(res, dout, cfg, heads_apart: bool = False):
+    """The one-pass kernel; ``heads_apart``: its form with dq alone
+    resident, each query head's dk and dv written by key tile in
+    float32 (470 MB a layer at 16,384 positions of 28 heads) and the
+    group's summed here, by XLA, at that precision."""
     mask, scale, block_q, block_k, interpret, h, h_kv, differential = cfg
     ops, out, lse = res
     hd = _heads(ops, h, h_kv, differential)
@@ -876,28 +910,61 @@ def _one_pass(res, dout, cfg):
     delta = _delta_rows(dout, out, h)
 
     q_tile, k_tile, rows = _by_k_specs(block_q, block_k, hd, False)
-    kv_shape = (b, t, h_kv * hd.d)
     resident = pltpu.VMEM((t, hd.width), jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, **_statics(cfg, t)),
+    if heads_apart:
+        # (B, group, T, H_kv·D): the group's g-th query heads side by
+        # side, so the group's sum runs over a leading axis (over lanes
+        # it is a relayout of the whole array: a copy each way)
+        kv_out = [jax.ShapeDtypeStruct((b, hd.group, t, h_kv * hd.d),
+                                       jnp.float32)] * 2
+        kv_spec = pl.BlockSpec(
+            (None, None, block_k, hd.width),
+            lambda b, i, g, p, qi, ki, fl: (b, g, ki[p], i))
+        kv_acc = pltpu.VMEM((block_k, hd.width), jnp.float32)
+    else:
+        kv_shape = (b, t, h_kv * hd.d)
+        kv_out = [jax.ShapeDtypeStruct(kv_shape, a.dtype) for a in (k, v)]
+        kv_spec, kv_acc = k_tile(whole=t), resident
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, **_statics(cfg, t),
+                          heads_apart=heads_apart),
         out_shape=(jax.ShapeDtypeStruct((b, t, h * hd.d), q.dtype),
-                   jax.ShapeDtypeStruct(kv_shape, k.dtype),
-                   jax.ShapeDtypeStruct(kv_shape, v.dtype)),
+                   *kv_out),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, hd.tiles // hd.group, hd.group, len(by_k[0])),
             in_specs=[q_tile(q_off), k_tile(k_off), k_tile(v_off),
                       q_tile(0, hd.out_width), rows, rows],
-            out_specs=(q_tile(whole=t), k_tile(whole=t), k_tile(whole=t)),
-            scratch_shapes=[resident, resident, resident]),
+            out_specs=(q_tile(whole=t), kv_spec, kv_spec),
+            scratch_shapes=[resident, kv_acc, kv_acc]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=_SCOPED_VMEM + _resident_bytes(
-                t, hd.width, q.dtype.itemsize)),
+                t, hd.width, q.dtype.itemsize, 1 if heads_apart else 3)),
         interpret=interpret,
-        name="flash_attention_bwd",
+        name="flash_attention_bwd_heads" if heads_apart
+        else "flash_attention_bwd",
     )(*_tables(by_k), q, k, v, dout, lse, delta)
+    if heads_apart:
+        dk, dv = (_group_sum(a, by_k[1], block_k).astype(x.dtype)
+                  for a, x in ((dk, k), (dv, v)))
+    return dq, dk, dv
+
+
+def _group_sum(parts, k_idx, block_k: int):
+    """dk (or dv) of the K/V heads from the float32 parts of their
+    groups' query heads, ``(B, group, T, H_kv·D)``.  A key tile no walk
+    entry reaches (``k_idx``: the walk's key tiles) was never written:
+    its rows are set to zero."""
+    parts = parts.sum(1)
+    t = parts.shape[1]
+    reached = np.zeros(t // block_k, bool)
+    reached[k_idx] = True
+    if not reached.all():
+        rows = np.repeat(reached, block_k)[None, :, None]
+        parts = jnp.where(rows, parts, 0.0)
+    return parts
 
 
 def _two_pass(res, dout, cfg):
@@ -957,6 +1024,9 @@ def _two_pass(res, dout, cfg):
     return dq, dk, dv
 
 
+_FORMS = {"one_pass": _one_pass,
+          "one_pass_heads": functools.partial(_one_pass, heads_apart=True),
+          "two_pass": _two_pass}
 _backward = engine_jit(_flash_bwd_impl, static_argnums=(2, 3),
                        key_hint="flash_attention_backward")
 _attach.defvjp(_attach_fwd, _attach_bwd)

@@ -205,16 +205,22 @@ def test_bad_mask_arguments_are_refused():
 # ------------------------------------------ the backward's two forms
 from analytics_zoo_tpu.ops import pallas_attention as kernels  # noqa: E402
 
-FORMS = ["one_pass", "two_pass"]
+FORMS = ["one_pass", "two_pass", "one_pass_heads"]
+# dq alone at 256 positions of 128 lanes in float32: the three resident
+# arrays fit it at neither float32 nor bfloat16
+DQ_ALONE_AT_256 = 256 * 128 * 12
 
 
 @pytest.fixture
 def backward_form(request, monkeypatch):
-    """The backward in the form named: every shape here fits the
-    one-pass form's budget, so the two-pass form is reached by a budget
-    nothing fits."""
+    """The backward in the form named: every shape here (256 positions)
+    fits the one-pass form's budget, so the two-pass form is reached by
+    a budget nothing fits, and the form with dk and dv a query head by
+    one that a query tile's whole dq alone fits."""
     if request.param == "two_pass":
         monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
+    if request.param == "one_pass_heads":
+        monkeypatch.setattr(kernels, "_RESIDENT_VMEM", DQ_ALONE_AT_256)
     return request.param
 
 
@@ -566,8 +572,9 @@ def test_the_two_forms_agree_to_rounding(monkeypatch, case, dtype):
 def test_the_backward_form_follows_the_length(monkeypatch, pallas_calls):
     """One pass wherever a query tile's whole dq and its K/V tile's
     whole dk and dv (float32 accumulators, two buffers of each output
-    block) fit ``_RESIDENT_VMEM``; the dq and dkv kernels past it.  The
-    group's size does not enter.  The counter says which a program got."""
+    block) fit ``_RESIDENT_VMEM``; one pass with dk and dv a query head
+    where dq alone does; the dq and dkv kernels past that.  The group's
+    size does not enter.  The counter says which a program got."""
     # the sparse cell's 8,192 positions of 128 lanes in bfloat16 (eight
     # query heads to a K/V head) fit, and the hybrid cell's; twice that
     # does not; the GPT cell's 512 in float32 do
@@ -575,13 +582,21 @@ def test_the_backward_form_follows_the_length(monkeypatch, pallas_calls):
     assert not kernels._fits_resident(16384, 128, 2)
     assert kernels._fits_resident(512, 128, 4)
     assert not kernels._fits_resident(8192, 128, 4)
+    # dq alone: the windowed cell's 16,384 positions, and twice that
+    assert [kernels._backward_form(t, 128, 2)
+            for t in (8192, 16384, 32768, 65536)] == [
+        "one_pass", "one_pass_heads", "one_pass_heads", "two_pass"]
     # at float32: three arrays of 128 lanes, 4 + 2 x 4 bytes a position
     monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 256 * 3 * 128 * 12)
     assert kernels._fits_resident(256, 128, 4)
     assert not kernels._fits_resident(257, 128, 4)
+    assert kernels._fits_resident(768, 128, 4, arrays=1)
+    assert not kernels._fits_resident(769, 128, 4, arrays=1)
     names = {256: {"flash_attention_bwd"},
-             512: {"flash_attention_dq", "flash_attention_dkv"}}
-    for t, form in ((256, "one_pass"), (512, "two_pass")):
+             512: {"flash_attention_bwd_heads"},
+             1024: {"flash_attention_dq", "flash_attention_dkv"}}
+    for t, form in ((256, "one_pass"), (512, "one_pass_heads"),
+                    (1024, "two_pass")):
         q, k, v, w = _operands(1, t, 8, 1, 128, 8 * 128, 13)
         grads = jax.grad(lambda *a: jnp.sum(w * flash_attention_token_major(
             *a, n_head=8, causal=True, interpret=True)), (0, 1, 2))
@@ -624,6 +639,11 @@ def test_the_one_pass_walk_goes_through_each_key_tile_once(
     assert pallas_grids(grads(), q, k, v) == {
         "flash_attention_fwd": (2, 8, n),
         "flash_attention_bwd": (2, 2, 4, n)}
+    # dq alone resident: the same grid and walk
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", DQ_ALONE_AT_256)
+    assert pallas_grids(grads(), q, k, v) == {
+        "flash_attention_fwd": (2, 8, n),
+        "flash_attention_bwd_heads": (2, 2, 4, n)}
     monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
     assert pallas_grids(grads(), q, k, v) == {
         "flash_attention_fwd": (2, 8, n), "flash_attention_dq": (2, 8, n),
@@ -676,3 +696,78 @@ def test_rows_no_pair_reaches_leave_as_zeros(monkeypatch):
     np.testing.assert_allclose(dq[:, blk:-blk], whole[0][:, blk:-blk],
                                rtol=1e-6, atol=1e-6)
     assert float(jnp.max(jnp.abs(dq[:, -blk:] - whole[0][:, -blk:]))) > 0
+
+
+# ------------------- dq alone resident, dk and dv a query head (16k)
+DQ_ALONE_AT_512 = 512 * 128 * 12
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(AGREE))
+def test_dk_and_dv_a_query_head_agree_with_both_forms(monkeypatch, case,
+                                                      dtype):
+    """The form past the one-pass budget that keeps dq alone resident is
+    the one-pass kernel's body: dq comes out as the one-pass form's; dk
+    and dv are each query head's float32 parts, summed over the group by
+    XLA, so they differ from either other form in the order float32 sums
+    are taken alone."""
+    h, h_kv, d, pair, mask = AGREE[case]
+    t = 512
+    ops = [a.astype(dtype) for a in _operands(
+        1, t, h, h_kv, d, (2 if pair else 1) * h * d, 12)]
+    w = ops.pop().astype(jnp.float32)
+
+    def grads(budget):
+        monkeypatch.setattr(kernels, "_RESIDENT_VMEM", budget)
+        before = backward_builds()
+        got = jax.grad(lambda *a: jnp.sum(w * flash_attention_token_major(
+            *a, n_head=h, differential=pair, block_q=128, block_k=128,
+            interpret=True, **_mask_kw(mask)).astype(jnp.float32)),
+            (0, 1, 2))(*ops)
+        return forms_built_since(before), got
+
+    forms = {}
+    for budget in (32 << 20, DQ_ALONE_AT_512, 0):
+        (form,), forms[form] = grads(budget)
+    heads = forms.pop("one_pass_heads")
+    np.testing.assert_array_equal(heads[0], forms["one_pass"][0])
+    tol = 2e-6 if dtype == "float32" else 2 ** -7
+    for form, grads_ in sorted(forms.items()):
+        for name, a, b in zip("qkv", heads, grads_):
+            assert a.dtype == b.dtype == ops["qkv".index(name)].dtype
+            a, b = (x.astype(jnp.float32) for x in (a, b))
+            scale = float(jnp.max(jnp.abs(b)))
+            assert scale > 0
+            assert float(jnp.max(jnp.abs(a - b))) <= tol * scale, \
+                (form, name)
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_rows_no_pair_reaches_leave_as_zeros_a_query_head(monkeypatch,
+                                                           group):
+    """A walk that leaves a key tile out (here: the causal list less every
+    pair of the last key tile and of the first q tile) never writes that
+    tile's per-head dk and dv: the group's sum sets its rows to zero,
+    as the one-pass form's zeroed accumulators leave them; the rows the
+    walk reaches are the one-pass form's for the same walk."""
+    t, blk, d = 512, 128, 128
+    q, k, v, w = _operands(1, t, group, 1, d, group * d, 15)
+    cfg = ("causal", d ** -0.5, blk, blk, True, group, 1, False)
+    out, lse = kernels._flash_fwd_impl((q, k, v), cfg)
+    by_q, (qi, ki, fl) = _tile_pairs("causal", t, blk, blk)
+    keep = (ki != t // blk - 1) & (qi != 0)
+    qi, ki, fl = qi[keep], ki[keep], fl[keep] & ~(_FIRST | _LAST)
+    fl[np.r_[True, ki[1:] != ki[:-1]]] |= _FIRST
+    fl[np.r_[ki[1:] != ki[:-1], True]] |= _LAST
+    monkeypatch.setattr(kernels, "_tile_pairs",
+                        lambda *a: (by_q, (qi, ki, fl)))
+    res = ((q, k, v), out, lse)
+    shared = kernels._one_pass(res, w, cfg)
+    dq, dk, dv = kernels._one_pass(res, w, cfg, heads_apart=True)
+    assert not np.asarray(dk[:, -blk:]).any()
+    assert not np.asarray(dv[:, -blk:]).any()
+    assert not np.asarray(dq[:, :blk]).any()
+    np.testing.assert_array_equal(dq, shared[0])
+    for got, want in zip((dk, dv), shared[1:]):
+        assert float(jnp.max(jnp.abs(got[:, :-blk]))) > 0
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)

@@ -3,7 +3,7 @@ sizes: ReGLU experts routed from another input than their own, four
 shares of them adding up to the uncut layer with the router counted
 once; grouped-query attention with no position encoding and under a
 window at groups of 7 query heads, on the kernels and on the lax path;
-the two-pass flash backward under the window at group 7; the decoder
+both long flash backward forms under the window at group 7; the decoder
 layer whose router reads the attention's input."""
 
 import functools
@@ -298,13 +298,20 @@ def test_no_rotary_reads_no_position():
         assert bool(jnp.allclose(a, b)) == same
 
 
-@pytest.mark.parametrize("mask", ["window", "causal"])
-def test_two_pass_backward_at_group_7(monkeypatch, mask):
-    """The form 16,384 positions take (past ``_RESIDENT_VMEM``), reached
-    at 512 with a budget nothing fits: ``_dq`` and ``_dkv`` under the
-    window (and the causal mask) with 14 query heads on 2 K/V heads,
-    against dense attention's gradients."""
-    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 0)
+@pytest.mark.parametrize(
+    "mask,form", [("window", "two_pass"), ("causal", "two_pass"),
+                  ("window", "one_pass_heads"), ("causal", "one_pass_heads")],
+    ids=["window", "causal", "window-one_pass_heads",
+         "causal-one_pass_heads"])
+def test_two_pass_backward_at_group_7(monkeypatch, mask, form):
+    """The forms past the one-pass budget, reached at 512 positions: the
+    one 16,384 positions take (dq alone resident, dk and dv a query head
+    summed over the group), with a budget a query tile's whole dq fits,
+    and ``_dq`` + ``_dkv``, with a budget nothing fits; under the window
+    (and the causal mask) with 14 query heads on 2 K/V heads, against
+    dense attention's gradients."""
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM",
+                        512 * 128 * 12 if form == "one_pass_heads" else 0)
     what = sliding_window(200) if mask == "window" else "causal"
     t, h, h_kv, d = 512, 14, 2, 128
     rs = np.random.RandomState(5)
@@ -327,7 +334,7 @@ def test_two_pass_backward_at_group_7(monkeypatch, mask):
         return jnp.sum(w * jnp.einsum("bhqk,bkhd->bqhd", p, vh).reshape(
             1, t, h * d))
 
-    key = '{kernel="flash_attention_backward",path="two_pass"}'
+    key = '{kernel="flash_attention_backward",path="%s"}' % form
     before = counters("fused_kernel_builds_total").get(key, 0)
     got = jax.grad(flash, (0, 1, 2))(q, k, v)
     assert counters("fused_kernel_builds_total")[key] == before + 1
@@ -439,3 +446,39 @@ def test_the_decoder_follows_the_published_layout():
     state = model.get_variables()["state"]
     assert [l.name for l in layers] == [n for n in state
                                         if "rows_routed" in state[n]]
+
+
+# --------------------------------- the windowed cell's program, at toy size
+def test_the_cell_in_the_backward_form_16k_positions_take(
+        interpreted_kernels, f32_policy, monkeypatch):
+    """The benchmark's windowed cell (``run_cell``) at 768 positions, with
+    a budget that a query tile's whole dq fits and the three resident
+    arrays do not: every layer's backward takes the form the cell's 16,384
+    positions take (dq alone resident, dk and dv a query head), and the
+    program's first epoch agrees with the float32 reference to rounding."""
+    from analytics_zoo_tpu.observability import get_registry
+    from benchmark import harness
+    monkeypatch.setattr(kernels, "_RESIDENT_VMEM", 768 * 128 * 12)
+    toy = dict(seq_len=768, num_hidden_layers=2, hidden_size=64,
+               num_attention_heads=7, num_key_value_heads=1,
+               moe_num_primary_experts_published=16, experts_held=[4, 4],
+               moe_num_primary_experts=4, moe_num_active_primary_experts=3,
+               moe_ffn_hidden_size=32, vocab_size=97, vocab_held=[0, 97],
+               vocab_size_published=388, sliding_window_size=128,
+               initializer_range=0.2,
+               recompute=dict(decoder_layers=True, loss_chunk_rows=256))
+    before = get_registry().snapshot()
+    line = harness.run_cell(
+        "smallthinker-21ba3b-instruct.sft_16k_hbm", 2 ** 31 + 13, 1.0, False,
+        require_chip=False, cfg_override=toy,
+        cell_override=dict(rows=4, limits={"loss": 1e-5, "grad": 1e-3,
+                                           "dparam": 1e-3}))
+    assert line["correct"], line["compared"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    built = harness.counter_delta(get_registry().snapshot(), before,
+                                  "fused_kernel_builds_total")
+    assert built.get('{kernel="flash_attention",path="pallas"}')
+    assert built.get(
+        '{kernel="flash_attention_backward",path="one_pass_heads"}')
+    assert not {'{kernel="flash_attention_backward",path="%s"}' % form
+                for form in ("one_pass", "two_pass")} & set(built)

@@ -111,6 +111,17 @@ def _flash_sdar_long(q, k, v):
         q, k, v, n_head=32, causal=True, block_q=512, block_k=512)
 
 
+def _flash_windowed_cell(q, k, v):
+    return flash_attention_token_major(
+        q, k, v, n_head=28, causal=True, block_q=512, block_k=512)
+
+
+def _flash_windowed_cell_window(q, k, v):
+    return flash_attention_token_major(
+        q, k, v, n_head=28, mask=sliding_window(4096), block_q=512,
+        block_k=512)
+
+
 def _scan(x, dt, a, b, c):
     return selective_scan(x, dt, a, b, c)[0]
 
@@ -178,10 +189,19 @@ CASES = [
      [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 1),
     ("flash-sdar-cell-grad-t8192x4096", _grad(_flash_sdar_cell, 3),
      [(1, 8192, 4096), (1, 8192, 512), (1, 8192, 512)], BF16, 2),
-    # twice as long: past the one-pass backward's VMEM budget, so the dq
-    # and dkv kernels are what compiles
+    # twice as long: past the one-pass backward's VMEM budget, so the
+    # form that keeps a query tile's whole dq alone resident and writes
+    # dk and dv a query head is what compiles (16 MiB resident)
     ("flash-sdar-long-grad-t16384x4096", _grad(_flash_sdar_long, 3),
-     [(1, 16384, 4096), (1, 16384, 512), (1, 16384, 512)], BF16, 3),
+     [(1, 16384, 4096), (1, 16384, 512), (1, 16384, 512)], BF16, 2),
+    # the windowed cell's: 28 heads on 4 K/V heads of 128 (groups of 7)
+    # at 16,384 positions, the global causal layer and the 4,096-key
+    # window, in that form
+    ("flash-windowed-cell-grad-t16384x3584", _grad(_flash_windowed_cell, 3),
+     [(1, 16384, 3584), (1, 16384, 512), (1, 16384, 512)], BF16, 2),
+    ("flash-windowed-cell-window-grad-t16384x3584",
+     _grad(_flash_windowed_cell_window, 3),
+     [(1, 16384, 3584), (1, 16384, 512), (1, 16384, 512)], BF16, 2),
     # the hybrid cell's: 40 heads of 64 in differential pairs on 20 K/V
     # heads (two maps a pair over its 128-wide V), inside a 512-key
     # window with K/V as operands of their own, causal with q, k and v
@@ -262,7 +282,11 @@ NAMED = {
     "flash-pair-cross-grad-t8192x2560": ["flash_attention_fwd",
                                          "flash_attention_bwd"],
     "flash-sdar-long-grad-t16384x4096": [
-        "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"],
+        "flash_attention_fwd", "flash_attention_bwd_heads"],
+    "flash-windowed-cell-grad-t16384x3584": [
+        "flash_attention_fwd", "flash_attention_bwd_heads"],
+    "flash-windowed-cell-window-grad-t16384x3584": [
+        "flash_attention_fwd", "flash_attention_bwd_heads"],
     "selective-scan-grad-t8192c5120n16": ["selective_scan_fwd",
                                           "selective_scan_bwd"],
     # the one-pass backward holds a head pair's whole dq in VMEM: this
@@ -515,9 +539,10 @@ def test_prerouted_decoder_layer_compiles_for_v5e(v5e, one_chip_routing,
     widths and 16,384 positions, forward and backward, state and all:
     28 query heads on 4 K/V heads (groups of 7), no positions and the
     causal mask or rotary under the 4,096-key window.  Past the one-pass
-    budget Mosaic takes the two-pass backward (``_dq`` + ``_dkv``); the
-    forward kernel stands once though the layer is recomputed; the
-    ReGLU experts' grouped products as in the latent layer."""
+    budget the backward keeps a query tile's whole dq alone resident and
+    writes dk and dv a query head (``_bwd_heads``); the forward kernel
+    stands once though the layer is recomputed; the ReGLU experts'
+    grouped products as in the latent layer."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import (
         attention, moe, prerouted)
     windowed = kind == "window"
@@ -550,7 +575,7 @@ def test_prerouted_decoder_layer_compiles_for_v5e(v5e, one_chip_routing,
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(calls) == sorted(
-        ["flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"]
+        ["flash_attention_fwd", "flash_attention_bwd_heads"]
         + 2 * 3 * ["grouped_matmul_fwd"]
         + 3 * ["grouped_matmul_dlhs", "grouped_matmul_drhs"])
 
