@@ -77,7 +77,13 @@ query head ``h`` reads K/V head ``h // group`` through the index map,
 and the backward kernels walk the group's query heads themselves, so no
 repeated K/V exists in HBM, nor a per-query-head dk/dv but in the form
 with dq alone resident.  K and V are operands like any other: they may
-be another layer's.
+be another layer's.  The exception is heads narrower than the lanes on
+fewer K/V heads (32 of 64 on 8): a tile's two query heads read a K/V
+head that lies at the lanes of one of them only, so each K/V head is
+repeated to its group before the kernels and dk / dv are summed over it
+by XLA (``_broadcasts_kv``;
+``fused_kernel_builds_total{kernel="flash_attention_packed",
+path="kv_broadcast"}``).
 
 The differential pair (``differential=True``): 64-wide heads in pairs, a
 pair a lane tile, fewer K/V pairs than query pairs.  Each head's logits
@@ -309,7 +315,10 @@ def _heads_per_tile(h: int, h_kv: int, d: int,
     where a narrower head divides the 128 lanes, every query head has
     its own K/V head and the tiles come out whole; 0 where neither
     holds (no lane-aligned block picks such a head: Mosaic refuses it,
-    and interpret mode runs it at one head a block).
+    and interpret mode runs it at one head a block).  Narrow heads on
+    FEWER K/V heads reach the kernels with their K/V heads repeated to
+    the group first (``_broadcasts_kv``), and so as heads that each have
+    their own.
 
     ``differential``: the heads come in pairs, a pair of query heads
     reads a PAIR of K/V heads, first on first and second on second, and
@@ -324,6 +333,27 @@ def _heads_per_tile(h: int, h_kv: int, d: int,
     if _LANES % d == 0 and h_kv == h and h % per == 0:
         return per
     return 0
+
+
+def _broadcasts_kv(h: int, h_kv: int, d: int) -> bool:
+    """Whether narrow heads on fewer K/V heads take the kernels with each
+    K/V head repeated to its group before them (a lane tile's two heads
+    of 64 read one K/V head, which lies at the lanes of only one of
+    them): a copy of K and V at the query heads' width, 32 MB each at
+    8,192 positions of 32 heads of 64 in bfloat16, and dk / dv summed
+    over the group by XLA, in float32, from the kernels' per-query-head
+    results."""
+    return (h_kv != h and not _heads_per_tile(h, h_kv, d)
+            and _heads_per_tile(h, h, d) > 1)
+
+
+def _repeat_heads(a, group: int, d: int):
+    """(B, T, H_kv·D) -> (B, T, H_kv·group·D), each head ``group`` times
+    in a row; its transpose sums the copies in float32."""
+    b, t, width = a.shape
+    heads = a.astype(jnp.float32).reshape(b, t, width // d, 1, d)
+    return jnp.broadcast_to(heads, (b, t, width // d, group, d)).reshape(
+        b, t, width * group).astype(a.dtype)
 
 
 def _head_lanes(j: int, per: int, *xs):
@@ -1046,7 +1076,8 @@ def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
     with q, k and v side by side (``n_kv_head``: ``H_kv``, ``H`` if not
     given): the kernels read the three out of the one array.  A head is
     a block of the last dimension: heads narrower than the 128 lanes
-    share a block (``_heads_per_tile``).  ``causal``,
+    share a block (``_heads_per_tile``), on fewer K/V heads once each
+    K/V head is repeated to its group (``_broadcasts_kv``).  ``causal``,
     ``mask=sliding_window(W)`` or ``mask=block_diffusion(L, B)``
     (``T = 2 L``) restrict what a query reads.  k and v may be another
     layer's: their cotangents are this call's share of the sum over
@@ -1078,6 +1109,12 @@ def flash_attention_token_major(q, k=None, v=None, *, n_head: int,
         raise ValueError(
             f"the differential pair on the kernels takes heads of "
             f"{_LANES // 2}, in pairs; got {n_head} on {h_kv} of {d}")
+    if not differential and _broadcasts_kv(n_head, h_kv, d):
+        if k is None:
+            q, k, v = jnp.split(q, [n_head * d, (n_head + h_kv) * d], -1)
+        count_build("flash_attention_packed", "kv_broadcast")
+        k, v = (_repeat_heads(a, n_head // h_kv, d) for a in (k, v))
+        ops, h_kv = (q, k, v), n_head
     if scale is None:
         scale = d ** -0.5
     what = "causal" if causal else mask

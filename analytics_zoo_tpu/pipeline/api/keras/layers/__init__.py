@@ -63,7 +63,7 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.diffusion import (
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.ssm import (
     DifferentialAttention, GatedFeedForward, GatedMemoryUnit,
-    HybridDecoderLayer, Mamba, NextTokenLoss,
+    HybridDecoderLayer, Mamba, Mamba2, NextTokenLoss, PreNormDecoderLayer,
 )
 from analytics_zoo_tpu.pipeline.api.keras.layers.latent import (
     LatentAttention, LatentDecoderLayer,
@@ -104,7 +104,8 @@ __all__ = [
     "SparseEmbedding", "AtrousConvolution1D", "ShareConvolution2D",
     "SpaceToDepth2D", "MoE", "DroplessMoE",
     "DifferentialAttention", "GatedFeedForward", "GatedMemoryUnit",
-    "HybridDecoderLayer", "Mamba", "NextTokenLoss",
+    "HybridDecoderLayer", "Mamba", "Mamba2", "NextTokenLoss",
+    "PreNormDecoderLayer",
     "LatentAttention", "LatentDecoderLayer", "PreRoutedDecoderLayer",
     "AddConstant", "BinaryThreshold", "CAdd", "CMul", "Exp",
     "GaussianSampler", "HardShrink", "HardTanh", "Identity", "Log",

@@ -61,16 +61,21 @@ def _flash_route(t: int, n_head: int, n_kv_head: int, head_dim: int,
     (single-device) mesh; the tiles are 256 positions long; and a head
     has to be a block of the projection's last dimension, a lane
     multiple or one of ``128 // head_dim`` that fill 128 lanes
-    (``ops/pallas_attention._heads_per_tile``).  Every operand enters
-    the kernels tile by tile, so no length is too long.  Availability
-    comes from the kernel suite's ONE capability probe
-    (ops/fused.pallas_supported — does this backend compile Pallas?),
-    not from a backend-name string match."""
+    (``ops/pallas_attention._heads_per_tile``; narrow heads on fewer K/V
+    heads fill them once the kernels' caller repeats each K/V head to
+    its group, ``_broadcasts_kv``).  Every operand enters the kernels
+    tile by tile, so no length is too long.  Availability comes from the
+    kernel suite's ONE capability probe (ops/fused.pallas_supported —
+    does this backend compile Pallas?), not from a backend-name string
+    match."""
     from analytics_zoo_tpu.ops import fused
-    from analytics_zoo_tpu.ops.pallas_attention import _heads_per_tile
+    from analytics_zoo_tpu.ops.pallas_attention import (
+        _broadcasts_kv, _heads_per_tile)
     if not (fused.pallas_supported()
             and math.prod(_mesh().shape.values()) == 1 and t % 256 == 0):
         return 0
+    if not differential and _broadcasts_kv(n_head, n_kv_head, head_dim):
+        n_kv_head = n_head
     return _heads_per_tile(n_head, n_kv_head, head_dim, differential)
 
 
@@ -199,7 +204,9 @@ class GroupedQueryAttention(Layer):
     ``i // (n_head / n_kv_head)``.
 
     Inputs ``[x, positions]``: ``x`` (B, T, D) and integer position ids
-    (B, T).  ``mask``: ``None`` (every query reads every key),
+    (B, T); with ``rope_theta=None`` ``x`` alone will do.  ``scale``:
+    the softmax's factor on the logits (default ``1 / sqrt(head_dim)``).
+    ``mask``: ``None`` (every query reads every key),
     ``"causal"``, ``ops.pallas_attention.sliding_window(W)`` (causal
     inside a band of ``W`` keys) or
     ``ops.pallas_attention.block_diffusion(L, B)`` over ``T = 2 L``
@@ -212,7 +219,8 @@ class GroupedQueryAttention(Layer):
 
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
                  rope_theta: Optional[float] = 10000.0, qk_norm: bool = True,
-                 norm_epsilon: float = 1e-6, mask=None, **kwargs):
+                 norm_epsilon: float = 1e-6, mask=None,
+                 scale: Optional[float] = None, **kwargs):
         super().__init__(**kwargs)
         if n_head % n_kv_head:
             raise ValueError(
@@ -223,9 +231,15 @@ class GroupedQueryAttention(Layer):
         self.qk_norm = bool(qk_norm)
         self.norm_epsilon = float(norm_epsilon)
         self.mask = mask
+        self.scale = None if scale is None else float(scale)
+
+    @staticmethod
+    def _x_shape(input_shape):
+        return input_shape[0] if isinstance(input_shape, list) \
+            else input_shape
 
     def build(self, rng, input_shape) -> Params:
-        d = input_shape[0][-1]
+        d = self._x_shape(input_shape)[-1]
         params: Params = {}
         self.add_weight(params, rng, "q_kernel",
                         (d, self.n_head * self.head_dim))
@@ -246,7 +260,8 @@ class GroupedQueryAttention(Layer):
         from analytics_zoo_tpu.ops.attention import rotary_embedding
         from analytics_zoo_tpu.ops.pallas_attention import (
             allowed_pairs, flash_attention_token_major)
-        x, positions = inputs
+        x, positions = inputs if isinstance(inputs, (list, tuple)) \
+            else (inputs, None)
         b, t, _ = x.shape
         compute = get_policy().compute_dtype
 
@@ -278,7 +293,8 @@ class GroupedQueryAttention(Layer):
             ctx = flash_attention_token_major(
                 *(a.reshape(b, t, -1) for a in (q, k, v)),
                 n_head=self.n_head, causal=causal, block_q=block,
-                block_k=block, mask=None if causal else self.mask)
+                block_k=block, mask=None if causal else self.mask,
+                scale=self.scale)
         else:
             group = self.n_head // self.n_kv_head
             q, k, v = (jnp.moveaxis(a, 1, 2) for a in (q, k, v))
@@ -286,13 +302,13 @@ class GroupedQueryAttention(Layer):
                 q, jnp.repeat(k, group, axis=1),
                 jnp.repeat(v, group, axis=1),
                 mask=None if self.mask is None else jnp.asarray(
-                    allowed_pairs(self.mask, t)))
+                    allowed_pairs(self.mask, t)), scale=self.scale)
             ctx = jnp.moveaxis(ctx, 1, 2).reshape(
                 b, t, self.n_head * self.head_dim)
         return _mm(ctx, params["o_kernel"]).astype(x.dtype)
 
     def compute_output_shape(self, input_shape):
-        return tuple(input_shape[0])
+        return tuple(self._x_shape(input_shape))
 
 
 class PositionwiseFeedForward(Layer):

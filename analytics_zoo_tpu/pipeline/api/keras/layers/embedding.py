@@ -21,7 +21,8 @@ class Embedding(Layer):
     def __init__(self, input_dim: int, output_dim: int, init="uniform",
                  W_regularizer=None, mask_zero: bool = False,
                  parallel_mode: str = None, vocab_held=None,
-                 tie_head: bool = False, **kwargs):
+                 tie_head: bool = False, multiplier: float = 1.0,
+                 **kwargs):
         """parallel_mode: None | "dim" — "dim" shards the embedding dim
         over the ``model`` axis (the gather stays local; downstream TP
         layers consume the sharded activations directly).
@@ -36,7 +37,11 @@ class Embedding(Layer):
         tie_head: the layer has a second output, the table itself
         (count, D), for a head that forms its logits from it
         (``layers.ssm.NextTokenLoss``): one leaf with two uses, whose
-        gradient is the sum of both."""
+        gradient is the sum of both.
+
+        multiplier: the vectors come out times this factor (Granite's
+        ``embedding_multiplier``); the table handed to a tied head is
+        the table itself."""
         super().__init__(**kwargs)
         self.vocab_first, self.vocab_count = (
             (0, int(input_dim)) if vocab_held is None
@@ -54,6 +59,7 @@ class Embedding(Layer):
             raise ValueError("parallel_mode must be None|dim")
         self.parallel_mode = parallel_mode
         self.tie_head = bool(tie_head)
+        self.multiplier = float(multiplier)
 
     def build(self, rng, input_shape) -> Params:
         from jax.sharding import PartitionSpec as P
@@ -79,6 +85,8 @@ class Embedding(Layer):
             out = jnp.take(params["embeddings"], ids, axis=0)
         if self.mask_zero:
             out = out * (ids != 0)[..., None].astype(out.dtype)
+        if self.multiplier != 1.0:
+            out = out * self.multiplier
         return [out, params["embeddings"]] if self.tie_head else out
 
     def compute_output_shape(self, input_shape):

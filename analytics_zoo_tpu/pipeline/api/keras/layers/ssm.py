@@ -112,12 +112,8 @@ class Mamba(Layer):
         self.add_weight(params, rng, "x_kernel", (c, r + 2 * n),
                         init="normal")
         self.add_weight(params, rng, "dt_kernel", (r, c), init="normal")
-        # dt starts log-uniform in [1e-3, 1e-1]; A at -(1..N) a channel
-        self.add_weight(
-            params, rng, "dt_bias", (c,),
-            init=lambda key, shape, dtype: _inverse_softplus(jnp.exp(
-                jax.random.uniform(key, shape, dtype, math.log(1e-3),
-                                   math.log(1e-1)))))
+        # A at -(1..N) a channel
+        self.add_weight(params, rng, "dt_bias", (c,), init=_dt_bias)
         self.add_weight(
             params, rng, "a_log", (c, n),
             init=lambda key, shape, dtype: jnp.broadcast_to(
@@ -159,6 +155,79 @@ class Mamba(Layer):
 
 def _inverse_softplus(y):
     return y + jnp.log(-jnp.expm1(-y))
+
+
+def _dt_bias(key, shape, dtype):
+    """dt starts log-uniform in [1e-3, 1e-1]."""
+    return _inverse_softplus(jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(1e-1))))
+
+
+class Mamba2(Layer):
+    """The Mamba-2 mixer (Dao & Gu 2024), ``H`` heads of ``P`` channels
+    on ``G`` groups of ``N`` states: ``[z, xBC, dt_r] = u W_in`` (widths
+    ``H P``, ``H P + 2 G N``, ``H``); ``xBC = silu(causal depthwise
+    conv(xBC) + b)`` split into ``x``, ``B``, ``C``; ``dt = softplus(dt_r
+    + dt_bias)``; ``A = -exp(A_log)``, one a head; ``y =
+    ssd_scan(x, dt, A, B, C) + D x`` (``ops/ssd_scan.py``); out ``=
+    RMSNorm(y * silu(z); g) W_out``, the gate before the norm and the
+    norm over all ``H P`` channels.  No bias on the projections."""
+
+    def __init__(self, n_heads: int, head_dim: int, d_state: int,
+                 n_groups: int = 1, d_conv: int = 4, chunk: int = 256,
+                 norm_epsilon: float = 1e-5, **kwargs):
+        super().__init__(**kwargs)
+        self.n_heads, self.head_dim = int(n_heads), int(head_dim)
+        self.d_state, self.n_groups = int(d_state), int(n_groups)
+        self.d_conv, self.chunk = int(d_conv), int(chunk)
+        self.norm_epsilon = float(norm_epsilon)
+
+    def _widths(self):
+        inner = self.n_heads * self.head_dim
+        return inner, inner + 2 * self.n_groups * self.d_state
+
+    def build(self, rng, input_shape) -> Params:
+        d, h = input_shape[-1], self.n_heads
+        inner, conv = self._widths()
+        params: Params = {}
+        self.add_weight(params, rng, "in_kernel", (d, inner + conv + h),
+                        init="normal")
+        self.add_weight(params, rng, "conv_kernel", (self.d_conv, conv),
+                        init="normal")
+        self.add_weight(params, rng, "conv_bias", (conv,), init="zero")
+        self.add_weight(params, rng, "dt_bias", (h,), init=_dt_bias)
+        self.add_weight(
+            params, rng, "a_log", (h,),
+            init=lambda key, shape, dtype: jnp.log(
+                jnp.arange(1, shape[0] + 1, dtype=dtype)))
+        self.add_weight(params, rng, "d", (h,), init="one")
+        self.add_weight(params, rng, "norm_gamma", (inner,), init="one")
+        self.add_weight(params, rng, "out_kernel", (inner, d), init="normal")
+        return params
+
+    def call(self, params, u, training=False, rng=None):
+        from analytics_zoo_tpu.ops.ssd_scan import ssd_scan
+        compute = get_policy().compute_dtype
+        t, p = u.shape[1], self.head_dim
+        inner, conv = self._widths()
+        proj = _mm(u, params["in_kernel"]).astype(compute)
+        z, xbc, dt_r = jnp.split(proj, [inner, inner + conv], axis=-1)
+        # tap k of the causal convolution reads position t - (K - 1) + k
+        pad = jnp.pad(xbc.astype(F32), ((0, 0), (self.d_conv - 1, 0), (0, 0)))
+        xbc = jax.nn.silu(sum(pad[:, k:k + t] * params["conv_kernel"][k]
+                              for k in range(self.d_conv))
+                          + params["conv_bias"])
+        x, b, c = jnp.split(xbc, [inner, inner + self.n_groups
+                                  * self.d_state], axis=-1)
+        dt = jax.nn.softplus(dt_r.astype(F32) + params["dt_bias"])
+        y, _ = ssd_scan(x.astype(compute), dt, -jnp.exp(params["a_log"]),
+                        b.astype(compute), c.astype(compute),
+                        groups=self.n_groups, chunk=self.chunk)
+        y = y.astype(F32) + jnp.repeat(params["d"], p) * x
+        gated = rms_norm(y * jax.nn.silu(z.astype(F32)),
+                         params["norm_gamma"], self.norm_epsilon)
+        return _mm(gated.astype(compute),
+                   params["out_kernel"]).astype(u.dtype)
 
 
 class GatedMemoryUnit(Layer):
@@ -319,8 +388,9 @@ class DifferentialAttention(Layer):
 class KeepsKernelResults(Layer):
     """A layer that may run its body under ``jax.checkpoint`` with the
     policy that keeps what the body's Pallas kernels wrote, by name
-    (``KEPT_RESULTS`` of ``ops/pallas_attention.py`` and
-    ``ops/selective_scan.py``), and publishes the bytes so kept in the
+    (``KEPT_RESULTS`` of ``ops/pallas_attention.py``,
+    ``ops/selective_scan.py`` and ``ops/ssd_scan.py``), and publishes the
+    bytes so kept in the
     gauge ``train_recompute_kept_bytes{name}``, over every recomputed
     layer traced."""
 
@@ -331,9 +401,10 @@ class KeepsKernelResults(Layer):
 
     def _recomputed(self, body, *args):
         from analytics_zoo_tpu.ops import (
-            fused, pallas_attention, selective_scan)
+            fused, pallas_attention, selective_scan, ssd_scan)
         keep = jax.checkpoint_policies.save_only_these_names(
-            *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS)
+            *pallas_attention.KEPT_RESULTS, *selective_scan.KEPT_RESULTS,
+            *ssd_scan.KEPT_RESULTS)
         with fused.recording_kept_results() as kept:
             out = jax.checkpoint(body, policy=keep)(*args)
         self._gauge_kept(kept)
@@ -354,10 +425,12 @@ class KeepsKernelResults(Layer):
 
 
 class PreNormDecoderLayer(KeepsKernelResults):
-    """One decoder layer: ``h = h + attention(RMSNorm(h))``, then ``h = h
-    + ffn(RMSNorm(h))``.  ``ffn``: a ``GatedFeedForward`` (a dense layer)
-    or a ``DroplessMoE`` (a sparse one, whose state — ``rows_routed``,
-    ``selection_bias`` — is this layer's, so that
+    """One decoder layer: ``h = h + r attention(RMSNorm(h))``, then ``h =
+    h + r ffn(RMSNorm(h))``, ``r`` the ``residual_multiplier`` (Granite's
+    0.22; 1 elsewhere).  The "attention" is the layer's mixer: any layer
+    of one input, a ``Mamba2`` too.  ``ffn``: a ``GatedFeedForward`` (a
+    dense layer) or a ``DroplessMoE`` (a sparse one, whose state —
+    ``rows_routed``, ``selection_bias`` — is this layer's, so that
     ``observability.moe_stats`` finds it).  The layer holds the weights
     of its parts under ``ln1_gamma``, ``attn_*``, ``ln2_gamma``,
     ``ffn_*``.  A subclass names what the attention reads besides
@@ -365,14 +438,22 @@ class PreNormDecoderLayer(KeepsKernelResults):
     input (``_routing``).
 
     ``recompute``: the layer's internals are computed again in the
-    backward pass, EXCEPT what its flash kernels wrote, which the policy
-    keeps by name (``KeepsKernelResults``)."""
+    backward pass, EXCEPT what its kernels wrote, which the policy keeps
+    by name (``KeepsKernelResults``)."""
 
     def __init__(self, attention: Layer, ffn: Layer, epsilon: float = 1e-6,
-                 recompute: bool = False, **kwargs):
+                 recompute: bool = False, residual_multiplier: float = 1.0,
+                 **kwargs):
         super().__init__(**kwargs)
         self.attention, self.ffn = attention, ffn
         self.epsilon, self.recompute = float(epsilon), bool(recompute)
+        self.residual_multiplier = float(residual_multiplier)
+
+    def _residual(self, y):
+        """A branch's output as the residual sum takes it."""
+        if self.residual_multiplier != 1.0:
+            return y * self.residual_multiplier
+        return y
 
     def _attention_inputs(self, a):
         """What the attention reads: its normed input ``a`` (B, T, D)."""
@@ -410,12 +491,12 @@ class PreNormDecoderLayer(KeepsKernelResults):
         # operands themselves and the router reads float32
         a = rms_norm(h, params["ln1_gamma"], self.epsilon)
         routing = self._routing(part("ffn"), a)
-        h = h + self.attention.call(
-            part("attn"), self._attention_inputs(a)).astype(h.dtype)
+        h = h + self._residual(self.attention.call(
+            part("attn"), self._attention_inputs(a)).astype(h.dtype))
         m = rms_norm(h, params["ln2_gamma"], self.epsilon)
         y, state = self.ffn.apply(part("ffn"), m, state=state, **routing)
         y = y[0] if isinstance(y, (list, tuple)) else y      # [y, aux]
-        return h + y.astype(h.dtype), state
+        return h + self._residual(y.astype(h.dtype)), state
 
     def apply(self, params, x, state=None, training=False, rng=None):
         # a dense layer's empty state comes back as it went in
@@ -515,13 +596,17 @@ class NextTokenLoss(Layer):
 
     ``chunk_rows``: the logits are formed that many positions at a time
     and formed again in the backward pass, so that one chunk's (rows, V)
-    float32 logits exist at a time, not the sequence's."""
+    float32 logits exist at a time, not the sequence's.
+
+    ``logits_scaling``: the logits are divided by it before the softmax
+    (Granite's ``logits_scaling``)."""
 
     def __init__(self, vocab_first: int = 0, chunk_rows: int = 0,
-                 head_units: int = 0, **kwargs):
+                 head_units: int = 0, logits_scaling: float = 1.0, **kwargs):
         super().__init__(**kwargs)
         self.vocab_first, self.chunk_rows = int(vocab_first), int(chunk_rows)
         self.head_units = int(head_units)
+        self.logits_scaling = float(logits_scaling)
 
     def build(self, rng, input_shape) -> Params:
         params: Params = {}
@@ -552,6 +637,8 @@ class NextTokenLoss(Layer):
                 get_policy().cast_compute(h_c),
                 get_policy().cast_compute(table),
                 (((2,), (over,)), ((), ())), preferred_element_type=F32)
+            if self.logits_scaling != 1.0:
+                logits = logits / self.logits_scaling
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, targets_c[..., None],
                                          axis=-1)[..., 0]
@@ -654,4 +741,69 @@ def decoder_hybrid_decoder(*, seq_len: int, vocab_size: int,
             h = out
     loss = NextTokenLoss(first, loss_chunk_rows)(
         [LayerNorm(norm_epsilon)(h), ids, table])
+    return Model([ids, *unused], loss)
+
+
+def mamba2_hybrid_decoder(*, seq_len: int, vocab_size: int,
+                          hidden_size: int, intermediate_size: int,
+                          layer_types: Sequence[str], n_head: int,
+                          n_kv_head: int, mamba_heads: int,
+                          mamba_head_dim: int, d_state: int,
+                          n_groups: int = 1, d_conv: int = 4,
+                          chunk: int = 256,
+                          attention_scale: Optional[float] = None,
+                          embedding_multiplier: float = 1.0,
+                          residual_multiplier: float = 1.0,
+                          logits_scaling: float = 1.0,
+                          norm_epsilon: float = 1e-5, vocab_held=None,
+                          recompute: bool = False, loss_chunk_rows: int = 0,
+                          extra_inputs: int = 0):
+    """A pre-norm decoder mixing ``Mamba2`` and grouped-query attention
+    layers (the Granite 4.0-H family's) as a graph ``Model`` whose output
+    is each sequence's next-token loss; train it under ``lambda y_true,
+    y_pred: jnp.mean(y_pred)``.
+
+    Layer ``l`` is a ``PreNormDecoderLayer`` whose mixer is a ``Mamba2``
+    where ``layer_types[l]`` is ``"mamba"`` and else ``n_head`` heads on
+    ``n_kv_head`` K/V heads of ``hidden_size / n_head``, causal, with no
+    position encoding, no bias and ``attention_scale`` on the logits;
+    then a ``GatedFeedForward`` of ``intermediate_size``, each branch
+    times ``residual_multiplier``.  The embedding is tied to the head
+    (``vocab_held=(first, count)``: this chip's slice of a
+    vocabulary-parallel table), its vectors times
+    ``embedding_multiplier`` and the logits divided by
+    ``logits_scaling``.  ``recompute`` and ``loss_chunk_rows``:
+    ``PreNormDecoderLayer``'s and ``NextTokenLoss``'s.  ``extra_inputs``
+    further model inputs are taken and not used."""
+    from analytics_zoo_tpu.pipeline.api.keras.engine import Input
+    from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
+        GroupedQueryAttention)
+    from analytics_zoo_tpu.pipeline.api.keras.layers.embedding import (
+        Embedding)
+    from analytics_zoo_tpu.pipeline.api.keras.layers.normalization import (
+        RMSNorm)
+    from analytics_zoo_tpu.pipeline.api.keras.topology import Model
+
+    first, _ = vocab_held or (0, vocab_size)
+    ids = Input(shape=(seq_len,))
+    unused = [Input(shape=(seq_len,)) for _ in range(extra_inputs)]
+    h, table = Embedding(vocab_size, hidden_size, init="normal",
+                         vocab_held=vocab_held, tie_head=True,
+                         multiplier=embedding_multiplier)(ids)
+    for kind in layer_types:
+        if kind == "mamba":
+            mixer = Mamba2(mamba_heads, mamba_head_dim, d_state, n_groups,
+                           d_conv, chunk, norm_epsilon)
+        elif kind == "attention":
+            mixer = GroupedQueryAttention(
+                n_head, n_kv_head, hidden_size // n_head, rope_theta=None,
+                qk_norm=False, mask="causal", scale=attention_scale)
+        else:
+            raise ValueError(f"unknown layer type {kind!r}")
+        h = PreNormDecoderLayer(
+            mixer, GatedFeedForward(intermediate_size), norm_epsilon,
+            recompute=recompute, residual_multiplier=residual_multiplier)(h)
+    loss = NextTokenLoss(first, loss_chunk_rows,
+                         logits_scaling=logits_scaling)(
+        [RMSNorm(norm_epsilon)(h), ids, table])
     return Model([ids, *unused], loss)

@@ -410,8 +410,8 @@ def test_build_counter_says_which_form_a_traced_layer_got(one_chip_routing):
     """``fused_kernel_builds_total``: the GPT block's 12 heads of 64
     share lane tiles (the packed series beside the kernels' own), the
     sparse cell's 32 on 4 of 128 do not, and 64-wide heads on fewer K/V
-    heads have no lane-aligned block and take the lax path, counted as
-    any other fallback."""
+    heads share lane tiles once each K/V head is repeated to its group
+    (``path="kv_broadcast"`` on the packed series beside ``"pallas"``)."""
     from analytics_zoo_tpu.pipeline.api.keras.layers.attention import (
         GroupedQueryAttention, MultiHeadSelfAttention)
     f32 = jax.ShapeDtypeStruct((2, 512, 768), jnp.float32)
@@ -427,7 +427,10 @@ def test_build_counter_says_which_form_a_traced_layer_got(one_chip_routing):
         shapes, x, pos) == {'{kernel="flash_attention",path="pallas"}': 1.0}
     assert _builds_while_tracing(
         GroupedQueryAttention(8, 2, 64, mask="causal"),
-        shapes, x, pos) == {'{kernel="flash_attention",path="lax"}': 1.0}
+        shapes, x, pos) == {
+            '{kernel="flash_attention",path="pallas"}': 1.0,
+            '{kernel="flash_attention_packed",path="pallas"}': 1.0,
+            '{kernel="flash_attention_packed",path="kv_broadcast"}': 1.0}
     # a padding-mask INPUT still sends the dense layer to the lax path
     assert _builds_while_tracing(
         MultiHeadSelfAttention(768, 12), [(None, 512, 768), (None, 512)],

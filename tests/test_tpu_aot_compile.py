@@ -29,6 +29,7 @@ from analytics_zoo_tpu.ops.pallas_attention import (
 from analytics_zoo_tpu.ops.pallas_latent_attention import (
     latent_flash_attention)
 from analytics_zoo_tpu.ops.selective_scan import selective_scan
+from analytics_zoo_tpu.ops.ssd_scan import ssd_scan
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +127,19 @@ def _scan(x, dt, a, b, c):
     return selective_scan(x, dt, a, b, c)[0]
 
 
+def _ssd(x, dt, a, b, c):
+    """x, B and C in bfloat16 beside float32 dt and A, as the Mamba-2
+    layer hands them."""
+    return ssd_scan(x.astype(BF16), dt, a, b.astype(BF16),
+                    c.astype(BF16))[0]
+
+
+def _flash_granite(q, k, v):
+    return flash_attention_token_major(
+        q, k, v, n_head=32, causal=True, scale=1 / 64, block_q=512,
+        block_k=512)
+
+
 def _flash_latent(q, q_pe, kv, k_pe):
     return latent_flash_attention(q, q_pe, kv, k_pe, n_head=32, causal=True,
                                   block_q=512, block_k=512)
@@ -136,6 +150,9 @@ def _flash_latent(q, q_pe, kv, k_pe):
 # parts, the up-projection's result (k_nope | v) and the ONE rotary key
 LATENT = [(1, 8192, 32 * 192), (1, 8192, 32 * 64), (1, 8192, 32 * 256),
           (1, 8192, 64)]
+# the Mamba-2 cell's scan operands: x (64 heads of 64), dt, A, B, C
+SSD = [(1, 8192, 4096), (1, 8192, 64), (64,), (1, 8192, 128),
+       (1, 8192, 128)]
 # twice as long: a head pair's whole dq no longer fits the one-pass
 # backward's VMEM budget, so the dq and dkv kernels are what compiles
 LATENT_LONG = [(1, 16384) + s[2:] for s in LATENT]
@@ -225,6 +242,14 @@ CASES = [
     ("selective-scan-grad-t8192c5120n16", _grad(_scan, 5),
      [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16),
       (1, 8192, 16)], F32, 2),
+    # the Mamba-2 cell's: the chunked scan over 64 heads of 64 on one
+    # group of 128 states (x, B, C in bfloat16, dt and the states in
+    # float32), and 32 query heads of 64 on
+    # 8 K/V heads, two to a lane tile once K/V are repeated to the group
+    ("ssd-scan-t8192h64p64n128", _ssd, SSD, F32, 1),
+    ("ssd-scan-grad-t8192h64p64n128", _grad(_ssd, 5), SSD, F32, 2),
+    ("flash-granite-grad-t8192x2048", _grad(_flash_granite, 3),
+     [(1, 8192, 2048), (1, 8192, 512), (1, 8192, 512)], BF16, 2),
 ]
 # every activation the LayerNorm epilogue claims to run in-kernel
 CASES += [
@@ -289,6 +314,9 @@ NAMED = {
         "flash_attention_fwd", "flash_attention_bwd_heads"],
     "selective-scan-grad-t8192c5120n16": ["selective_scan_fwd",
                                           "selective_scan_bwd"],
+    "ssd-scan-grad-t8192h64p64n128": ["ssd_scan_fwd", "ssd_scan_bwd"],
+    "flash-granite-grad-t8192x2048": ["flash_attention_fwd",
+                                      "flash_attention_bwd"],
     # the one-pass backward holds a head pair's whole dq in VMEM: this
     # is where a ``vmem_limit_bytes`` too small for it shows
     "flash-latent-grad-t8192h32-128-64-128": [
@@ -578,6 +606,32 @@ def test_prerouted_decoder_layer_compiles_for_v5e(v5e, one_chip_routing,
         ["flash_attention_fwd", "flash_attention_bwd_heads"]
         + 2 * 3 * ["grouped_matmul_fwd"]
         + 3 * ["grouped_matmul_dlhs", "grouped_matmul_drhs"])
+
+
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_mamba2_decoder_layer_compiles_for_v5e(v5e, one_chip_routing, on_tpu,
+                                               kind):
+    """One recomputed decoder layer of the Mamba-2 cell at its published
+    widths and 8,192 positions, forward and backward: the chunked scan's
+    two kernels (64 heads of 64 on one group of 128 states) or flash
+    attention on 32 query heads of 64 over 8 K/V heads repeated to the
+    group (the one-pass backward), each forward kernel ONCE though the
+    layer is recomputed, and no lax form in the program."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import attention, ssm
+    mixer = ssm.Mamba2(64, 64, 128) if kind == "mamba" else \
+        attention.GroupedQueryAttention(32, 8, 64, rope_theta=None,
+                                        qk_norm=False, mask="causal",
+                                        scale=1 / 64)
+    layer = ssm.PreNormDecoderLayer(mixer, ssm.GatedFeedForward(8192), 1e-5,
+                                    recompute=True, residual_multiplier=0.22)
+    text = _layer_grad_text(
+        layer, (1, 8192, 2048),
+        [jax.ShapeDtypeStruct((1, 8192, 2048), F32, sharding=v5e)])
+    calls = [line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    prefix = "ssd_scan" if kind == "mamba" else "flash_attention"
+    assert sorted(calls) == [prefix + "_bwd", prefix + "_fwd"]
 
 
 def test_capability_probe_compiles_for_v5e(v5e):
