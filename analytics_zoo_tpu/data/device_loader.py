@@ -122,9 +122,15 @@ class DeviceLoader:
             # a consumer stopping mid-epoch (end trigger, retry
             # restore, exception) must release the prefetch thread and
             # the device batches it buffered — without this they stay
-            # pinned in HBM for the life of the process
-            if isinstance(placed, PrefetchIterator):
-                placed.close()
+            # pinned in HBM for the life of the process.  The host read
+            # is closed only once that thread has ended (a read that
+            # stopped mid-epoch drops the pipeline's build stream); a
+            # thread that outlives the join may still be inside it, so
+            # its stream is dropped from here instead, never shared
+            if isinstance(placed, PrefetchIterator) and not placed.close():
+                pipe.drop_stream()
+            else:
+                host_batches.close()
 
     def __iter__(self) -> Iterator[Any]:
         return self.epoch()

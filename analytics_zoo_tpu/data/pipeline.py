@@ -23,11 +23,19 @@ Determinism contract:
 The position is intentionally NOT buried in a live iterator:
 ``iter_epoch`` is a pure read (resumable from any ``(epoch, step)``),
 ``commit`` moves the position, and the consuming loop decides when.
+
+With workers, the pull-ahead is ONE ordered stream of builds over
+``(epoch, step)`` positions that runs on past an epoch's last step, so
+the workers start the next epoch while the consumer finishes this one
+(:class:`_BuildStream`).  A stream is picked up again only where its
+next position is exactly the one asked for; otherwise it is dropped.
 """
 
 from __future__ import annotations
 
+import threading
 import time
+from collections import deque
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
@@ -57,7 +65,86 @@ def _pipeline_metrics(name: str):
             "data_worker_queue_depth",
             "batches built ahead by the pipeline worker pool",
             labels=("pipeline",)).labels(name),
+        **{outcome: reg.counter(
+            "data_builds_carried_total",
+            "batch builds carried past the reader's position: "
+            "outcome=used, submitted before the epoch's read began and "
+            "handed out by it; outcome=dropped, cancelled or discarded "
+            "with their stream", labels=("pipeline", "outcome")
+        ).labels(name, outcome) for outcome in ("used", "dropped")},
     }
+
+
+class _BuildStream:
+    """One ordered pull-ahead of batch builds over ``(epoch, step)``
+    positions, running on past each epoch's last step into the next.
+
+    Up to ``2 x workers`` builds are in flight, as in
+    ``WorkerPool.imap``.  One reader takes from it at a time (the
+    pipeline lends it out); ``drop`` may come from any thread and
+    cancels every build still queued.
+    """
+
+    def __init__(self, pipe: "DataPipeline", epoch: int, step: int,
+                 iteration: Optional[int]):
+        self.pool = pipe._pool
+        self.pos = (epoch, step)   # the next position handed out
+        self.dropped = False
+        self._pipe = pipe
+        self._depth = 2 * self.pool.workers
+        self._items = self._positions(epoch, step, iteration)
+        self._inflight: deque = deque()   # [epoch, step, future, carried]
+        self._lock = threading.Lock()
+
+    def _positions(self, epoch, step, iteration):
+        sampler, k = self._pipe.sampler, 0
+        while True:
+            for s, sel, mask in sampler.iter_epoch(epoch, step):
+                yield epoch, s, (sel, mask), \
+                    None if iteration is None else iteration + k
+                k += 1
+            epoch, step = epoch + 1, 0
+
+    def fill(self) -> None:
+        with self._lock:
+            while not self.dropped and len(self._inflight) < self._depth:
+                epoch, step, sel_mask, it = next(self._items)
+                self._inflight.append([epoch, step, self.pool.submit(
+                    self._pipe._build_batch, sel_mask, it), False])
+
+    def resume(self) -> None:
+        """A new read picks the stream up: what is in flight now was
+        submitted before it began, so is carried."""
+        with self._lock:
+            for entry in self._inflight:
+                entry[3] = True
+
+    def take(self) -> Tuple[int, Any]:
+        self.fill()
+        with self._lock:
+            if self.dropped:
+                raise RuntimeError(
+                    f"data pipeline {self._pipe.name!r}: its build "
+                    "stream was dropped during a read")
+            self._pipe._m["qdepth"].set(len(self._inflight))
+            epoch, step, future, carried = self._inflight.popleft()
+            self.pos = (epoch, step + 1) \
+                if step + 1 < self._pipe.num_batches else (epoch + 1, 0)
+        batch = future.result()
+        if carried:
+            self._pipe._m["used"].inc()
+        return step, batch
+
+    def drop(self) -> None:
+        with self._lock:
+            if self.dropped:
+                return
+            self.dropped = True
+            pending, self._inflight = self._inflight, deque()
+        for entry in pending:
+            entry[2].cancel()
+        self._pipe._m["dropped"].inc(len(pending))
+        self._pipe._m["qdepth"].set(0)
 
 
 class DataPipeline:
@@ -98,6 +185,10 @@ class DataPipeline:
         self._epoch = 0
         self._step = 0   # next batch to hand out
         self._pool: Optional[WorkerPool] = None
+        # the build stream between reads, and those lent to a read
+        self._stream: Optional[_BuildStream] = None
+        self._lent: set = set()
+        self._stream_lock = threading.Lock()
         self._m = _pipeline_metrics(name)
 
     # ------------------------------------------------------------ geometry
@@ -165,34 +256,86 @@ class DataPipeline:
             return (batch, mask)
         return batch
 
+    def batch_at(self, epoch: int, step: int):
+        """The batch at ``(epoch, step)``, built on the calling thread:
+        a pure read that leaves the workers and their stream alone."""
+        return self._build_batch(self.sampler.batch_indices(epoch, step))
+
     def iter_epoch(self, epoch: int, start_step: int = 0,
                    iteration: Optional[int] = None
                    ) -> Iterator[Tuple[int, Any]]:
         """Pure read of ``(step, batch)`` pairs for one epoch — does
         NOT move the pipeline position (``commit`` does).  Resumable
         from any step; with ``num_workers`` the batches are assembled
-        in the pool, ordered.  ``iteration`` is the training step that
-        ``start_step``'s batch feeds, for the builders' spans."""
-        steps = self.sampler.iter_epoch(epoch, start_step)
+        in the pool, ordered, by the pipeline's build stream: a read
+        that reaches the epoch's end leaves the stream building the
+        next epoch, for a read that starts exactly there.
+        ``iteration`` is the training step that ``start_step``'s batch
+        feeds, for the builders' spans."""
+        if self.num_workers <= 0:
+            for step, sel, mask in self.sampler.iter_epoch(
+                    epoch, start_step):
+                yield step, self._build_batch(
+                    (sel, mask),
+                    None if iteration is None
+                    else iteration + step - start_step)
+            return
+        if start_step >= self.num_batches:
+            return
+        stream = self._lend_stream(epoch, start_step, iteration)
+        completed = False
+        try:
+            while stream.pos[0] == epoch:
+                yield stream.take()
+            completed = True
+        finally:
+            self._release_stream(stream, completed)
 
-        def at(step):
-            return None if iteration is None \
-                else iteration + step - start_step
-        if self.num_workers > 0:
+    def _lend_stream(self, epoch: int, step: int,
+                     iteration: Optional[int]) -> _BuildStream:
+        """The stream between reads if its next position is
+        ``(epoch, step)``; else a new one from there."""
+        with self._stream_lock:
             if self._pool is None:
                 self._pool = WorkerPool(self.num_workers,
                                         name=f"data-{self.name}")
-            pairs = ((step, (sel, mask)) for step, sel, mask in steps)
+            stream, self._stream = self._stream, None
+            if stream is not None and stream.pos == (epoch, step):
+                stream.resume()
+            else:
+                if stream is not None:
+                    stream.drop()
+                stream = _BuildStream(self, epoch, step, iteration)
+            self._lent.add(stream)
+        return stream
 
-            def build(pair):
-                step, sel_mask = pair
-                return step, self._build_batch(sel_mask, at(step))
+    def _release_stream(self, stream: _BuildStream,
+                        completed: bool) -> None:
+        """Back from a read.  A read that reached its epoch's end keeps
+        the stream, topped up with the next epoch's builds; a read that
+        stopped before it (its reader took builds it may never commit)
+        drops it."""
+        if completed:
+            stream.fill()   # still lent: a drop_stream() meanwhile reaches it
+        with self._stream_lock:
+            self._lent.discard(stream)
+            if completed and not stream.dropped \
+                    and stream.pool is self._pool:
+                stream, self._stream = self._stream, stream
+        if stream is not None:   # the one released, or the one it replaced
+            stream.drop()
 
-            yield from self._pool.imap(
-                build, pairs, on_depth=self._m["qdepth"].set)
-        else:
-            for step, sel, mask in steps:
-                yield step, self._build_batch((sel, mask), at(step))
+    def drop_stream(self) -> None:
+        """Cancel the builds carried past the position: the stream kept
+        between reads and any lent to a read.  ``Estimator.train`` calls
+        it on its way out, so builds of an epoch that will never run do
+        not hold the workers."""
+        with self._stream_lock:
+            streams = [*self._lent, self._stream]
+            self._stream = None
+        for stream in streams:
+            if stream is not None:
+                stream.drop()
 
     # ------------------------------------------------------------ position
     @property
@@ -217,13 +360,17 @@ class DataPipeline:
         position rolls to ``(epoch+1, 0)``.  ``for batch in pipeline:``
         therefore consumes exactly one (rest-of-)epoch per loop."""
         epoch = self._epoch
-        t0 = time.perf_counter()
-        for step, batch in self.iter_epoch(epoch, self._step):
-            self._m["wait"].observe(time.perf_counter() - t0)
-            self._m["batches"].inc()
-            self.commit(epoch, step + 1)
-            yield batch
+        batches = self.iter_epoch(epoch, self._step)
+        try:
             t0 = time.perf_counter()
+            for step, batch in batches:
+                self._m["wait"].observe(time.perf_counter() - t0)
+                self._m["batches"].inc()
+                self.commit(epoch, step + 1)
+                yield batch
+                t0 = time.perf_counter()
+        finally:
+            batches.close()
 
     def __len__(self) -> int:
         return self.num_batches
@@ -273,12 +420,19 @@ class DataPipeline:
         self._step = int(state["step"])
         if self._step >= self.num_batches:
             self._epoch, self._step = self._epoch + 1, 0
+        # the kept stream builds for where the position was
+        with self._stream_lock:
+            stream, self._stream = self._stream, None
+        if stream is not None:
+            stream.drop()
 
     # ------------------------------------------------------------- cleanup
     def close(self) -> None:
-        if self._pool is not None:
-            self._pool.shutdown()
-            self._pool = None
+        self.drop_stream()
+        with self._stream_lock:
+            pool, self._pool = self._pool, None
+        if pool is not None:
+            pool.shutdown()
 
     def __enter__(self) -> "DataPipeline":
         return self

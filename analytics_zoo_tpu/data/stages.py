@@ -269,10 +269,11 @@ class PrefetchIterator:
         except BaseException as e:   # propagate into the consumer
             self._put(e)
 
-    def close(self) -> None:
+    def close(self) -> bool:
         """Stop the worker and release everything it buffered.  Called
         by the consumer when it stops early (e.g. an end-trigger
-        firing mid-epoch); idempotent."""
+        firing mid-epoch); idempotent.  True once the worker has
+        ended: only then is ``source_iter`` free for another thread."""
         self._abort.set()
         while True:   # unblock + drop buffered items
             try:
@@ -280,6 +281,7 @@ class PrefetchIterator:
             except queue.Empty:
                 break
         self._thread.join(timeout=5.0)
+        return not self._thread.is_alive()
 
     def __iter__(self):
         return self

@@ -176,8 +176,18 @@ def eval_batches(data_set, batch_size: int):
                 "validation DataPipeline with remainder='pad' (and "
                 "shuffle=False) so the tail batch is masked, not "
                 "dropped")
-        return (batch for _step, batch in data_set.iter_epoch(0))
+        return _read_once(data_set)
     return data_set.epoch_batches(0, batch_size, train=False)
+
+
+def _read_once(pipeline):
+    """Epoch 0's batches; the builds the read carried into epoch 1 are
+    dropped, since the next evaluation reads epoch 0 again."""
+    try:
+        for _step, batch in pipeline.iter_epoch(0):
+            yield batch
+    finally:
+        pipeline.drop_stream()
 
 
 def predict_in_batches(run_batch, x, batch_size: int):
@@ -663,9 +673,8 @@ class Estimator:
                     # workers start with the first epoch)
                     with tracer.span("startup_loader"):
                         if is_pipeline:
-                            warm_batch = next(iter(train_set.iter_epoch(
-                                train_set.epoch,
-                                start_step=train_set.step)))[1]
+                            warm_batch = train_set.batch_at(
+                                train_set.epoch, train_set.step)
                         elif type(train_set) is FeatureSet:
                             # exact-class guard, same as the HBM/eval
                             # caches: subclasses may have per-call
@@ -1081,6 +1090,10 @@ class Estimator:
             finally:
                 watchdog.stop()
                 set_active_watchdog(prev_watchdog)
+                if is_pipeline:
+                    # the workers may be building the next epoch: it
+                    # will not run, so free them before train_return
+                    train_set.drop_stream()
                 # summaries hold open file handles (JSONL + tfevents):
                 # close them whether training completed or raised.
                 # _ScalarWriter reopens on the next add_scalar, so a

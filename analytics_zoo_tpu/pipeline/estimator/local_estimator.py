@@ -240,6 +240,8 @@ class LocalEstimator:
         finally:
             watchdog.stop()
             set_active_watchdog(prev_watchdog)
+            if pipeline is not None:
+                pipeline.drop_stream()
         if not validate:
             sync_to_host()
         return self

@@ -13,6 +13,7 @@ The contracts under test (docs/data.md):
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
@@ -269,6 +270,186 @@ class TestDeviceLoader:
         dev = [np.asarray(b[0]) for b in DeviceLoader(_pipe(), depth=2)]
         for h, d in zip(host, dev):
             np.testing.assert_array_equal(h, d)
+
+
+# ------------------------------------------- the epoch-spanning stream
+def _carried(name, outcome):
+    from analytics_zoo_tpu.observability import get_registry
+    return get_registry().counter(
+        "data_builds_carried_total", labels=("pipeline", "outcome")
+    ).labels(name, outcome).value
+
+
+def _rows_key(y):
+    return tuple(int(v) for v in np.ravel(y))
+
+
+class TestCarriedBuildStream:
+    """With workers, one ordered stream of builds runs on past an
+    epoch's end; a read picks it up only at exactly its next position."""
+
+    def test_carried_epochs_match_fresh_reads_bit_for_bit(self):
+        def fn(b):
+            return np.sin(b[0]) * np.float32(3.0), b[1]
+        carried = _pipe(n=200, num_workers=4, name="carry-bits").map(fn)
+        fresh = _pipe(n=200, num_workers=4, name="carry-fresh").map(fn)
+        used = _carried("carry-bits", "used")
+        try:
+            for epoch in range(3):
+                fresh.drop_stream()   # a new stream for every epoch
+                want = list(fresh.iter_epoch(epoch))
+                got = list(carried)
+                assert len(got) == len(want) == 20
+                for (gx, gy), (_, (wx, wy)) in zip(got, want):
+                    assert gx.tobytes() == wx.tobytes()
+                    assert gy.tobytes() == wy.tobytes()
+            # 2 x 4 builds of each next epoch were carried, twice
+            assert _carried("carry-bits", "used") - used == 16
+        finally:
+            carried.close()
+            fresh.close()
+
+    def test_next_epoch_builds_start_before_last_batch_taken(self):
+        starts = {}
+
+        def record(b):
+            starts.setdefault(_rows_key(b[1]), time.perf_counter())
+            return b
+        p = _pipe(n=200, num_workers=4, name="carry-early").map(record)
+        used = _carried("carry-early", "used")
+        try:
+            for _ in p:
+                time.sleep(0.02)   # a consumer slower than the builds
+                t_last = time.perf_counter()
+            first = _rows_key(p.sampler.batch_indices(1, 0)[0])
+            assert starts[first] < t_last
+            assert _carried("carry-early", "used") == used
+            list(p)
+            assert _carried("carry-early", "used") - used == 8
+        finally:
+            p.close()
+
+    def test_load_state_dict_elsewhere_drops_carried_builds(self):
+        fn = lambda b: (b[0] * 2.0, b[1])   # noqa: E731
+        p = _pipe(num_workers=4, name="carry-seek").map(fn)
+        serial = _pipe().map(fn)
+        dropped = _carried("carry-seek", "dropped")
+        used = _carried("carry-seek", "used")
+        try:
+            list(p)   # epoch 0; 8 builds of epoch 1 carried
+            state = p.state_dict()
+            state["epoch"], state["step"] = 2, 3
+            p.load_state_dict(state)
+            assert _carried("carry-seek", "dropped") - dropped == 8
+            serial.load_state_dict(state)
+            got, want = list(p), list(serial)
+            assert len(got) == len(want) == 7
+            for (gx, gy), (wx, wy) in zip(got, want):
+                np.testing.assert_array_equal(gx, wx)
+                np.testing.assert_array_equal(gy, wy)
+            assert _carried("carry-seek", "used") == used
+        finally:
+            p.close()
+
+    @pytest.mark.parametrize("stop", [5, 19])
+    def test_mid_epoch_loader_stop_drops_and_resumes_exactly(self, stop):
+        """At step 5 the stream is lent to the loader's read when the
+        consumer stops; at step 19 that read has already reached the
+        epoch's end and kept it, one position past the consumer's."""
+        name = f"carry-stop-{stop}"
+        p = _pipe(n=200, num_workers=4, name=name)
+        serial = _pipe(n=200)
+        loader = DeviceLoader(p, depth=2)
+        dropped = _carried(name, "dropped")
+        try:
+            assert len(list(loader.epoch())) == 20
+            batches = loader.epoch()
+            for _ in range(stop):
+                next(batches)
+            deadline = time.monotonic() + 10
+            while stop == 19 and p._stream is None \
+                    and time.monotonic() < deadline:
+                time.sleep(0.01)
+            batches.close()   # the end trigger fired mid-epoch
+            assert (p.epoch, p.step) == (1, stop)
+            got = [np.asarray(b[0]) for b in loader.epoch()]
+            assert _carried(name, "dropped") - dropped >= 1
+            want = [bx for _, (bx, _by) in serial.iter_epoch(1, stop)]
+            assert len(got) == len(want) == 20 - stop
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(g, w)
+            assert (p.epoch, p.step) == (2, 0)
+        finally:
+            p.close()
+
+    def test_close_runs_no_queued_build_of_the_dropped_stream(self):
+        import threading
+        gate, started = threading.Event(), []
+        q = _pipe()
+        epoch1 = {_rows_key(q.sampler.batch_indices(1, k)[0])
+                  for k in range(q.num_batches)}
+
+        def hold(b):
+            key = _rows_key(b[1])
+            if key in epoch1:
+                started.append(key)
+                gate.wait(10)
+            return b
+        p = _pipe(num_workers=2, name="carry-close").map(hold)
+        dropped = _carried("carry-close", "dropped")
+        try:
+            list(p)   # epoch 0: two of epoch 1 run (held), two queue
+            deadline = time.monotonic() + 10
+            while len(started) < 2 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            pool = p._pool
+            p.close()
+            assert _carried("carry-close", "dropped") - dropped == 4
+        finally:
+            gate.set()
+        pool.shutdown(wait=True)
+        assert len(started) == 2
+
+    def test_reads_stay_exact_under_concurrent_drops(self):
+        """More workers than cores, a short switch interval, and a
+        second thread dropping the stream while reads take from it: a
+        read either hands out the batch of its position or stops, and
+        the next read resumes there."""
+        import threading
+        fn = lambda b: (b[0] + 1.0, b[1])   # noqa: E731
+        p = _pipe(n=400, num_workers=16, name="carry-stress").map(fn)
+        serial = _pipe(n=400).map(fn)
+        want = {(e, k): b for e in range(4)
+                for k, (_, b) in enumerate(serial.iter_epoch(e))}
+        done, taken = threading.Event(), []
+
+        def dropper():
+            while not done.is_set():
+                p.drop_stream()
+                time.sleep(0.001)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        thread = threading.Thread(target=dropper, daemon=True)
+        thread.start()
+        try:
+            deadline = time.monotonic() + 60
+            while p.epoch < 4 and time.monotonic() < deadline:
+                try:
+                    for bx, by in p:
+                        at = (p.epoch, p.step - 1) if p.step \
+                            else (p.epoch - 1, p.num_batches - 1)
+                        np.testing.assert_array_equal(bx, want[at][0])
+                        np.testing.assert_array_equal(by, want[at][1])
+                        taken.append(at)
+                except RuntimeError as e:   # dropped during the read
+                    assert "dropped" in str(e)
+        finally:
+            done.set()
+            thread.join(timeout=10)
+            sys.setswitchinterval(interval)
+            p.close()
+        assert not thread.is_alive()
+        assert taken == sorted(want)
 
 
 # ------------------------------------------------- training integration
