@@ -172,7 +172,7 @@ def global_batch_rows(mesh: Mesh, batch_size: int) -> int:
                          if data_split_across_hosts(mesh) else 1)
 
 
-def fetch_global(tree):
+def fetch_global(tree, site: str = "other"):
     """Bring a (possibly cross-process-sharded) pytree to host numpy.
 
     ``jax.device_get`` refuses arrays whose shards live on other
@@ -181,11 +181,28 @@ def fetch_global(tree):
     collective, so EVERY process must call this together (the reference
     analogue is InternalDistriOptimizer.getModel pulling the
     AllReduceParameter chunks back to the driver, Topology.scala:1549).
-    """
-    def fetch(leaf):
-        if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
-            from jax.experimental import multihost_utils
-            return multihost_utils.process_allgather(leaf, tiled=True)
-        return jax.device_get(leaf)
 
-    return jax.tree_util.tree_map(fetch, tree)
+    The rest goes to ONE ``jax.device_get``, which calls
+    ``copy_to_host_async`` on every leaf before it materialises any, so
+    a return pays one round trip and not one a leaf.  The bytes fetched
+    are counted in ``host_fetch_bytes_total{path,site}``: ``path`` is
+    ``overlapped`` or ``allgather``, ``site`` the caller's name for the
+    fetch (``train_return``, ``checkpoint_save``).
+    """
+    from analytics_zoo_tpu.observability import get_registry
+    fetched = get_registry().counter(
+        "host_fetch_bytes_total",
+        "bytes of the device arrays fetch_global fetched",
+        labels=("path", "site"))
+
+    def gather(leaf):
+        if not isinstance(leaf, jax.Array):
+            return leaf
+        if leaf.is_fully_addressable:
+            fetched.labels("overlapped", site).inc(float(leaf.nbytes))
+            return leaf
+        from jax.experimental import multihost_utils
+        fetched.labels("allgather", site).inc(float(leaf.nbytes))
+        return multihost_utils.process_allgather(leaf, tiled=True)
+
+    return jax.device_get(jax.tree_util.tree_map(gather, tree))

@@ -474,9 +474,12 @@ class Estimator:
                 # watchdog's halt snapshot goes to model_dir/halt/).
                 with tracer.span("checkpoint_save", jax_annotation=True,
                                  iteration=ts.iteration):
-                    payload = {"params": mesh_lib.fetch_global(params),
-                               "state": mesh_lib.fetch_global(state),
-                               "opt_state": mesh_lib.fetch_global(opt_state),
+                    # one fetch: every leaf's copy in flight at once
+                    host_params, host_state, host_opt = \
+                        mesh_lib.fetch_global((params, state, opt_state),
+                                              site="checkpoint_save")
+                    payload = {"params": host_params, "state": host_state,
+                               "opt_state": host_opt,
                                "epoch": ts.epoch, "iteration": ts.iteration}
                     if is_pipeline:
                         # the pipeline position points at the NEXT batch to
@@ -1105,8 +1108,8 @@ class Estimator:
             # the job's other edge: the trained state comes back to the host
             with tracer.span("train_return", jax_annotation=True,
                              bytes=_tree_bytes((params, state))):
-                self.variables = {"params": mesh_lib.fetch_global(params),
-                                  "state": mesh_lib.fetch_global(state)}
+                self.variables = mesh_lib.fetch_global(
+                    {"params": params, "state": state}, site="train_return")
                 self.model.set_variables(self.variables)
             return self
         finally:

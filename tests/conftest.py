@@ -87,6 +87,11 @@ _SETUP_METRICS = (
     "train_return_pct_of_window")
 _CELLS_OWN_TESTS = ("test_bench_sdar", "test_bench_phi4flash",
                     "test_bench_kanana")
+# the return's rate lists the eight cells, so it joins the METRICS of cells
+# 4-8's files; the same ``benchmark`` PR folds it in (Open questions 22)
+_EVERY_CELL_METRICS = ("train_return_gb_per_s",)
+_EVERY_CELLS_OWN_TESTS = _CELLS_OWN_TESTS + ("test_bench_smallthinker",
+                                             "test_bench_granite")
 
 
 def _with_setup_metrics(module):
@@ -120,10 +125,16 @@ def _every_cell_reports_the_setup_metrics(request, monkeypatch):
     the module at run time sees the longer list: a ``parametrize`` over
     ``METRICS`` was expanded at import, over the file's own.  A
     ``benchmark`` PR folds the seven into the files and drops this
-    (ROADMAP.md S6, PERF.md Open questions 22)."""
-    if request.module.__name__.rsplit(".", 1)[-1] in _CELLS_OWN_TESTS:
+    (ROADMAP.md S6, PERF.md Open questions 22), and
+    ``_EVERY_CELL_METRICS`` with them."""
+    name = request.module.__name__.rsplit(".", 1)[-1]
+    if name in _CELLS_OWN_TESTS:
         monkeypatch.setattr(request.module, "METRICS",
                             _with_setup_metrics(request.module))
+    if name in _EVERY_CELLS_OWN_TESTS:
+        monkeypatch.setattr(request.module, "METRICS",
+                            list(request.module.METRICS)
+                            + list(_EVERY_CELL_METRICS))
 
 
 @pytest.fixture(autouse=True)
